@@ -1,0 +1,309 @@
+#!/usr/bin/env python
+"""Chip smoke: TPC-H through the served path on a real TPU, or a failure.
+
+    python chip_smoke.py [--chips 4] [--sf 10] [--seed 42]
+
+The quickest proof that the system still starts on the chip. One process,
+no children: `Session(tpch_catalog(sf, seed))` -> `ServingTier` ->
+`MySQLServer(port=0)` + `SqlHttpServer(port=0)` as threads, then TPC-H Q1,
+Q6, Q3 over the MySQL wire and Q1 again over HTTP, engine defaults untouched
+(result cache off, strategies `auto`), so every send runs a device program.
+Each statement is sent twice, and a third time only if the second send still
+compiled (a first run that over-seeded a capacity publishes the tightened
+one, and the next run compiles once at it — runtime/executor.py `_adaptive`);
+the last send must compile nothing. Every answer is compared with
+tests/tpch_oracle.py after the sends, by tests/test_tpch_sql.py's tolerance.
+
+Exits non-zero — with no JSON line — unless `jax.default_backend()` is
+"tpu"; no flag admits a CPU. Also non-zero on any mismatch, exception,
+missing counter, compile in a last send, off-device column or missing
+memory statistic. Seconds are printed as facts of this run, not as metrics.
+Last stdout line on success:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+from __future__ import annotations
+
+import argparse
+import faulthandler
+import http.client
+import json
+import math
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+# (door, TPC-H query, result column that orders rows for comparison — None
+# where the statement's own ORDER BY is total, as tests/test_tpch_sql.py's
+# FULLY_ORDERED has it; Q3's top-10 may tie on its sort keys, so its rows
+# are matched by the unique l_orderkey instead)
+STATEMENTS = (("mysql", 1, None), ("mysql", 6, None), ("mysql", 3, 0),
+              ("http", 1, None))
+
+# the columns Q1/Q6/Q3 read: all the oracle's frames hold (a to_pandas of
+# all 16 lineitem columns at SF10 is tens of GB of host strings) and the
+# floor for what must be resident on the device afterwards
+QUERY_COLUMNS = {
+    "lineitem": ("l_orderkey", "l_quantity", "l_extendedprice", "l_discount",
+                 "l_tax", "l_returnflag", "l_linestatus", "l_shipdate"),
+    "orders": ("o_orderkey", "o_custkey", "o_orderdate", "o_shippriority"),
+    "customer": ("c_custkey", "c_mktsegment"),
+}
+
+MAX_SENDS = 3
+
+
+def _cell_ok(got, exp) -> bool:
+    """One wire cell (MySQL text or JSON value) against the oracle's value:
+    numbers within 1e-6 relative, dates by day, everything else as text."""
+    import numpy as np
+    import pandas as pd
+
+    if exp is None or (isinstance(exp, float) and math.isnan(exp)):
+        return got is None
+    if got is None:
+        return False
+    if isinstance(exp, (pd.Timestamp, np.datetime64)):
+        return str(got)[:10] == str(exp)[:10]
+    if isinstance(exp, (int, float, np.integer, np.floating)):
+        g, e = float(got), float(exp)
+        return abs(g - e) <= max(abs(e), 1.0) * 1e-6
+    return str(got) == str(exp)
+
+
+def _first_mismatch(got, exp, key):
+    """None when the rows agree, else a description of the first difference."""
+    if len(got) != len(exp):
+        return f"{len(got)} rows vs oracle {len(exp)}"
+    if key is not None:
+        got = sorted(got, key=lambda r: int(r[key]))
+        exp = sorted(exp, key=lambda r: int(r[key]))
+    for i, (g, e) in enumerate(zip(got, exp)):
+        if len(g) != len(e):
+            return f"row {i}: arity {len(g)} vs {len(e)}"
+        for j, (gv, ev) in enumerate(zip(g, e)):
+            if not _cell_ok(gv, ev):
+                return f"row {i} col {j}: {gv!r} vs oracle {ev!r}"
+    return None
+
+
+def _oracle_frames(catalog) -> dict:
+    from starrocks_tpu.column import HostTable, Schema
+
+    frames = {}
+    for table, cols in QUERY_COLUMNS.items():
+        ht = catalog.get_table(table).table
+        sub = HostTable(
+            Schema(tuple(f for f in ht.schema.fields if f.name in cols)),
+            ht.arrays, {k: v for k, v in ht.valids.items() if k in cols})
+        frames[table] = sub.to_pandas()
+    return frames
+
+
+def _versions() -> dict:
+    import importlib.metadata as md
+
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[pkg] = md.version(pkg)
+        except md.PackageNotFoundError:
+            out[pkg] = "not installed"
+    return out
+
+
+def run(sf: float, chips: int, seed: int) -> dict:
+    """Drive the served path once on whatever backend JAX has (main() only
+    lets a TPU through; tier-1 calls this at SF0.01 on CPU). Returns
+    {"ok", "failures", "device", "statements", ...}; prints as it goes."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    # tests/ is not a package: its modules import their siblings by bare name
+    sys.path.insert(0, os.path.join(REPO, "tests"))
+    import tpch_oracle
+    from test_mysql_protocol import FullClient
+    from tpch_queries import QUERIES
+
+    from starrocks_tpu.runtime.http_service import SqlHttpServer
+    from starrocks_tpu.runtime.metrics import PROGRAM_COMPILES, RECOMPILES
+    from starrocks_tpu.runtime.mysql_service import MySQLServer
+    from starrocks_tpu.runtime.serving import ServingTier
+    from starrocks_tpu.runtime.session import Session
+    from starrocks_tpu.storage.catalog import tpch_catalog
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
+    print(f"versions {json.dumps(_versions())}")
+    print(f"device {json.dumps(device)} chips_used={chips} sf={sf:g} "
+          f"seed={seed} compile_cache={jax.config.jax_compilation_cache_dir}")
+    failures: list = []
+    if chips > len(devices):
+        failures.append(f"--chips {chips} but JAX has {len(devices)} devices")
+        return {"ok": False, "failures": failures, "device": device}
+
+    t0 = time.monotonic()
+    catalog = tpch_catalog(sf, seed)
+    print(f"generated lineitem_rows="
+          f"{catalog.get_table('lineitem').row_count} "
+          f"seconds={time.monotonic() - t0:.1f}")
+
+    session = Session(catalog, dist_shards=chips if chips > 1 else None)
+    tier = ServingTier(session)
+    my = MySQLServer(session, port=0, tier=tier).start()
+    ht = SqlHttpServer(session, port=0, tier=tier).start()
+    mysql = FullClient("127.0.0.1", my.port)
+    mysql.sock.settimeout(1100)  # a cold SF10 statement compiles for minutes
+    web = http.client.HTTPConnection("127.0.0.1", ht.port, timeout=1100)
+
+    def send(door: str, sql: str) -> list:
+        if door == "mysql":
+            return mysql.query(sql)[1]
+        web.request("POST", "/query", json.dumps({"sql": sql}),
+                    {"Content-Type": "application/json"})
+        resp = web.getresponse()
+        body = resp.read()
+        if resp.status != 200:
+            raise RuntimeError(f"http {resp.status}: {body[:300]!r}")
+        return [tuple(r) for r in json.loads(body)["rows"]]
+
+    statements = []  # [(record, qid, key, rows of the last send)]
+    seen: set = set()  # queries this tier has already compiled
+    try:
+        for door, qid, key in STATEMENTS:
+            name = f"{door}:q{qid}"
+            sends, rows = [], None
+            while len(sends) < 2 or (sends[-1]["compiles"]
+                                     and len(sends) < MAX_SENDS):
+                c0, r0 = PROGRAM_COMPILES.value, RECOMPILES.value
+                t0 = time.monotonic()
+                got = send(door, QUERIES[qid])
+                sends.append({
+                    "seconds": round(time.monotonic() - t0, 3),
+                    "compiles": int(PROGRAM_COMPILES.value - c0),
+                    "recompiles": int(RECOMPILES.value - r0)})
+                if rows is not None and got != rows:
+                    failures.append(f"{name}: send {len(sends)} returned "
+                                    "other rows than send 1")
+                rows = got
+                print(f"sent {name} #{len(sends)} rows={len(got)} "
+                      f"{json.dumps(sends[-1])}")
+            if qid not in seen and not sends[0]["compiles"]:
+                failures.append(f"{name}: first send compiled no program")
+            if sends[-1]["compiles"] or sends[-1]["recompiles"]:
+                failures.append(
+                    f"{name}: send {len(sends)} still compiled "
+                    f"({sends[-1]['compiles']} programs)")
+            seen.add(qid)
+            statements.append(
+                ({"statement": name, "sends": sends}, qid, key, rows))
+
+        # what the statements left on the device, before anything is freed
+        resident = session.cache.resident_arrays()
+        on = {d for _, a in resident for d in a.devices()}
+        off = sorted({str(k[:3]) for k, a in resident
+                      if any(d.platform != device["platform"]
+                             for d in a.devices())})
+        if off:
+            failures.append(f"cached columns not on {device['platform']} "
+                            f"devices: {off[:5]}")
+        want = {(t, c) for t, cols in QUERY_COLUMNS.items() for c in cols}
+        missing = sorted(want - {(k[0], k[1]) for k, _ in resident})
+        if missing:
+            failures.append(f"query columns not device-resident: {missing}")
+        resident_bytes = sum(a.nbytes for _, a in resident)
+        print(f"resident arrays={len(resident)} bytes={resident_bytes} "
+              f"devices={sorted(d.id for d in on)}")
+        if chips > 1:
+            li = [a for k, a in resident
+                  if (k[0], k[1]) == ("lineitem", "l_extendedprice")]
+            if not li or any(len(a.devices()) != chips for a in li):
+                failures.append(
+                    f"lineitem.l_extendedprice does not span {chips} "
+                    f"devices: {[len(a.devices()) for a in li]}")
+        peaks = []
+        for d in devices[:chips]:
+            stats = d.memory_stats()
+            peak = (stats or {}).get("peak_bytes_in_use")
+            peaks.append(peak)
+            print(f"memory device={d.id} peak_bytes_in_use={peak} "
+                  f"stats={json.dumps(stats)}")
+            if peak is None:
+                # the CPU backend keeps no statistics; a TPU must
+                if d.platform == "tpu":
+                    failures.append(f"device {d.id}: no peak_bytes_in_use")
+            elif peak < resident_bytes // chips:
+                failures.append(
+                    f"device {d.id}: peak_bytes_in_use {peak} is below its "
+                    f"share of the {resident_bytes} resident bytes")
+    finally:
+        mysql.sock.close()
+        web.close()
+        my.shutdown()
+        ht.stop()
+
+    # oracles, outside anything timed above
+    t0 = time.monotonic()
+    frames = _oracle_frames(catalog)
+    expected: dict = {}  # qid -> oracle rows (Q1 is asked through two doors)
+    for st, qid, key, rows in statements:
+        if qid not in expected:
+            expected[qid] = [tuple(r) for r in getattr(
+                tpch_oracle, f"q{qid}")(frames).itertuples(index=False)]
+        exp = expected[qid]
+        diff = _first_mismatch(rows, exp, key)
+        st["oracle_match"] = diff is None
+        if diff is not None:
+            failures.append(f"{st['statement']}: {diff}")
+        first, last = st["sends"][0], st["sends"][-1]
+        print(f"statement {st['statement']} rows={len(exp)} "
+              f"oracle_match={st['oracle_match']} "
+              f"first_run_s={first['seconds']} "
+              f"first_run_compiles={first['compiles']}"
+              f"+{first['recompiles']}re "
+              f"sends={len(st['sends'])} last_run_s={last['seconds']} "
+              f"last_run_compiles={last['compiles']}")
+    print(f"oracle seconds={time.monotonic() - t0:.1f}")
+
+    for f in failures:
+        print(f"FAILED {f}")
+    return {"ok": not failures, "failures": failures, "device": device,
+            "sf": sf, "seed": seed, "chips": chips,
+            "statements": [st for st, *_ in statements],
+            "resident_bytes": resident_bytes, "peak_bytes_in_use": peaks}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4 = the same statements through "
+                         "Session(dist_shards=4) on a four-chip host")
+    ap.add_argument("--sf", type=float, default=10.0,
+                    help="TPC-H scale factor (the deployment is SF10)")
+    ap.add_argument("--seed", type=int, default=42)
+    args = ap.parse_args()
+
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"chip_smoke: needs a TPU, but JAX's default backend is "
+              f"{backend!r} (JAX_PLATFORMS="
+              f"{os.environ.get('JAX_PLATFORMS', '<unset>')})",
+              file=sys.stderr)
+        return 1
+    # a hung chip becomes a traceback and a non-zero exit inside the limit
+    faulthandler.dump_traceback_later(1170, exit=True)
+    res = run(args.sf, args.chips, args.seed)
+    if not res["ok"]:
+        return 1
+    print(json.dumps({"ok": True, "device": res["device"]}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -19,10 +19,24 @@ Subpackages
 - ``runtime``   session, executor, profile, config (reference: be/src/common/, exec/runtime/)
 """
 
+import os
+
 import jax
 
 # The engine needs 64-bit ints for DECIMAL arithmetic (scaled int64) and
 # DATETIME microseconds; enable before any tracing happens.
 jax.config.update("jax_enable_x64", True)
+
+# Persistent XLA compile cache, placed here and nowhere else: engine,
+# servers, tests and tools all import this package before they compile.
+# JAX reads JAX_COMPILATION_CACHE_DIR itself, so when it is set no code
+# names another directory; otherwise the one fixed, normalised path
+# <checkout>/.xla_cache (a cache in a directory that moves never hits).
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".xla_cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 __version__ = "0.1.0"

@@ -35,7 +35,6 @@ HOST_LOOP_KNOBS = {
     "profile_queries": "host-side profile collection toggle",
     "bench_sf": "bench harness input sizing",
     "chunk_align": "immutable; baked into every capacity everywhere",
-    "compilation_cache_dir": "immutable process-level XLA cache wiring",
     "query_queue_timeout_s": "admission control, pre-planning",
     "default_agg_groups": "capacity default; caps dict keys the programs",
     "plan_verify_level": "the verifier's own knob (host-side)",

@@ -609,6 +609,26 @@ def _compare_dec128(cc, a: EVal, b: EVal, op):
     return EVal(res, _and_valid(a.valid, b.valid), T.BOOLEAN)
 
 
+def _exact_decimal_literal(lit: EVal, col_type) -> EVal | None:
+    """A concrete float literal as an exact DECIMAL literal for comparison
+    with a `col_type` (DECIMAL64) column, or None when it has no exact
+    decimal form the column can be widened to inside 18 digits. Comparing as
+    scaled integers is exact on every backend; the alternative casts the
+    COLUMN to DOUBLE by a float64 division, which a TPU emulates without
+    correct rounding (on a v5e 5/100.0 < 0.05, so TPC-H Q6's `l_discount
+    between 0.05 and 0.07` silently lost every 0.05 row)."""
+    if not (lit.type.is_float and np.ndim(lit.data) == 0
+            and not isinstance(lit.data, jnp.ndarray)):
+        return None
+    x = float(lit.data)
+    for s in range(col_type.scale,
+                   col_type.scale + 18 - col_type.precision + 1):
+        iv = int(round(x * 10 ** s))
+        if iv / 10 ** s == x and abs(iv) < 10 ** 18:
+            return EVal(iv, None, T.DECIMAL(18, s))
+    return None
+
+
 def _compare(cc, a, b, op):
     _dec128_guard(a, b)
     if a.type.is_decimal128 or b.type.is_decimal128:
@@ -616,6 +636,10 @@ def _compare(cc, a, b, op):
     a, b = _promote_temporal_literals(a, b)
     if a.type.is_string or b.type.is_string:
         return _compare_strings(cc, a, b, op)
+    if a.type.is_decimal and b.type.is_float:
+        b = _exact_decimal_literal(b, a.type) or b
+    elif b.type.is_decimal and a.type.is_float:
+        a = _exact_decimal_literal(a, b.type) or a
     ct = _common(a, b)
     if ct.is_decimal:
         # compare at the max scale of both sides

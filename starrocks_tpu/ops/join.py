@@ -29,6 +29,7 @@ from ..column.column import Chunk, Field, Schema
 from ..exprs.compile import ExprCompiler
 from ..exprs.ir import Col
 from .common import eval_keys, mix64
+from .segment import on_tpu
 
 INNER = "inner"
 LEFT_OUTER = "left_outer"
@@ -167,6 +168,16 @@ def pack_key_pair(probe: Chunk, build: Chunk, probe_keys, build_keys,
     return pk, p_ok, bk, b_ok
 
 
+def _or_across_shards(lanes, axis: str):
+    """Bitwise OR of per-shard uint8 0/1 lanes (the global-RF collective of
+    the dense bitmap and the bloom bitset), as a 32-bit sum. NOT
+    `lax.pmax` on uint8: on a four-chip v5e an 8-bit max all-reduce returned
+    wrong lanes (57,956 of 65,536 differed from numpy) and TPC-H Q3's
+    runtime filter dropped matching rows; 32-bit all-reduces were exact."""
+    return jnp.asarray(
+        jax.lax.psum(jnp.asarray(lanes, jnp.int32), axis) > 0, jnp.uint8)
+
+
 def runtime_filter_mask(
     probe: Chunk, build: Chunk, probe_keys, build_keys, bit_widths=None,
     axis: str | None = None, dense_range: tuple | None = None,
@@ -178,12 +189,13 @@ def runtime_filter_mask(
     the same program. Two strengths:
 
     - min/max range filter (always available); with `axis` the local bounds
-      merge across shards via pmin/pmax — the global-RF collective.
+      merge across shards (all_gather + local min/max) — the global-RF
+      collective.
     - EXACT membership (IN-set) filter when the planner bounds the key range
       via catalog stats (`dense_range=(lo, hi)`): build keys scatter into a
       dense presence bitmap the probe gathers; with `axis` the bitmaps
-      OR-merge across shards (pmax). Subsumes min/max — e.g. a filtered
-      dimension build passes only its surviving keys.
+      OR-merge across shards (`_or_across_shards`). Subsumes min/max —
+      e.g. a filtered dimension build passes only its surviving keys.
 
     Only valid for INNER/LEFT SEMI joins (probe rows may be dropped)."""
     pk, p_ok, bk, b_ok = pack_key_pair(
@@ -195,7 +207,7 @@ def runtime_filter_mask(
             jnp.where(b_ok, bk - lo, size)
         ].set(1, mode="drop")
         if axis is not None:
-            present = jax.lax.pmax(present, axis)  # bitmap OR across shards
+            present = _or_across_shards(present, axis)
         idx = pk - lo
         in_range = (idx >= 0) & (idx < size)
         hit = present[jnp.clip(idx, 0, size - 1)] == 1
@@ -203,8 +215,11 @@ def runtime_filter_mask(
     bmin = jnp.min(jnp.where(b_ok, bk, _I64MAX))
     bmax = jnp.max(jnp.where(b_ok, bk, jnp.iinfo(jnp.int64).min))
     if axis is not None:
-        bmin = jax.lax.pmin(bmin, axis)
-        bmax = jax.lax.pmax(bmax, axis)
+        # gather the n per-shard bounds and reduce locally: the TPU compiler
+        # lowers a 64-bit all-reduce for sums only (int64 pmin/pmax raise
+        # "Supported lowering only of Sum all reduce" on a v5e)
+        bmin = jnp.min(jax.lax.all_gather(bmin, axis))
+        bmax = jnp.max(jax.lax.all_gather(bmax, axis))
     # All-NULL (or empty) build side: bmin stays I64MAX and bmax stays
     # I64MIN, so bmin > bmax and the conjunction below is ALL-FALSE. That is
     # the intended INNER/LEFT-SEMI semantics — an empty build key set
@@ -221,10 +236,11 @@ _BLOOM_SALT = 0x9E3779B97F4A7C15  # golden-ratio odd constant (2nd probe)
 
 def bloom_build_bitset(bk, b_ok, bits: int, axis: str | None = None):
     """Build-side half of the bloom runtime filter: hash packed keys into a
-    power-of-2 bit array (one uint8 lane per bit — the gather/pmax-friendly
+    power-of-2 bit array (one uint8 lane per bit — the gather-friendly
     layout the dense bitmap already uses) via TWO independent splitmix64
-    probes. With `axis` the bitsets OR-merge across shards (pmax), exactly
-    like the dense presence bitmap — the global-RF collective."""
+    probes. With `axis` the bitsets OR-merge across shards
+    (`_or_across_shards`), exactly like the dense presence bitmap — the
+    global-RF collective."""
     assert bits & (bits - 1) == 0, "bloom bit count must be a power of 2"
     mask = jnp.uint64(bits - 1)
     h1 = mix64(jnp.asarray(bk, jnp.int64).view(jnp.uint64))
@@ -237,7 +253,7 @@ def bloom_build_bitset(bk, b_ok, bits: int, axis: str | None = None):
         .at[i2].set(1, mode="drop")
     )
     if axis is not None:
-        bitset = jax.lax.pmax(bitset, axis)  # bitwise OR across shards
+        bitset = _or_across_shards(bitset, axis)
     return bitset
 
 
@@ -312,17 +328,17 @@ def _probe_block(n: int) -> int:
 def _probe_searchsorted(bk_sorted, pk):
     """The unique-join probe ladder, flag-routable onto the explicit
     Pallas kernel (`SET join_probe_strategy = 'pallas_sorted'`;
-    ops/pallas_kernels.probe_searchsorted_pallas — interpret mode on CPU,
-    compiled on TPU). Default: jnp.searchsorted (XLA's own ladder)."""
+    ops/pallas_kernels.probe_searchsorted_pallas — interpret mode off-TPU;
+    on a TPU Mosaic does not lower its int64 refs yet, so the strategy
+    fails loudly there). Default: jnp.searchsorted (XLA's own ladder)."""
     from ..runtime.config import config as _cfg
 
     if _cfg.get("join_probe_strategy") == "pallas_sorted":
         from .pallas_kernels import probe_searchsorted_pallas
 
-        interpret = jax.default_backend() != "tpu"
         return probe_searchsorted_pallas(
             bk_sorted, pk, block=_probe_block(int(pk.shape[0])),
-            interpret=interpret)
+            interpret=not on_tpu())
     return jnp.searchsorted(bk_sorted, pk)
 
 
@@ -337,7 +353,7 @@ def hash_probe_rows(bk, pk, bcap: int, p_ok):
     from .pallas_kernels import hash_build_pallas, hash_probe_pallas
 
     table_size = 1 << (max(2 * bcap, 16) - 1).bit_length()
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     tkey, trow = hash_build_pallas(bk, table_size, interpret=interpret)
     row = hash_probe_pallas(
         tkey, trow, pk, block=_probe_block(int(pk.shape[0])),

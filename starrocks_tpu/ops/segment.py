@@ -175,23 +175,35 @@ def _seg_minmax_bcast(vals, gid, num_groups: int, is_min: bool, identity):
     return (jnp.min if is_min else jnp.max)(masked, axis=0)
 
 
+def on_tpu() -> bool:
+    """The one backend rule of the engine: Pallas kernels compile through
+    Mosaic, the scatter-free segment strategies are `auto`'s choice and the
+    planner's dense-aggregation domain is tight exactly when the default
+    backend is a TPU. Any other backend (the CPU of the test suite)
+    interprets the kernels and takes the scatter-friendly choices."""
+    return jax.default_backend() == "tpu"
+
+
 def _seg_sum_pallas(vals, gid, num_groups: int):
     """Float segment sums through the explicit Pallas kernel
     (ops/pallas_kernels.py): one-hot tiles in VMEM, partial sums on the MXU.
-    Flag-gated via segment_strategy=pallas; interpret mode on CPU keeps the
+    Flag-gated via segment_strategy=pallas; interpret mode off-TPU keeps the
     path correctness-testable without hardware. f32 accumulation — callers
-    gate exact (int/decimal) sums away from it. Returns None when the shape
-    doesn't block-divide (caller falls through to the default strategy)."""
+    gate exact (int/decimal) sums away from it. A row count that does not
+    block-divide raises: an explicitly chosen strategy never quietly gives
+    way to another."""
     n = vals.shape[0]
     block = min(n & -n, 2048)
     if block < 8:
-        return None
+        raise ValueError(
+            f"segment_strategy=pallas needs a row count divisible by 8, "
+            f"got {n}")
     from .pallas_kernels import segment_sum_pallas
 
     g = jnp.clip(jnp.asarray(gid, jnp.int32), 0, num_groups)
     out = segment_sum_pallas(
         g, jnp.asarray(vals, jnp.float32)[:, None], num_groups, block=block,
-        interpret=jax.default_backend() == "cpu",
+        interpret=not on_tpu(),
     )
     return jnp.asarray(out[:, 0], vals.dtype)
 
@@ -199,18 +211,18 @@ def _seg_sum_pallas(vals, gid, num_groups: int):
 def _use_mxu() -> bool:
     """True when the scatter-free (matmul / broadcast / scan) strategies
     should be used.  They exist because TPU scatters serialize on duplicate
-    indices; on the CPU fallback backend a plain scatter is 100-1000x FASTER
-    than the one-hot matmul (measured: 1.2M rows x 1024 groups = 1.1ms
-    scatter vs >1s matmul), so `auto` picks by compile-time backend.
-    `segment_strategy` config: auto | mxu | scatter (tests pin `mxu` to keep
-    the strategy branches covered on CPU)."""
+    indices; on the CPU backend a plain scatter is 100-1000x FASTER than the
+    one-hot matmul (CPU run: 1.2M rows x 1024 groups = 1.1ms scatter vs >1s
+    matmul), so `auto` picks by backend (`on_tpu`). `segment_strategy`
+    config: auto | mxu | scatter (tests pin `mxu` to keep the strategy
+    branches covered on CPU)."""
     from ..runtime.config import config
 
     if not config.get("enable_scatter_free_segments"):
         return False
     s = config.get("segment_strategy")
     if s == "auto":
-        return jax.default_backend() not in ("cpu",)
+        return on_tpu()
     # "pallas" only reroutes float sums; every other reduction must keep
     # its scatter-free strategy (degrading them to scatters would make the
     # pallas A/B benchmark measure scatter serialization instead)
@@ -241,9 +253,7 @@ def seg_sum(vals, gid, num_groups: int, *, sorted_gid: bool = False,
     if (_cfg.get("segment_strategy") == "pallas"
             and not jnp.issubdtype(vals.dtype, jnp.integer)
             and num_groups <= _matmul_groups_max()):
-        out = _seg_sum_pallas(vals, gid, num_groups)
-        if out is not None:
-            return out
+        return _seg_sum_pallas(vals, gid, num_groups)
     if _use_mxu():
         if jnp.issubdtype(vals.dtype, jnp.integer):
             v64 = jnp.asarray(vals, jnp.int64)

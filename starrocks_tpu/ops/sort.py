@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from ..column.column import Chunk, pad_capacity
 from .common import eval_keys
+from .segment import on_tpu
 
 _I64MAX = jnp.iinfo(jnp.int64).max
 
@@ -180,7 +181,7 @@ def topn_order(packed, kk: int):
         from .pallas_kernels import topn_select_pallas
 
         cv, ci = topn_select_pallas(
-            neg, kk, interpret=jax.default_backend() != "tpu")
+            neg, kk, interpret=not on_tpu())
         _, pos = jax.lax.top_k(cv, kk)
         return ci[pos]
     _, idx = jax.lax.top_k(neg, kk)

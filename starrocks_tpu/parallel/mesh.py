@@ -19,23 +19,12 @@ from ..column.column import Chunk, pad_capacity
 
 DATA_AXIS = "d"
 
-try:  # jax >= 0.6 exports shard_map at top level with check_vma
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental home, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map (the engine always disables the
-    replication/VMA check: overflow-check outputs are deliberately
-    per-shard). Single import point for engine + tests."""
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: check_vma})
+    """jax.shard_map with the replication/VMA check off (the engine always
+    disables it: overflow-check outputs are deliberately per-shard).
+    Single import point for engine + tests (tools/src_lint.py R1)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(n_devices: int | None = None, axis: str = DATA_AXIS) -> Mesh:

@@ -44,6 +44,12 @@ big-endian lengths): a JSON header frame and a pickle payload frame.
 Chunk/HostTable payloads are numpy-backed pytrees, so the pickle body
 IS the columnar batch. The plane is trusted-transport only (pickle over
 loopback/LAN between processes this module itself spawned).
+
+A CPU-process plane by construction: a chip belongs to one process, so
+every worker is spawned with an explicit JAX_PLATFORMS=cpu environment
+(spawn_worker) and never reaches for the coordinator's accelerator. On a
+chip the single process that owns the mesh runs the in-mesh path
+(dist_executor.py); what this plane is for there is ROADMAP C4.
 """
 
 from __future__ import annotations

@@ -336,10 +336,6 @@ config.define("join_recursive_repartition", True, True,
               "partitioning decision only — compiled partition programs "
               "key on the resulting capacities, so this needs no trace "
               "channel (HOST_LOOP_KNOBS)")
-config.define("compilation_cache_dir", "", False,
-              "persistent XLA compilation cache directory (survives process "
-              "restarts; big win for TPU first-compiles). Set via "
-              "SR_TPU_COMPILATION_CACHE_DIR.")
 config.define("plan_verify_level", "off", True,
               "off | warn | strict: static invariant verification of every "
               "optimized plan and freshly-compiled program "
@@ -400,18 +396,3 @@ config.define("plan_verify_trace", True, True,
               "on at warn/strict)")
 config.load_env()
 
-
-def _wire_compilation_cache(path: str):
-    if not path:
-        return
-    import jax as _jax
-
-    import os as _os
-
-    _os.makedirs(path, exist_ok=True)
-    _jax.config.update("jax_compilation_cache_dir", path)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
-
-config.on_set("compilation_cache_dir", _wire_compilation_cache)

@@ -37,6 +37,7 @@ from . import lifecycle
 from .config import config
 from .executor import Executor
 from .failpoint import fail_point
+from .metrics import PROGRAM_COMPILES
 from .profile import RuntimeProfile
 
 
@@ -369,6 +370,8 @@ class DistExecutor(Executor):
             bucket, tuple(sorted(caps.values.items())))
         raw = reads = None
         if hit is None:
+            PROGRAM_COMPILES.inc()
+            p.add_counter("compiles", 1)
             fail_point("executor::before_compile")
             lifecycle.checkpoint("executor::before_compile")
             # per-fragment compile vs execute split: the trace happens

@@ -150,6 +150,14 @@ class DeviceCache:
         with self._lock:
             return self._caps.setdefault(key, default)
 
+    def resident_arrays(self) -> list:
+        """Snapshot [(cache key, device array)] of every cached device
+        buffer (column data, valid masks, selection masks, build orders) —
+        what chip_smoke.py inspects for placement and resident bytes."""
+        with self._lock:
+            return [(k, a) for k, entry in self._cols.items()
+                    for a in entry if isinstance(a, jax.Array)]
+
     def program_bucket(self, key):
         from .udf import registry_epoch
 

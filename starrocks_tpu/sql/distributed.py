@@ -602,9 +602,10 @@ def compile_distributed(
                             break
 
             # build-side runtime filter on the probe; with a sharded build
-            # the local summaries merge across shards — pmin/pmax for the
-            # range filter, bitset pmax (bitwise OR) for the dense bitmap
-            # AND the bloom bitset (the global-RF collective). Strategy
+            # the local summaries merge across shards — gathered min/max for
+            # the range filter, a bitwise OR (ops/join._or_across_shards)
+            # for the dense bitmap AND the bloom bitset (the global-RF
+            # collective; neither uses pmin/pmax, see ops/join.py). Strategy
             # ladder matches the single-chip compiler: dense > bloom >
             # min/max per `runtime_filter_strategy`.
             from ..runtime.config import config as _cfg

@@ -45,7 +45,7 @@ def _dense_agg_domain_max(cfg) -> int:
     """Largest group-key domain the planner will cover with a dense packed-gid
     capacity. 0 (default) = auto: generous on CPU (scatters are cheap), tight
     on TPU (wide segment reduces cost HBM bandwidth; the lexsort path wins)."""
-    import jax
+    from ..ops.segment import on_tpu
 
     v = cfg.get("dense_agg_domain_max")
     if v:
@@ -53,7 +53,7 @@ def _dense_agg_domain_max(cfg) -> int:
     # CPU: must cover the TPC-H-scale dense PK domains (l_orderkey at SF1 is
     # 6M) — a 6M-slot scatter-add is ~10ms there while the lexsort
     # alternative is seconds (argsort is single-threaded in XLA CPU)
-    return (1 << 24) if jax.default_backend() == "cpu" else 4096
+    return 4096 if on_tpu() else (1 << 24)
 
 
 # --- plan properties ---------------------------------------------------------

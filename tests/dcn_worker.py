@@ -69,7 +69,7 @@ def main():
         part = jnp.sum(jnp.where(oh, v[:, None], 0.0), axis=0)
         return jax.lax.psum(part, "dp")
 
-    from jax.experimental.shard_map import shard_map
+    from starrocks_tpu.parallel.mesh import shard_map
 
     fn = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("dp"), P("dp")),
                            out_specs=P()))

@@ -1,0 +1,41 @@
+"""chip_smoke.py's own logic, exercised on CPU before chip time is spent:
+the entry point refuses anything but a TPU, and its body — the served path
+(MySQL + HTTP doors over one tier), the oracle comparison and the compile
+accounting — passes at SF0.01."""
+
+import os
+import subprocess
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_refuses_cpu_backend():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    t0 = time.monotonic()
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert "'cpu'" in out.stderr, out.stderr[-2000:]
+    # refused before any data was generated, and no result line printed
+    assert time.monotonic() - t0 < 60
+    assert "generated" not in out.stdout and '"ok"' not in out.stdout
+
+
+def test_run_passes_on_cpu_at_small_scale():
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    res = chip_smoke.run(sf=0.01, chips=1, seed=7)
+    assert res["ok"], res["failures"]
+    assert res["device"]["platform"] == "cpu"
+    by_name = {s["statement"]: s for s in res["statements"]}
+    assert set(by_name) == {"mysql:q1", "mysql:q6", "mysql:q3", "http:q1"}
+    for s in by_name.values():
+        assert s["oracle_match"] and s["sends"][-1]["compiles"] == 0
+    # a statement new to the tier compiles; the same statement through the
+    # other door reuses the tier's program
+    assert by_name["mysql:q1"]["sends"][0]["compiles"] >= 1
+    assert by_name["http:q1"]["sends"][0]["compiles"] == 0
+    assert res["resident_bytes"] > 0

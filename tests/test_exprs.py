@@ -40,6 +40,25 @@ def test_arithmetic_ints():
     assert _vals(c, sub(col("b"), col("a")), 3) == [9, 18, 27]
 
 
+def test_decimal_vs_float_literal_compares_as_integers():
+    """`l_discount between 0.05 and 0.07` (TPC-H Q6): a DECIMAL column
+    against a float literal compares as scaled integers — no float64 in the
+    traced program. Casting the column to DOUBLE divides by 10^scale in
+    float64, which a TPU emulates without correct rounding (a v5e answered
+    Q6 28% low: every l_discount = 0.05 row failed `>= 0.05`)."""
+    c = _chunk(disc=[0.04, 0.05, 0.06, 0.07, 0.08],
+               __types={"disc": T.DECIMAL(15, 2)})
+    e = between(col("disc"), lit(0.05), lit(0.07))
+    jaxpr = jax.make_jaxpr(lambda ch: eval_expr(ch, e).data)(c)
+    assert "f64" not in str(jaxpr)
+    assert _vals(c, e, 5) == [False, True, True, True, False]
+    # a literal finer than the column's scale widens the column instead
+    assert _vals(c, lt(col("disc"), lit(0.055)), 5) == [
+        True, True, False, False, False]
+    # one with no exact decimal form keeps the DOUBLE comparison
+    assert _vals(c, lt(col("disc"), lit(0.1 + 0.2)), 5) == [True] * 5
+
+
 def test_divide_null_on_zero():
     c = _chunk(a=[10, 20, 30], b=[2, 0, 5])
     out = _vals(c, div(col("a"), col("b")), 3)

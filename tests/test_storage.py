@@ -162,11 +162,34 @@ def test_backup_restore(tmp_path):
         restore(d2, d3)  # non-empty target rejected
 
 
-def test_compilation_cache_config(tmp_path, monkeypatch):
-    # the knob exists and is wired (full restart-effect is covered on TPU)
-    from starrocks_tpu.runtime.config import config
+def test_compilation_cache_placement(tmp_path):
+    """Both placement rules of the persistent XLA cache, each in a fresh
+    process after the whole engine is imported: JAX_COMPILATION_CACHE_DIR
+    set -> that directory and no other; unset -> the one normalised fixed
+    path <checkout>/.xla_cache."""
+    import subprocess
+    import sys
 
-    assert any(n == "compilation_cache_dir" for n, *_ in config.items())
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".xla_cache")
+    probe = ("import jax, starrocks_tpu, starrocks_tpu.runtime.session; "
+             "print(jax.config.jax_compilation_cache_dir)")
+
+    def cache_dir(env_dir):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["JAX_PLATFORMS"] = "cpu"
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        out = subprocess.run([sys.executable, "-c", probe], cwd=repo,
+                             env=env, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.strip().splitlines()[-1]
+
+    assert cache_dir(str(tmp_path)) == str(tmp_path)
+    got = cache_dir(None)
+    assert got == fixed and got == os.path.normpath(got)
 
 
 # --- round 3: partitions, compaction, PK delta path --------------------------

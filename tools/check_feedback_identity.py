@@ -37,10 +37,6 @@ def main() -> int:
     from starrocks_tpu.sql.optimizer import optimize
     from starrocks_tpu.sql.parser import parse
 
-    if not config.get("compilation_cache_dir"):
-        config.set("compilation_cache_dir", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".xla_cache"), force=True)
     config.set("plan_feedback", True)
 
     t0 = time.time()

@@ -53,11 +53,12 @@ def bench_probe_strategies(n_probe: int, n_build: int, repeats: int) -> dict:
 
     from starrocks_tpu.ops.join import hash_probe_rows
     from starrocks_tpu.ops.pallas_kernels import probe_searchsorted_pallas
+    from starrocks_tpu.ops.segment import on_tpu
 
     rng = np.random.default_rng(7)
     bk = jnp.asarray(rng.permutation(n_build * 4)[:n_build].astype(np.int64))
     pk = jnp.asarray(rng.integers(0, n_build * 4, n_probe).astype(np.int64))
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
 
     @jax.jit
     def sorted_path(bk, pk):

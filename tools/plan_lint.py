@@ -70,12 +70,6 @@ def lint_corpus(which: str = "all", verbose: bool = False,
         # (result-key completeness audit of the real knob read-set) and
         # the validated-hit path run under strict
         config.set("enable_query_cache", True)
-    if not config.get("compilation_cache_dir"):
-        # share the tier-1 suite's persistent XLA cache: lint re-traces
-        # every program (that is the point) but compiles stay warm
-        config.set("compilation_cache_dir", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".xla_cache"), force=True)
 
     t0 = time.time()
     n_queries = errors = 0
@@ -154,10 +148,6 @@ def lint_fragments(which: str = "all", verbose: bool = False) -> int:
     D.SHARD_THRESHOLD_ROWS = 10_000
     D.SHUFFLE_AGG_MIN_GROUPS = 4_000
     config.set("plan_verify_level", "strict")
-    if not config.get("compilation_cache_dir"):
-        config.set("compilation_cache_dir", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".xla_cache"), force=True)
 
     t0 = time.time()
     n_queries = errors = mismatches = 0

@@ -58,7 +58,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tests"))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# The backend is whatever JAX_PLATFORMS in the environment selects (a TPU
+# where there is one; tools/run_tier1.sh passes JAX_PLATFORMS=cpu): no code
+# here names a platform. Every summary carries the platform it ran on.
 
 
 # --- query mix ----------------------------------------------------------------
@@ -804,9 +806,6 @@ def run_serve_bench(threads: int = 32, seconds: float = 8.0,
                     chaos: bool = False, single_thread_ab: bool = True,
                     warm: bool = True, feedback: bool = True,
                     points: bool = True, obs: bool = True) -> dict:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from starrocks_tpu import lockdep
     from starrocks_tpu.runtime import failpoint
     from starrocks_tpu.runtime.config import config
@@ -845,7 +844,7 @@ def run_serve_bench(threads: int = 32, seconds: float = 8.0,
     out: dict = {
         "threads": threads, "seconds": seconds, "sf": sf, "pool": pool,
         "statements": len(statements), "mix": "zipf-1.1",
-        "backend": jax.devices()[0].platform,
+        "backend": _backend(),
         # pool speedup is bounded by host cores: on a 1-core box the A/B
         # signal is queue-wait collapse, not QPS (see BENCH_DETAIL notes)
         "host_cpus": os.cpu_count(),
@@ -948,7 +947,10 @@ def run_serve_bench(threads: int = 32, seconds: float = 8.0,
 def run_cluster_phase(workers: int = 2, clients: int = 4,
                       seconds: float = 8.0) -> dict:
     """--cluster: N client threads against a coordinator + M worker
-    PROCESSES (runtime/cluster_exec.py), two timed windows:
+    PROCESSES (runtime/cluster_exec.py), two timed windows. The cluster
+    runtime is a CPU-process plane (each worker is pinned to
+    JAX_PLATFORMS=cpu; a chip belongs to one process), so run this phase
+    with JAX_PLATFORMS=cpu: its figures are CPU figures.
 
       steady — every client fires fragment queries against the healthy
         fleet (each answer checked against a pre-cluster local oracle).
@@ -1115,6 +1117,12 @@ def run_cluster_phase(workers: int = 2, clients: int = 4,
     return out
 
 
+def _backend() -> str:
+    import jax
+
+    return jax.devices()[0].platform
+
+
 def main():
     ap = argparse.ArgumentParser(
         description="sustained mixed-workload serving benchmark")
@@ -1151,7 +1159,8 @@ def main():
                     help="run ONLY the cluster phase: clients against a "
                          "coordinator + worker PROCESSES with a "
                          "kill-one-worker window (retry latency + "
-                         "post-kill p99)")
+                         "post-kill p99); a CPU-process plane — run it "
+                         "with JAX_PLATFORMS=cpu")
     ap.add_argument("--cluster-workers", type=int, default=2,
                     help="worker processes for --cluster")
     ap.add_argument("--cluster-clients", type=int, default=4,
@@ -1164,6 +1173,7 @@ def main():
         res = run_cluster_phase(workers=args.cluster_workers,
                                 clients=args.cluster_clients,
                                 seconds=args.seconds)
+        res["backend"] = _backend()
         if args.detail:
             path = os.path.join(REPO, "BENCH_DETAIL.json")
             detail = {}
@@ -1177,26 +1187,19 @@ def main():
         return 0 if res["cluster_pass"] else 1
 
     if args.points:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        res = {"points": run_point_phase(seconds=args.seconds)}
+        res = {"points": run_point_phase(seconds=args.seconds),
+               "backend": _backend()}
         print(json.dumps(res))
         return 0
 
     if args.obs:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        res = {"obs": run_obs_phase()}
+        res = {"obs": run_obs_phase(), "backend": _backend()}
         print(json.dumps(res))
         return 0 if res["obs"]["obs_pass"] else 1
 
     if args.ingest:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        res = {"ingest": run_ingest_phase(seconds=args.seconds)}
+        res = {"ingest": run_ingest_phase(seconds=args.seconds),
+               "backend": _backend()}
         if args.detail:
             path = os.path.join(REPO, "BENCH_DETAIL.json")
             detail = {}

@@ -5,9 +5,9 @@ Four rules, all for bug classes that pass every unit test and then burn
 on real hardware (or real traffic):
 
 R1 shard-map-shim: `shard_map` must be imported from parallel/mesh.py (the
-   version shim that handles the jax>=0.6 move and the check_vma/check_rep
-   rename), never from jax directly. A bare import works on exactly one jax
-   version.
+   single import point, which turns the replication/VMA check off the way
+   every engine program needs), never from jax directly — so a jax move or
+   kwarg rename is one edit.
 
 R2 traced-host-op: inside TRACED scopes — functions handed to jax.jit /
    shard_map, and the program closures built by compile_plan /
