@@ -1,0 +1,12 @@
+"""Device milliseconds per statement of the traced slice in operations under
+a join's scope, `sr.join`, whatever the phase (`build`, `probe`, `expand`,
+`payload`, `rf`, `compact` or none)."""
+
+from benchmarks.harness import scopes
+
+META = {"layer": "kernels", "unit": "ms", "better": "lower",
+        "source": "device_trace", "moves": "lat_geomean_ms"}
+
+
+def compute(run):
+    return scopes.kind_ms(run, "join")

@@ -1,0 +1,11 @@
+"""Device milliseconds per statement of the traced slice in operations under
+`sr.sort`, `sr.limit` or `sr.window`."""
+
+from benchmarks.harness import scopes
+
+META = {"layer": "kernels", "unit": "ms", "better": "lower",
+        "source": "device_trace", "moves": "lat_geomean_ms"}
+
+
+def compute(run):
+    return scopes.kind_ms(run, "sort")

@@ -51,10 +51,6 @@ HOST_LOOP_KNOBS = {
     "join_recursive_repartition":
         "host-side hybrid-join partitioning decision; the sub-partition "
         "capacities it produces key the compiled partition programs",
-    "enable_device_profile":
-        "host-side AOT cost/memory introspection attached to the "
-        "RuntimeProfile after the traced call; never reaches the trace "
-        "or result bytes",
 }
 
 # Knobs that shape the OPTIMIZED PLAN (read during optimize(), not during

@@ -28,7 +28,7 @@ from .. import types as T
 from ..column.column import Chunk, Field, Schema
 from ..exprs.compile import EVal, ExprCompiler
 from ..exprs.ir import AggExpr, Col, Expr
-from .common import boundaries, eval_keys, key_sort_arrays
+from .common import boundaries, eval_keys, key_sort_arrays, phase
 from .segment import (
     _group_bounds_sorted, seg_count, seg_first_index, seg_max, seg_min,
     seg_sum,
@@ -745,14 +745,16 @@ def hash_aggregate(
             # stable single-key argsort: within-group row order matches the
             # lexsort path's, so float accumulation order (and thus exact
             # results) is identical
-            order = jnp.argsort(packed)
+            with phase("lexsort"):
+                order = jnp.argsort(packed)
             pk_s = packed[order]
             live_s = live[order]
             prev = jnp.concatenate(
                 [jnp.full((1,), -1, jnp.int64), pk_s[:-1]])
             is_new = live_s & (pk_s != prev)
         else:
-            order = jnp.lexsort(tuple(key_sort_arrays(keys, live)))
+            with phase("lexsort"):
+                order = jnp.lexsort(tuple(key_sort_arrays(keys, live)))
             is_new = boundaries(keys, live, order)
             live_s = live[order]
         gid = jnp.clip(jnp.cumsum(is_new) - 1, 0, num_groups - 1)

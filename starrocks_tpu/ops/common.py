@@ -82,6 +82,19 @@ def boundaries(keys, live, order):
     return diff & live_s
 
 
+# Phase scopes inside an operator's `sr.<kind>.<n>` scope (sql/physical.py
+# `emit`): HLO metadata only, so a profiler trace says which part of a join
+# or an aggregate a device operation belongs to (`sr.join.1/expand`).
+PHASES = ("build", "probe", "expand", "payload", "rf", "compact",
+          "limbs", "lexsort", "segments", "sort")
+
+
+def phase(name: str):
+    assert name in PHASES, name
+    return jax.named_scope(name)
+
+
+@phase("compact")
 def compact(chunk: Chunk, capacity: int | None = None):
     """Gather live rows to the front (stable). Output capacity may shrink.
 

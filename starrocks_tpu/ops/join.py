@@ -28,7 +28,7 @@ from .. import types as T
 from ..column.column import Chunk, Field, Schema
 from ..exprs.compile import ExprCompiler
 from ..exprs.ir import Col
-from .common import eval_keys, mix64
+from .common import eval_keys, mix64, phase
 from .segment import on_tpu
 
 INNER = "inner"
@@ -178,6 +178,7 @@ def _or_across_shards(lanes, axis: str):
         jax.lax.psum(jnp.asarray(lanes, jnp.int32), axis) > 0, jnp.uint8)
 
 
+@phase("rf")
 def runtime_filter_mask(
     probe: Chunk, build: Chunk, probe_keys, build_keys, bit_widths=None,
     axis: str | None = None, dense_range: tuple | None = None,
@@ -272,6 +273,7 @@ def bloom_probe_bitset(bitset, pk, p_ok):
     return p_ok & (pk != _I64MAX) & (g1 == 1) & (g2 == 1)
 
 
+@phase("rf")
 def bloom_filter_mask(
     probe: Chunk, build: Chunk, probe_keys, build_keys, bit_widths=None,
     axis: str | None = None, bits: int = 1 << 20,
@@ -301,12 +303,14 @@ def dense_semi_anti_mask(probe: Chunk, build: Chunk, probe_keys, build_keys,
     pk, p_ok, bk, b_ok = pack_key_pair(probe, build, probe_keys, build_keys)
     lo, hi = dense_range
     size = int(hi - lo + 1)
-    present = jnp.zeros((size,), jnp.uint8).at[
-        jnp.where(b_ok, bk - lo, size)
-    ].set(1, mode="drop")
-    idx = pk - lo
-    in_range = (idx >= 0) & (idx < size)
-    member = p_ok & in_range & (present[jnp.clip(idx, 0, size - 1)] == 1)
+    with phase("build"):
+        present = jnp.zeros((size,), jnp.uint8).at[
+            jnp.where(b_ok, bk - lo, size)
+        ].set(1, mode="drop")
+    with phase("probe"):
+        idx = pk - lo
+        in_range = (idx >= 0) & (idx < size)
+        member = p_ok & in_range & (present[jnp.clip(idx, 0, size - 1)] == 1)
     return ~member if anti else member
 
 
@@ -387,22 +391,26 @@ def hash_join_unique(
     if _cfg.get("join_probe_strategy") == "pallas":
         # sort-free path: open-addressing hash table in Pallas (the cached
         # build_order, an argsort artifact, is simply unused here)
-        match, build_row = hash_probe_rows(bk, pk, bcap, p_ok)
+        with phase("probe"):
+            match, build_row = hash_probe_rows(bk, pk, bcap, p_ok)
         return _unique_join_epilogue(
             probe, build, payload, match, build_row, join_type)
 
-    order = (build_order if build_order is not None
-             else jnp.argsort(bk, stable=True))  # sentinels go last
-    bk_sorted = bk[order]
+    with phase("build"):
+        order = (build_order if build_order is not None
+                 else jnp.argsort(bk, stable=True))  # sentinels go last
+        bk_sorted = bk[order]
 
-    pos = _probe_searchsorted(bk_sorted, pk)
-    pos_c = jnp.clip(pos, 0, bcap - 1)
-    match = (bk_sorted[pos_c] == pk) & p_ok & (pk != _I64MAX)
-    build_row = order[pos_c]
+    with phase("probe"):
+        pos = _probe_searchsorted(bk_sorted, pk)
+        pos_c = jnp.clip(pos, 0, bcap - 1)
+        match = (bk_sorted[pos_c] == pk) & p_ok & (pk != _I64MAX)
+        build_row = order[pos_c]
     return _unique_join_epilogue(
         probe, build, payload, match, build_row, join_type)
 
 
+@phase("payload")
 def _unique_join_epilogue(probe, build, payload, match, build_row, join_type):
     """Shared tail of the 1:N join kernels (sorted + LUT): gather the build
     payload by matched row, NULL-mask non-matches for LEFT OUTER, and apply
@@ -458,15 +466,17 @@ def hash_join_lut(
     pk, p_ok, bk, b_ok = pack_key_pair(probe, build, probe_keys, build_keys)
 
     # dead/NULL build rows land in the spill slot (dropped)
-    idxb = jnp.where(b_ok, bk - lo, size)
-    lut = jnp.full((size,), -1, jnp.int32).at[idxb].set(
-        jnp.arange(build.capacity, dtype=jnp.int32), mode="drop"
-    )
-    idxp = pk - lo
-    in_range = p_ok & (idxp >= 0) & (idxp < size)
-    row = lut[jnp.clip(idxp, 0, size - 1)]
-    match = in_range & (row >= 0)
-    build_row = jnp.clip(row, 0, build.capacity - 1)
+    with phase("build"):
+        idxb = jnp.where(b_ok, bk - lo, size)
+        lut = jnp.full((size,), -1, jnp.int32).at[idxb].set(
+            jnp.arange(build.capacity, dtype=jnp.int32), mode="drop"
+        )
+    with phase("probe"):
+        idxp = pk - lo
+        in_range = p_ok & (idxp >= 0) & (idxp < size)
+        row = lut[jnp.clip(idxp, 0, size - 1)]
+        match = in_range & (row >= 0)
+        build_row = jnp.clip(row, 0, build.capacity - 1)
     return _unique_join_epilogue(
         probe, build, payload, match, build_row, join_type)
 
@@ -494,15 +504,17 @@ def hash_join_expand(
         probe, build, probe_keys, build_keys, bit_widths
     )  # build NULL/dead rows pack to the sentinel
 
-    order = (build_order if build_order is not None
-             else jnp.argsort(bk, stable=True))
-    bk_sorted = bk[order]
+    with phase("build"):
+        order = (build_order if build_order is not None
+                 else jnp.argsort(bk, stable=True))
+        bk_sorted = bk[order]
     bcap = build.capacity
 
-    probe_ok = p_ok & (pk != _I64MAX)
-    start = jnp.searchsorted(bk_sorted, pk, side="left")
-    end = jnp.searchsorted(bk_sorted, pk, side="right")
-    counts = jnp.where(probe_ok, end - start, 0)
+    with phase("probe"):
+        probe_ok = p_ok & (pk != _I64MAX)
+        start = jnp.searchsorted(bk_sorted, pk, side="left")
+        end = jnp.searchsorted(bk_sorted, pk, side="right")
+        counts = jnp.where(probe_ok, end - start, 0)
 
     if join_type == LEFT_SEMI:
         out = probe.and_sel(counts > 0)
@@ -515,37 +527,40 @@ def hash_join_expand(
     elif join_type != INNER:
         raise NotImplementedError(join_type)
 
-    total = jnp.sum(counts)
-    # expansion: repeat probe-row ids by counts into fixed out_capacity
-    probe_rows = jnp.repeat(
-        jnp.arange(probe.capacity), counts, total_repeat_length=out_capacity
-    )
-    # offset of each output slot within its probe row's run
-    run_start = jnp.cumsum(counts) - counts  # first out slot per probe row
-    offs = jnp.arange(out_capacity) - run_start[probe_rows]
-    build_pos = jnp.clip(start[probe_rows] + offs, 0, bcap - 1)
-    build_row = order[build_pos]
-    out_live = jnp.arange(out_capacity) < total
-    if join_type == LEFT_OUTER:
-        # probe_ok masking matters: a NULL-key probe row must not "match"
-        # the build side's sentinel run (NULL/dead rows also pack to the
-        # sentinel), so its payload stays NULL
-        had_match = (probe_ok & ((end - start) > 0))[probe_rows]
-    else:
-        had_match = jnp.ones((out_capacity,), jnp.bool_)
+    with phase("expand"):
+        total = jnp.sum(counts)
+        # expansion: repeat probe-row ids by counts into fixed out_capacity
+        probe_rows = jnp.repeat(
+            jnp.arange(probe.capacity), counts,
+            total_repeat_length=out_capacity
+        )
+        # offset of each output slot within its probe row's run
+        run_start = jnp.cumsum(counts) - counts  # first out slot per probe row
+        offs = jnp.arange(out_capacity) - run_start[probe_rows]
+        build_pos = jnp.clip(start[probe_rows] + offs, 0, bcap - 1)
+        build_row = order[build_pos]
+        out_live = jnp.arange(out_capacity) < total
+        if join_type == LEFT_OUTER:
+            # probe_ok masking matters: a NULL-key probe row must not "match"
+            # the build side's sentinel run (NULL/dead rows also pack to the
+            # sentinel), so its payload stays NULL
+            had_match = (probe_ok & ((end - start) > 0))[probe_rows]
+        else:
+            had_match = jnp.ones((out_capacity,), jnp.bool_)
+        taken = probe.take(probe_rows)
 
-    taken = probe.take(probe_rows)
     data = list(taken.data)
     valid = list(taken.valid)
     out_fields = _merge_schemas(probe, build, payload)
-    for n in payload:
-        i = build.schema.index(n)
-        d = build.data[i][build_row]
-        v = build.valid[i]
-        v = None if v is None else v[build_row]
-        if join_type == LEFT_OUTER:
-            v = had_match if v is None else (v & had_match)
-        data.append(d)
-        valid.append(v)
+    with phase("payload"):
+        for n in payload:
+            i = build.schema.index(n)
+            d = build.data[i][build_row]
+            v = build.valid[i]
+            v = None if v is None else v[build_row]
+            if join_type == LEFT_OUTER:
+                v = had_match if v is None else (v & had_match)
+            data.append(d)
+            valid.append(v)
     sel = out_live if taken.sel is None else (out_live & taken.sel)
     return Chunk(Schema(out_fields), tuple(data), tuple(valid), sel), total

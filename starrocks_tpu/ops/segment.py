@@ -33,6 +33,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .common import phase
+
 _LIMB_BITS = 8
 _LIMB_MASK = (1 << _LIMB_BITS) - 1
 # per-block partial sums must stay exactly representable in f32:
@@ -66,6 +68,7 @@ def _onehot_blocked(gid, num_groups: int, block: int):
     )
 
 
+@phase("limbs")
 def _seg_sum_int_matmul(vals, gid, num_groups: int, nbits: int):
     """Exact (mod 2^64) integer segment sums on the MXU."""
     n = vals.shape[0]
@@ -229,6 +232,7 @@ def _use_mxu() -> bool:
     return s in ("mxu", "pallas")
 
 
+@phase("segments")
 def seg_sum(vals, gid, num_groups: int, *, sorted_gid: bool = False,
             nbits: int = 64):
     """Segment sum without scatters where possible.
@@ -277,6 +281,7 @@ def seg_count(live, gid, num_groups: int, *, sorted_gid: bool = False):
                    sorted_gid=sorted_gid, nbits=1)
 
 
+@phase("segments")
 def _seg_minmax(vals, gid, num_groups: int, is_min: bool, identity,
                 sorted_gid: bool):
     vals = jnp.asarray(vals)
@@ -302,6 +307,7 @@ def seg_max(vals, gid, num_groups: int, *, identity, sorted_gid: bool = False):
     return _seg_minmax(vals, gid, num_groups, False, identity, sorted_gid)
 
 
+@phase("segments")
 def seg_first_index(gid, num_groups: int, n: int):
     """First row index of each group for group-sorted gid (empty -> n)."""
     left, right = _group_bounds_sorted(gid, num_groups)

@@ -21,13 +21,11 @@ already a parallel bitonic-class sort; this module narrows what feeds it:
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
 from ..column.column import Chunk, pad_capacity
-from .common import eval_keys
+from .common import eval_keys, phase
 from .segment import on_tpu
 
 _I64MAX = jnp.iinfo(jnp.int64).max
@@ -114,57 +112,6 @@ def packed_order_key(keys, sort_keys, live):
     return jnp.where(live, packed, _I64MAX)
 
 
-# --- sort timing (diagnostics; see runtime/config.py enable_sort_timing) ----
-
-# host perf_counter stamps appended by ordered io_callbacks embedded in the
-# compiled program; the executor drains PAIRS (before, after) into the
-# query profile as 'sort_ms'
-SORT_STAMPS: list = []
-
-
-def drain_sort_stamps() -> float:
-    """Total seconds across (before, after) stamp pairs recorded since the
-    last drain (unpaired trailing stamp, if any, is dropped)."""
-    stamps, SORT_STAMPS[:] = SORT_STAMPS[:], []
-    total = 0.0
-    for i in range(0, len(stamps) - 1, 2):
-        total += stamps[i + 1] - stamps[i]
-    return total
-
-
-def _stamp(_):
-    SORT_STAMPS.append(time.perf_counter())
-    import numpy as np
-
-    return np.int32(0)
-
-
-def _timed(fn, operand):
-    """fn(operand) bracketed by ordered host timestamp callbacks when
-    enable_sort_timing is on. The stamps are data-dependent on the sort's
-    input and output, so the measured interval covers the sort (XLA may
-    still schedule neighbors inside it — this is a diagnostic, not a
-    profiler)."""
-    from ..runtime.config import config as _cfg
-
-    if not _cfg.get("enable_sort_timing"):
-        return fn(operand)
-    from jax.experimental import io_callback
-
-    probe = operand[0] if isinstance(operand, tuple) else operand
-    t0 = io_callback(_stamp, jax.ShapeDtypeStruct((), jnp.int32),
-                     probe[:1], ordered=True)
-    if isinstance(operand, tuple):
-        operand = (operand[0] + jnp.asarray(t0 * 0, operand[0].dtype),
-                   ) + operand[1:]
-    else:
-        operand = operand + jnp.asarray(t0 * 0, operand.dtype)
-    out = fn(operand)
-    t1 = io_callback(_stamp, jax.ShapeDtypeStruct((), jnp.int32),
-                     out[:1], ordered=True)
-    return out + jnp.asarray(t1 * 0, out.dtype)
-
-
 # --- TopN partial select -----------------------------------------------------
 
 
@@ -211,17 +158,20 @@ def sort_chunk(chunk: Chunk, sort_keys, limit: int | None = None,
         if (limit is not None and 0 < limit <= TOPN_MAX_K
                 and pad_capacity(limit) < cap):
             kk = pad_capacity(limit)
-            order = _timed(lambda p: topn_order(p, kk), packed)
+            with phase("sort"):
+                order = topn_order(packed, kk)
             out = chunk.take(order)
             k = jnp.minimum(n, limit)
             if counters is not None:
                 counters["topn_rows_pruned"] = jnp.maximum(n - limit, 0)
             return out.with_sel(jnp.arange(kk) < k)
-        order = _timed(lambda p: jnp.argsort(p, stable=True), packed)
+        with phase("sort"):
+            order = jnp.argsort(packed, stable=True)
     else:
         ops = sort_operands(keys, sort_keys)
         ops.append(jnp.asarray(~live, jnp.int8))  # live rows first
-        order = _timed(lambda t: jnp.lexsort(t), tuple(ops))
+        with phase("sort"):
+            order = jnp.lexsort(tuple(ops))
 
     out = chunk.take(order)
     k = n if limit is None else jnp.minimum(n, limit)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from .. import types as T
 from ..column.column import Chunk, Field
 from ..exprs.compile import ExprCompiler
-from .common import boundaries, eval_keys
+from .common import boundaries, eval_keys, phase
 from .sort import _descending
 
 
@@ -185,13 +185,14 @@ def window_op(
     # the FULL key tuple first (one argsort), then just the partition keys
     # (partition prefix + liveness fold into one operand, order keys stay
     # lexsort operands), then the all-operand lexsort.
-    from .sort import _timed, packed_order_key
+    from .sort import packed_order_key
 
     pspecs = [(None, True, False)] * len(pkeys)  # partitions: asc, nulls last
     packed = packed_order_key(
         pkeys + okeys, pspecs + list(order_by), live)
     if packed is not None:
-        order = _timed(lambda p: jnp.argsort(p, stable=True), packed)
+        with phase("sort"):
+            order = jnp.argsort(packed, stable=True)
     else:
         ops = []
         for k, (_, asc, nulls_first) in zip(reversed(okeys), reversed(list(order_by))):
@@ -210,7 +211,8 @@ def window_op(
                 if k.valid is not None:
                     ops.append(jnp.asarray(~k.valid, jnp.int8))
             ops.append(jnp.asarray(~live, jnp.int8))
-        order = _timed(lambda t: jnp.lexsort(t), tuple(ops))
+        with phase("sort"):
+            order = jnp.lexsort(tuple(ops))
 
     sorted_chunk = chunk.take(order)
     live_s = live[order]

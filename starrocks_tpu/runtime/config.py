@@ -269,11 +269,6 @@ config.define("enable_window_topn", True, True,
               "a window into per-partition segmented top-N pruning (the "
               "TopN runtime-filter analog: downstream sorts run over "
               "~k*partitions rows instead of the full window input)")
-config.define("enable_sort_timing", False, True,
-              "sandwich device sorts between ordered host callbacks and "
-              "report per-query 'sort_ms' profile counters (adds host "
-              "sync points: diagnostics only, keep off for benchmarks)",
-              trace=True)
 config.define("join_probe_strategy", "auto", True,
               "auto | pallas | pallas_sorted: unique-join probe strategy. "
               "pallas = open-addressing hash-table build+probe Pallas "

@@ -383,21 +383,15 @@ class DistExecutor(Executor):
                     plan, frag, caps, inputs, bnd, scans_meta)
                 fail_point("executor::before_dispatch")
                 lifecycle.checkpoint("executor::before_dispatch")
-                out, checks = fn(inputs, bnd)
-                jax.block_until_ready(out.data)
+                out, checks = self._dispatch_and_wait(fn, (inputs, bnd), p)
         else:
             fn, _ = hit
             fail_point("executor::before_dispatch")
             lifecycle.checkpoint("executor::before_dispatch")
             with p.timer(f"fragment_{frag.fid}_execute"):
-                out, checks = fn(inputs, bnd)
-                jax.block_until_ready(out.data)
+                out, checks = self._dispatch_and_wait(fn, (inputs, bnd), p)
         if raw is not None:
             self._verify_compile(raw, inputs, reads, p, extra_args=(bnd,))
-            if config.get("enable_device_profile"):
-                from .executor import _attach_device_profile
-
-                _attach_device_profile(fn, (inputs, bnd), p)
         self.cache.bucket_prog_put(
             bucket, tuple(sorted(caps.values.items())), (fn, scans_meta))
         self.cache.bucket_last_set(bucket, caps.values)

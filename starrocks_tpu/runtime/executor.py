@@ -13,6 +13,8 @@ host loop around it implements
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import threading
 import time
 
 import jax
@@ -47,34 +49,42 @@ class ExecError(RuntimeError):
     pass
 
 
-def _attach_device_profile(fn, args, p: RuntimeProfile):
-    """Optional XLA introspection (`SET enable_device_profile`): AOT-lower
-    the freshly cached program and attach `cost_analysis()` /
-    `memory_analysis()` facts to the attempt profile. Costs an extra
-    lowering per fresh program and must never fail the query."""
-    try:
-        comp = fn.lower(*args).compile()
-        ca = comp.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        facts = {}
-        for k in ("flops", "transcendentals", "bytes accessed"):
-            v = (ca or {}).get(k)
-            if isinstance(v, (int, float)):
-                facts[k] = float(v)
-        mem = comp.memory_analysis()
-        memd = {}
-        for a in ("argument_size_in_bytes", "output_size_in_bytes",
-                  "temp_size_in_bytes", "generated_code_size_in_bytes"):
-            v = getattr(mem, a, None)
-            if isinstance(v, int):
-                memd[a] = v
-        if facts:
-            p.set_info("device_cost", facts)
-        if memd:
-            p.set_info("device_memory", memd)
-    except Exception:  # noqa: BLE001  # lint: swallow-ok — introspection must never fail a query
-        pass
+# Compile apart from first run, without changing how programs run: JAX
+# reports how long it traced, lowered and compiled (or loaded from the
+# persistent cache) each program. While a fresh program's first call has set
+# this thread's sink, those durations become spans of its attempt profile;
+# with no sink set the listener does nothing.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "xla_compile",
+}
+_compile_sink = threading.local()
+
+
+def _on_compile_event(event: str, duration: float, **kw):
+    sink = getattr(_compile_sink, "to", None)
+    name = _COMPILE_SPANS.get(event)
+    if sink is None or name is None:
+        return
+    p, program = sink
+    # functions jitted inside the program (jnp.argsort, ...) trace inside
+    # its trace and report too: only the program's own trace counts
+    if name == "jax_trace" and kw.get("fun_name") != program:
+        return
+    p.add_span(name, time.time() - duration, duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def program_name(plan, fingerprint: str | None = None) -> str:
+    """`q_<8 hex>`: what a plan's jitted function is called, so its XLA
+    module in a profiler trace (`jit_q_<8 hex>`) leads back to the
+    statement — by the plan-feedback fingerprint SHOW WORKLOAD lists where
+    there is one, else by a digest of the plan."""
+    fp = fingerprint or hashlib.sha256(repr(plan).encode()).hexdigest()
+    return "q_" + fp[:8]
 
 
 class DeviceCache:
@@ -1190,11 +1200,9 @@ class Executor:
         headroom = config.get("join_expand_headroom")
         fail_point("executor::before_run")
         prev_counts: dict = {}  # last attempt's observed true counts
-        from ..ops.sort import drain_sort_stamps
 
         for attempt in range(max_recompiles):
             lifecycle.checkpoint("executor::attempt")
-            drain_sort_stamps()  # discard stamps of failed/other attempts
             p = profile.child(f"attempt_{attempt}")
             with p.timer("compile_and_run"):
                 out, keyed_checks = attempt_fn(caps, p)
@@ -1257,9 +1265,6 @@ class Executor:
                     if fam and o.isdigit():
                         profile.op_rows(int(o), fam, int(v),
                                         caps.values.get(key))
-                sort_s = drain_sort_stamps()
-                if sort_s:
-                    profile.add_counter("sort_ms", sort_s * 1000.0, "ms")
                 # tighten grossly over-seeded capacities for the NEXT run
                 # (estimate-seeded shrink/join caps can be 100x the true
                 # count): the next execution compiles once at the tight
@@ -1331,17 +1336,23 @@ class Executor:
         # node_ord fills lazily while the fresh program traces; the box
         # hands it to the feedback recorder after the run succeeds
         trace_box: dict = {}
+        fb_fp = (self._fb_ctx or {}).get("fp")
 
         def attempt(caps, p):
             def compile_cb():
                 compiled = compile_plan(plan, self.catalog, caps)
                 trace_box["node_ord"] = compiled.node_ord
+                # the XLA module is named after the statement, not `run`
+                name = program_name(plan, fb_fp)
+                compiled.fn.__name__ = compiled.fn.__qualname__ = name
                 # stash the (lazily-filling) ordinal table on the bucket:
                 # attribution must survive program-cache hits, which
-                # never re-trace
+                # never re-trace; so must the names a trace is read by
+                bucket = self.cache.program_bucket(("local", plan))
                 self.cache.bucket_meta_set(
-                    self.cache.program_bucket(("local", plan)),
-                    "node_ord", compiled.node_ord)
+                    bucket, "node_ord", compiled.node_ord)
+                self.cache.bucket_meta_set(
+                    bucket, "names", (name, compiled.scopes))
                 return (jax.jit(compiled.fn),
                         (compiled.scans, compiled.aux), compiled.fn)
 
@@ -1374,9 +1385,16 @@ class Executor:
         out = self._adaptive(profile, attempt, publish,
                              self._fb_recorder("local", profile,
                                                trace_box))
+        bucket = self.cache.program_bucket(("local", plan))
         node_ord = trace_box.get("node_ord") or self.cache.bucket_meta_get(
-            self.cache.program_bucket(("local", plan)), "node_ord")
+            bucket, "node_ord")
         self._bind_operators(profile, node_ord)
+        names = self.cache.bucket_meta_get(bucket, "names")
+        if names:
+            # a device trace back to this statement: its module is
+            # jit_<program>, its operations sit under sr.<kind>.<n> scopes
+            profile.set_info("program", names[0])
+            profile.set_info("scopes", names[1])
         return out
 
     @staticmethod
@@ -1560,6 +1578,18 @@ class Executor:
         return self._adaptive(profile, attempt, publish,
                               self._fb_recorder("batched", profile))
 
+    @staticmethod
+    def _dispatch_and_wait(fn, args, p):
+        """Call a program and wait for its result, as two spans: `dispatch`
+        is the host's part (argument handling, enqueue; on a fresh program
+        also trace, lowering and compile), `device_wait` the wait for the
+        device — this program's work and whatever is queued ahead of it."""
+        with p.timer("dispatch"):
+            out, checks = fn(*args)
+        with p.timer("device_wait"):
+            jax.block_until_ready(out.data)
+        return out, checks
+
     def _cached_attempt(self, cache_key, caps, p, compile_cb, place_cb):
         """Shared program-cache protocol for local + distributed attempts.
 
@@ -1593,11 +1623,13 @@ class Executor:
                     inputs = place_cb(scans)
                 fail_point("executor::before_dispatch")
                 lifecycle.checkpoint("executor::before_dispatch")
-                out, checks = fn(inputs)
-                jax.block_until_ready(out.data)
+                _compile_sink.to = (p, getattr(raw, "__name__", None))
+                try:
+                    out, checks = self._dispatch_and_wait(fn, (inputs,), p)
+                finally:
+                    _compile_sink.to = None
             dur = time.perf_counter() - t0
-            p.add_counter("compile_first_run", dur, "s")
-            p.spans.append(("compile_first_run", w0, dur))
+            p.add_span("compile_first_run", w0, dur)
             COMPILE_MS.observe(dur * 1000.0)
         else:
             fn, scans = hit
@@ -1605,12 +1637,9 @@ class Executor:
                 inputs = place_cb(scans)
             fail_point("executor::before_dispatch")
             lifecycle.checkpoint("executor::before_dispatch")
-            out, checks = fn(inputs)
-            jax.block_until_ready(out.data)
+            out, checks = self._dispatch_and_wait(fn, (inputs,), p)
         if raw is not None:
             self._verify_compile(raw, inputs, reads, p)
-            if config.get("enable_device_profile"):
-                _attach_device_profile(fn, (inputs,), p)
         # caps defaults fill during the first trace; record entries after it
         self.cache.bucket_prog_put(
             bucket, tuple(sorted(caps.values.items())), (fn, scans))

@@ -16,7 +16,8 @@ nodes for EXPLAIN ANALYZE.
 
 Every timer also records a wall-clock span, so a retained profile exports
 as Chrome `trace_event` JSON (GET /api/query/{id}/trace) and opens directly
-in Perfetto.
+in Perfetto. While a `jax.profiler` trace runs, every timer is also a host
+event `sr:<name>` in that trace, on the clock of the device operations.
 """
 
 from __future__ import annotations
@@ -24,6 +25,8 @@ from __future__ import annotations
 import json
 import time
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 from .. import lockdep
 from .config import config
@@ -38,10 +41,6 @@ config.define("profile_history_size", 64, True,
 config.define("profile_history_bytes", 8 << 20, True,
               "memory budget for retained profiles (rendered text + "
               "structured tree, estimated per entry; LRU eviction)")
-config.define("enable_device_profile", False, True,
-              "attach XLA cost_analysis()/memory_analysis() facts to the "
-              "profile on fresh compiles (host-side AOT introspection; "
-              "costs an extra lowering per fresh program)")
 
 
 class RuntimeProfile:
@@ -71,16 +70,20 @@ class RuntimeProfile:
     def set_info(self, name: str, value):
         self.infos[name] = value
 
+    def add_span(self, name: str, epoch_s: float, dur_s: float):
+        self.add_counter(name, dur_s, "s")
+        self.spans.append((name, epoch_s, dur_s))
+
     @contextmanager
     def timer(self, name: str):
         w0 = time.time()
         t0 = time.perf_counter()
         try:
-            yield
+            # a no-op check unless a profiler trace is being taken
+            with TraceAnnotation("sr:" + name):
+                yield
         finally:
-            dur = time.perf_counter() - t0
-            self.add_counter(name, dur, "s")
-            self.spans.append((name, w0, dur))
+            self.add_span(name, w0, time.perf_counter() - t0)
 
     # --- per-operator attribution (plan-ordinal keyed) ----------------------
     def op(self, ordinal: int) -> dict:

@@ -55,7 +55,10 @@ from .logical import (
     LUnnest, LWindow, LogicalPlan, walk_plan,
 )
 from .optimizer import and_all
-from .physical import Caps, PlanError, _equi_pair, _key_bit_width, unique_sets
+from .physical import (
+    Caps, PlanError, _equi_pair, _key_bit_width, plan_scopes, scope_name,
+    unique_sets,
+)
 
 SHARDED = "sharded"
 REPLICATED = "replicated"
@@ -209,6 +212,8 @@ def compile_distributed(
     def ordinal(p) -> int:
         return node_ord.setdefault(p, len(node_ord))
 
+    scopes = plan_scopes(plan)
+
     if recorder is not None:
         note = recorder.note
     else:
@@ -248,7 +253,10 @@ def compile_distributed(
         def emit(p):
             if p in emit_memo:
                 return emit_memo[p]
-            out = _emit(p)
+            # the operator's name on its device operations, as in
+            # physical.compile_plan
+            with jax.named_scope(scope_name(scopes, p)):
+                out = _emit(p)
             emit_memo[p] = out
             return out
 

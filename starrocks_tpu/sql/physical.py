@@ -21,6 +21,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
+
 from ..exprs.ir import AggExpr, Call, Col, Expr, Lit
 from ..ops import (
     INNER, LEFT_ANTI, LEFT_OUTER, LEFT_SEMI,
@@ -32,7 +34,7 @@ from ..column.column import pad_capacity
 from .analyzer import _conjuncts
 from .logical import (
     LAggregate, LFilter, LJoin, LLimit, LProject, LScan, LSort, LUnion,
-    LUnnest, LWindow, LogicalPlan,
+    LUnnest, LWindow, LogicalPlan, walk_plan,
 )
 from .optimizer import and_all, col_origin, estimate_rows, expr_cols
 
@@ -482,9 +484,41 @@ class Caps:
         return self.values.setdefault(key, default)
 
 
+_SCOPE_KIND = {
+    LScan: "scan", LFilter: "filter", LProject: "project", LJoin: "join",
+    LAggregate: "agg", LSort: "sort", LLimit: "limit", LWindow: "window",
+    LUnion: "union", LUnnest: "unnest",
+}
+
+
+def plan_scopes(plan: LogicalPlan) -> dict:
+    """plan node (by value) -> its number `<n>` among the distinct nodes in
+    pre-order. A numbering of its own: `node_ord` ordinals are handed out
+    lazily at first use and key capacities, feedback and EXPLAIN ANALYZE, so
+    naming every node must not touch them."""
+    numbers: dict = {}
+    for p in walk_plan(plan):
+        numbers.setdefault(p, len(numbers))
+    return numbers
+
+
+def scope_name(scopes: dict, p: LogicalPlan) -> str:
+    """`sr.<kind>.<n>`: the name scope a node's device operations are
+    emitted under (`.x` for a node synthesized while emitting, which the
+    plan does not hold)."""
+    kind = _SCOPE_KIND.get(type(p)) or type(p).__name__[1:].lower()
+    return f"sr.{kind}.{scopes.get(p, 'x')}"
+
+
+def scope_table(scopes: dict) -> dict:
+    """`<n>` -> the node's repr, cut to 80 characters: the profile's
+    `scopes` info, which reads a trace's scope back to the plan."""
+    return {n: repr(p)[:80] for p, n in scopes.items()}
+
+
 class Compiled:
     def __init__(self, fn, scans, checks_meta, out_names, aux=(),
-                 node_ord=None):
+                 node_ord=None, scopes=None):
         self.fn = fn  # (inputs tuple) -> (chunk, checks tuple)
         self.scans = scans  # list[(table, alias, columns)]
         self.checks_meta = checks_meta  # list[(cap_key,)] parallel to checks
@@ -498,6 +532,8 @@ class Compiled:
         # observed `join_{o}` overflow totals back to the plan subtree that
         # produced them.
         self.node_ord = {} if node_ord is None else node_ord
+        # scope number -> node repr (`scope_table`)
+        self.scopes = scopes or {}
 
 
 def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
@@ -524,6 +560,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             collect_scans(c)
 
     collect_scans(plan)
+    scopes = plan_scopes(plan)
 
     def collect_build_orders(p):
         if isinstance(p, LJoin) and cached_build_sort:
@@ -547,7 +584,10 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
         def emit(p: LogicalPlan):
             if p in emit_memo:
                 return emit_memo[p]
-            out = _emit(p)
+            # HLO metadata only: device operations carry the operator's
+            # name (nested under its parent's) into a profiler trace
+            with jax.named_scope(scope_name(scopes, p)):
+                out = _emit(p)
             emit_memo[p] = out
             return out
 
@@ -749,6 +789,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             from .. import types as T
             from ..column.column import Field, Schema
             from ..column import Chunk
+            from ..ops.common import phase
             from ..ops.join import _I64MAX, pack_keys
 
             lc = emit(base)
@@ -760,27 +801,29 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             for jn, (pk_e, bk_e, lo, hi) in levels:
                 rc = emit(jn.right)
                 size = int(hi - lo + 1)
-                bk, b_ok = pack_keys(rc, (bk_e,))
-                idxb = jnp.where(b_ok, bk - lo, size)
-                lut = jnp.full((size,), -1, jnp.int32).at[idxb].set(
-                    jnp.arange(rc.capacity, dtype=jnp.int32), mode="drop")
+                with phase("build"):
+                    bk, b_ok = pack_keys(rc, (bk_e,))
+                    idxb = jnp.where(b_ok, bk - lo, size)
+                    lut = jnp.full((size,), -1, jnp.int32).at[idxb].set(
+                        jnp.arange(rc.capacity, dtype=jnp.int32), mode="drop")
                 j = src.get(pk_e.name)
-                if j is None:
-                    # key from the base fact chunk
-                    pkd, ok = pack_keys(lc, (pk_e,))
-                else:
-                    # snowflake: key gathered from a lower level's payload
-                    prc, _, prow = builds[j]
-                    i = prc.schema.index(pk_e.name)
-                    kd = jnp.asarray(prc.data[i], jnp.int64)[prow]
-                    kv = prc.valid[i]
-                    ok = sel if kv is None else (sel & kv[prow])
-                    pkd = jnp.where(ok, kd, _I64MAX)
-                idxp = pkd - lo
-                m = ok & (idxp >= 0) & (idxp < size)
-                row = lut[jnp.clip(idxp, 0, size - 1)]
-                m = m & (row >= 0)
-                row = jnp.clip(row, 0, rc.capacity - 1)
+                with phase("probe"):
+                    if j is None:
+                        # key from the base fact chunk
+                        pkd, ok = pack_keys(lc, (pk_e,))
+                    else:
+                        # snowflake: key gathered from a lower level's payload
+                        prc, _, prow = builds[j]
+                        i = prc.schema.index(pk_e.name)
+                        kd = jnp.asarray(prc.data[i], jnp.int64)[prow]
+                        kv = prc.valid[i]
+                        ok = sel if kv is None else (sel & kv[prow])
+                        pkd = jnp.where(ok, kd, _I64MAX)
+                    idxp = pkd - lo
+                    m = ok & (idxp >= 0) & (idxp < size)
+                    row = lut[jnp.clip(idxp, 0, size - 1)]
+                    m = m & (row >= 0)
+                    row = jnp.clip(row, 0, rc.capacity - 1)
                 match_all = m if match_all is None else (match_all & m)
                 builds.append((rc, list(jn.right.output_names()), row))
                 for nm in jn.right.output_names():
@@ -799,14 +842,15 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             data = list(wide.data[:nbase])
             valid = list(wide.valid[:nbase])
             out_fields = list(wide.schema.fields[:nbase])
-            for (rc, names, _), rowc in zip(builds, wide.data[nbase:]):
-                for nm in names:
-                    i = rc.schema.index(nm)
-                    d = rc.data[i][rowc]
-                    v = rc.valid[i]
-                    out_fields.append(rc.schema.fields[i])
-                    data.append(d)
-                    valid.append(None if v is None else v[rowc])
+            with phase("payload"):
+                for (rc, names, _), rowc in zip(builds, wide.data[nbase:]):
+                    for nm in names:
+                        i = rc.schema.index(nm)
+                        d = rc.data[i][rowc]
+                        v = rc.valid[i]
+                        out_fields.append(rc.schema.fields[i])
+                        data.append(d)
+                        valid.append(None if v is None else v[rowc])
             return Chunk(Schema(tuple(out_fields)), tuple(data),
                          tuple(valid), wide.sel)
 
@@ -1087,7 +1131,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
         return chunk, checks
 
     return Compiled(run, scans, None, plan.output_names(), tuple(aux),
-                    node_ord=node_ord)
+                    node_ord=node_ord, scopes=scope_table(scopes))
 
 
 def _equi_pair(conj: Expr, lcols: frozenset, rcols: frozenset):

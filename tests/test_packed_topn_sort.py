@@ -9,6 +9,7 @@ brute-force oracle, and the new profile counters.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -29,7 +30,7 @@ from starrocks_tpu.storage.catalog import Catalog
 def _restore_flags():
     saved = {k: config.get(k) for k in
              ("enable_packed_sort_keys", "topn_strategy",
-              "enable_window_topn", "enable_sort_timing")}
+              "enable_window_topn")}
     yield
     for k, v in saved.items():
         config.set(k, v)
@@ -286,12 +287,21 @@ def test_window_topn_prefilter_nan_scores():
     assert np.asarray(pre2[0])[:3].all()
 
 
-def test_sort_timing_counter():
+def test_order_by_limit_lowers_with_a_sort_scope():
+    """What timed the sort from the host (`enable_sort_timing`, a callback
+    on each side of it) is gone: the sort now carries its name into a
+    profiler trace, `sr.sort.<n>/sort` around the top-k itself."""
+    from lowering import SCOPED, lowered_text, scope_paths
+    from starrocks_tpu.ops.common import PHASES
+
     rng = np.random.default_rng(3)
     cat = _rank_catalog(rng, n=2000)
-    config.set("enable_sort_timing", True)
     s = Session(cat)
-    s.sql("select p, v from t order by p, v limit 50")
-    prof = s.last_profile
-    ms = prof.counters.get("sort_ms")
-    assert ms is not None and ms[0] > 0
+    r = s.sql("select p, v from t order by p, v limit 50")
+    assert "sort_ms" not in r.profile.counters
+    text = lowered_text(s, r)
+    assert "sr.sort.0/sort" in scope_paths(text, PHASES)
+    # whichever way this key sorts (packed top-k, argsort or lexsort), the
+    # sort itself is called from inside the phase
+    in_sort = [p for p in SCOPED.findall(text) if "/sr.sort.0/sort/" in p]
+    assert any(re.search(r"lexsort|argsort|top_k", p) for p in in_sort), in_sort

@@ -1,0 +1,229 @@
+"""Names on what a statement does, for whoever reads a trace: SQL operator
+scopes (`sr.<kind>.<n>`, phases inside joins and aggregates) in the HLO
+metadata of the compiled program, the host spans that split a statement's
+compile (`jax_trace`, `jax_lower`, `xla_compile`) and its run (`dispatch`,
+`device_wait`), the profile's timers as `sr:<name>` events of a
+`jax.profiler` trace, and the program's name. None of it may move what
+keys on plan ordinals: the goldens are the parent commit's."""
+
+import json
+import os
+import re
+import threading
+
+import jax
+import pytest
+
+from starrocks_tpu.column import HostTable
+from starrocks_tpu.ops.common import PHASES
+from starrocks_tpu.runtime.config import config
+from starrocks_tpu.runtime.session import Session
+from starrocks_tpu.storage.catalog import Catalog, tpch_catalog
+
+from lowering import SCOPED, lowered_text, scope_paths
+from tpch_queries import QUERIES
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(HERE, "trace_scopes_parent.json")) as _f:
+    PARENT = json.load(_f)  # taken at 9b96640, before any scope existed
+
+# scopes each statement must lower with at SF0.01 on the CPU backend
+# (Q3's three-way join fuses into one multiway probe under its top join)
+EXPECTED = {
+    1: {"sr.sort.0/sort", "sr.sort.0/sr.project.1/sr.agg.2/segments",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.filter.3"},
+    3: {"sr.sort.0/sort", "sr.sort.0/sr.project.1/sr.agg.2/lexsort",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/build",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/probe",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/compact",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/payload",
+        "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/sr.filter.4"},
+    6: {"sr.project.0/sr.agg.1/segments",
+        "sr.project.0/sr.agg.1/sr.filter.2"},
+}
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    return tpch_catalog(0.01)
+
+
+@pytest.fixture(scope="module")
+def ran(tpch):
+    """q -> (session, the first send's result, its EXPLAIN ANALYZE text),
+    each statement on a session of its own."""
+    out = {}
+    for q in EXPECTED:
+        s = Session(tpch)
+        text = s.sql("explain analyze " + QUERIES[q])
+        out[q] = (s, s.sql(QUERIES[q]), text)
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered(ran):
+    return {q: lowered_text(s, r) for q, (s, r, _) in ran.items()}
+
+
+@pytest.mark.parametrize("q", sorted(EXPECTED))
+def test_statement_lowers_with_operator_scopes(lowered, q):
+    paths = scope_paths(lowered[q], PHASES)
+    assert EXPECTED[q] <= paths, sorted(paths)
+    # every scope an operation sits in hangs under the root's
+    root = sorted(EXPECTED[q])[0].split("/")[0]
+    assert all(p.startswith(root) for p in paths)
+
+
+@pytest.mark.parametrize("q", sorted(EXPECTED))
+def test_scopes_info_reads_the_lowered_text_back_to_the_plan(ran, lowered, q):
+    _, result, _ = ran[q]
+    table = result.profile.infos["scopes"]
+    numbered = set(re.findall(r"sr\.([a-z]+)\.(\d+)", " ".join(
+        SCOPED.findall(lowered[q]))))
+    assert numbered
+    heads = {"scan": "Scan", "filter": "Filter", "project": "Project",
+             "join": "Join", "agg": "Agg", "sort": "Sort", "limit": "Limit"}
+    for kind, n in numbered:
+        assert table[int(n)].startswith(heads[kind] + "["), (kind, n)
+    assert all(len(text) <= 80 for text in table.values())
+    # pre-order: 0 is the root, and the numbers have no holes
+    assert sorted(table) == list(range(len(table)))
+    assert table[0] == repr(result.plan)[:80]
+
+
+@pytest.mark.parametrize("q", sorted(EXPECTED))
+def test_ordinals_capacities_and_explain_analyze_are_the_parents(ran, q):
+    _, result, text = ran[q]
+    want = PARENT[f"q{q}"]
+    prof = result.profile
+    assert sorted([o, repr(n)] for n, o in prof.node_ord.items()) == \
+        want["node_ord"]
+    keys = {k for attempt in prof.children
+            for k in attempt.infos.get("capacities") or {}}
+    assert sorted(keys) == want["capacity_keys"]
+    # the plan tree with its [#o est= rows= cap= ctrs{}] annotations; the
+    # profile under it holds timings and is not compared
+    assert text.split("\nquery:")[0] == want["tree"]
+
+
+def _flat_spans(profile) -> list:
+    out = list(profile.spans)
+    for c in profile.children:
+        out.extend(_flat_spans(c))
+    return out
+
+
+def _inside(inner, outer, slack=2e-3) -> bool:
+    """Span starts are epoch stamps, durations come from the performance
+    counter: allow the two clocks a little."""
+    return (inner[1] >= outer[1] - slack
+            and inner[1] + inner[2] <= outer[1] + outer[2] + slack)
+
+
+def test_first_send_splits_compile_and_every_send_splits_the_run(tpch):
+    s = Session(tpch)
+    first = {}
+    for n, t, d in _flat_spans(s.sql(QUERIES[6]).profile):
+        first.setdefault(n, []).append((n, t, d))
+    assert {"jax_trace", "jax_lower", "xla_compile", "dispatch",
+            "device_wait", "compile_first_run"} <= set(first)
+    (whole,) = first["compile_first_run"]
+    for name in ("jax_trace", "jax_lower", "xla_compile"):
+        assert len(first[name]) == 1 and _inside(first[name][0], whole), name
+    # trace, lowering and compile happen inside the first call
+    assert _inside(first["xla_compile"][0], first["dispatch"][0])
+    t_trace, t_lower, t_xla = (first[n][0][1] for n in (
+        "jax_trace", "jax_lower", "xla_compile"))
+    assert t_trace <= t_lower + 2e-3 and t_lower <= t_xla + 2e-3
+
+    warm = s.sql(QUERIES[6]).profile
+    spans = {n: (n, t, d) for n, t, d in _flat_spans(warm)}
+    assert not {"jax_trace", "jax_lower", "xla_compile",
+                "compile_first_run"} & set(spans)
+    run, dispatch, wait = (spans[n] for n in (
+        "compile_and_run", "dispatch", "device_wait"))
+    assert _inside(dispatch, run) and _inside(wait, run)
+    assert dispatch[1] + dispatch[2] <= wait[1] + 2e-3  # disjoint, in order
+    attempt = warm.children[0]
+    assert {"dispatch", "device_wait"} <= set(attempt.counters)
+
+
+def test_two_threads_compiling_keep_their_spans_apart():
+    cat = Catalog()
+    cat.register("t", HostTable.from_pydict(
+        {"k": list(range(4000)), "v": [i % 7 for i in range(4000)]}))
+    statements = ["select sum(v) a from t where k < 1234",
+                  "select k, v from t where v = 3 order by k limit 5"]
+    profiles, gate = [None, None], threading.Barrier(2)
+
+    def send(i):
+        s = Session(cat)
+        gate.wait()
+        profiles[i] = s.sql(statements[i]).profile
+
+    threads = [threading.Thread(target=send, args=(i,)) for i in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for prof in profiles:
+        by = {}
+        for n, t, d in _flat_spans(prof):
+            by.setdefault(n, []).append((n, t, d))
+        # one fresh program each: its own trace, lowering and compile, once
+        assert [len(by[n]) for n in ("jax_trace", "jax_lower", "xla_compile")
+                ] == [1, 1, 1], {n: len(v) for n, v in by.items()}
+        (whole,) = by["compile_first_run"]
+        assert all(_inside(by[n][0], whole)
+                   for n in ("jax_trace", "jax_lower", "xla_compile"))
+    assert profiles[0].infos["program"] != profiles[1].infos["program"]
+
+
+@pytest.mark.parametrize("knob", ["enable_sort_timing",
+                                  "enable_device_profile"])
+def test_removed_knobs_are_unknown(knob):
+    with pytest.raises(Exception, match="(?i)unknown|no such|not defined"):
+        Session().sql(f"set {knob} = true")
+
+
+def test_profile_timers_are_host_events_of_a_profiler_trace(tpch, tmp_path):
+    from jax.profiler import ProfileData
+
+    s = Session(tpch)
+    s.sql(QUERIES[6])  # compiled before the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        s.sql(QUERIES[6])
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, files in os.walk(tmp_path)
+               for f in files if f.endswith(".xplane.pb")]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"sr:optimize", "sr:fetch_results", "sr:compile_and_run",
+            "sr:dispatch", "sr:device_wait"} <= names
+
+
+def test_program_is_named_after_the_statements_fingerprint(tpch):
+    saved = config.get("plan_feedback")
+    try:
+        for feedback in (True, False):
+            config.set("plan_feedback", feedback)
+            s = Session(tpch)
+            r = s.sql(QUERIES[6])
+            name = r.profile.infos["program"]
+            assert re.fullmatch(r"q_[0-9a-f]{8}", name)
+            (fn, _), = [prog for bucket in s.cache.programs.values()
+                        for prog in bucket["progs"].values()]
+            assert fn.__name__ == name  # the XLA module is jit_<name>
+            if feedback:
+                # ... and leads to the statement's row in SHOW WORKLOAD
+                from starrocks_tpu.runtime.workload import WORKLOAD
+
+                assert any(row["fingerprint"].startswith(name[2:])
+                           for row in WORKLOAD.snapshot())
+    finally:
+        config.set("plan_feedback", saved)
