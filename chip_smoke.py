@@ -103,6 +103,19 @@ def _oracle_frames(catalog) -> dict:
     return frames
 
 
+def _last_compactions() -> dict:
+    """The `compactions` info of the newest retained statement's last
+    attempt ({} where its program compacts nothing)."""
+    from starrocks_tpu.runtime.profile import PROFILE_MANAGER
+
+    entries = PROFILE_MANAGER.snapshot()
+    attempts = ((entries[-1]["profile"] or {}).get("children", ())
+                if entries else ())
+    done = [a["infos"]["compactions"] for a in attempts
+            if "compactions" in a.get("infos", {})]
+    return done[-1] if done else {}
+
+
 def _versions() -> dict:
     import importlib.metadata as md
 
@@ -199,8 +212,14 @@ def run(sf: float, chips: int, seed: int) -> dict:
                     f"{name}: send {len(sends)} still compiled "
                     f"({sends[-1]['compiles']} programs)")
             seen.add(qid)
-            statements.append(
-                ({"statement": name, "sends": sends}, qid, key, rows))
+            record = {"statement": name, "sends": sends}
+            done = _last_compactions()
+            if done:
+                # rows in, slots out and index method of each `compact` in
+                # the program the last send ran
+                record["compactions"] = done
+                print(f"compactions {name} {json.dumps(done)}")
+            statements.append((record, qid, key, rows))
 
         # what the statements left on the device, before anything is freed
         resident = session.cache.resident_arrays()

@@ -1342,6 +1342,7 @@ class Executor:
             def compile_cb():
                 compiled = compile_plan(plan, self.catalog, caps)
                 trace_box["node_ord"] = compiled.node_ord
+                trace_box["compactions"] = compiled.compactions
                 # the XLA module is named after the statement, not `run`
                 name = program_name(plan, fb_fp)
                 compiled.fn.__name__ = compiled.fn.__qualname__ = name
@@ -1376,6 +1377,18 @@ class Executor:
             out, checks = self._cached_attempt(
                 ("local", plan), caps, p, compile_cb, place_cb
             )
+            # what this program's compactions were (rows in, slots out,
+            # index method): known from its trace, kept with the bucket
+            # under the capacities that key the program, for cache hits
+            bucket = self.cache.program_bucket(("local", plan))
+            key = ("compactions", tuple(sorted(caps.values.items())))
+            done = trace_box.pop("compactions", None)
+            if done is None:
+                done = self.cache.bucket_meta_get(bucket, key)
+            else:
+                self.cache.bucket_meta_set(bucket, key, done)
+            if done:
+                p.set_info("compactions", dict(done))
             return out, [(k, int(v)) for k, v in checks.items()]
 
         def publish(vals):

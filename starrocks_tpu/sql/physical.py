@@ -518,7 +518,7 @@ def scope_table(scopes: dict) -> dict:
 
 class Compiled:
     def __init__(self, fn, scans, checks_meta, out_names, aux=(),
-                 node_ord=None, scopes=None):
+                 node_ord=None, scopes=None, compactions=None):
         self.fn = fn  # (inputs tuple) -> (chunk, checks tuple)
         self.scans = scans  # list[(table, alias, columns)]
         self.checks_meta = checks_meta  # list[(cap_key,)] parallel to checks
@@ -534,6 +534,11 @@ class Compiled:
         self.node_ord = {} if node_ord is None else node_ord
         # scope number -> node repr (`scope_table`)
         self.scopes = scopes or {}
+        # capacity key (`shrink_<tag>`, `wtop_<n>`) -> the compaction emitted
+        # under it: {"cap": rows in, "out_cap": slots out, "method": how the
+        # source-row index was computed}. Filled while fn traces, like
+        # node_ord; the attempt's `compactions` info.
+        self.compactions = {} if compactions is None else compactions
 
 
 def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
@@ -542,6 +547,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
     aux: list = []  # build-order descriptors (see Compiled.aux)
     aux_index: dict = {}
     node_ord: dict = {}  # plan node (by value) -> deterministic ordinal
+    compactions: dict = {}  # capacity key -> what `compact` did under it
 
     def ordinal(p) -> int:
         return node_ord.setdefault(p, len(node_ord))
@@ -604,18 +610,29 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             idx = aux_index.get(desc)
             return idx
 
+        def compact_to(c, key: str, cap: int):
+            from ..ops.common import INDEX_METHOD, compact
+
+            out, n = compact(c, cap)
+            checks[key] = n
+            compactions[key] = {"cap": c.capacity, "out_cap": cap,
+                                "method": INDEX_METHOD}
+            return out
+
         def maybe_compact(child_plan, c, tag: str, est: float | None = None):
             """Shrink a sparse chunk before a sort-heavy op: selective
             filters/joins leave most capacity dead, and sort/agg/window cost
-            scales with CAPACITY, not live rows. Seeded from the cardinality
-            estimate (callers override `est` when they know better, e.g. a
-            probe side just masked by an exact runtime filter); the overflow
-            check recompiles on underestimates (same contract as every other
+            scales with CAPACITY, not live rows. The shrink is not free: on
+            a v5e ~1 ns an input row for the index plus ~50 ns per OUTPUT
+            row and int64 column for the gathers (PERF.md section 6, PR 25),
+            so it pays where it is selective or what follows costs more a
+            row than that. Seeded from the cardinality estimate (callers
+            override `est` when they know better, e.g. a probe side just
+            masked by an exact runtime filter); the overflow check
+            recompiles on underestimates (same contract as every other
             capacity)."""
             if c.capacity < 8192:
                 return c
-            from ..ops.common import compact
-
             if est is None:
                 est = estimate_rows(child_plan, catalog)
             default = pad_capacity(int(est * 1.5) + 1024)
@@ -625,9 +642,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             cap = caps.get(key, default)
             if cap >= c.capacity:
                 return c
-            out, n = compact(c, cap)
-            checks[key] = n
-            return out
+            return compact_to(c, key, cap)
 
         def _emit(p: LogicalPlan):
             if isinstance(p, LScan):
@@ -660,7 +675,6 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                     # (row-counting limit func, prefix-only co-residents);
                     # otherwise window_op's exact in-window mask does all
                     # the work
-                    from ..ops.common import compact
                     from ..ops.window import (
                         window_topn_prefilter, window_topn_prefilter_safe,
                     )
@@ -678,8 +692,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                         cap = caps.get(key, pad_capacity(
                             seed_rows * 2 + 1024))
                         if cap < c.capacity:
-                            c, nk = compact(c, cap)
-                            checks[key] = nk
+                            c = compact_to(c, key, cap)
                 if pre is None:
                     # no threshold path: the estimate-seeded shrink is the
                     # only capacity reduction before the window sort
@@ -734,8 +747,8 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                 # Compaction only pays when the aggregate must LEXSORT its
                 # input (cost scales with capacity). The no-group-key path
                 # and the packed-gid dense path are single fused passes over
-                # the chunk — compacting first would ADD a cumsum + one
-                # scatter per column for nothing.
+                # the chunk — compacting first would ADD an index + one
+                # gather per column for nothing.
                 # array_agg reads PHYSICAL slot positions (contiguity matters
                 # even with one global group) — it must see a compacted chunk
                 sort_free = (
@@ -1131,7 +1144,8 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
         return chunk, checks
 
     return Compiled(run, scans, None, plan.output_names(), tuple(aux),
-                    node_ord=node_ord, scopes=scope_table(scopes))
+                    node_ord=node_ord, scopes=scope_table(scopes),
+                    compactions=compactions)
 
 
 def _equi_pair(conj: Expr, lcols: frozenset, rcols: frozenset):

@@ -39,3 +39,6 @@ def test_run_passes_on_cpu_at_small_scale():
     assert by_name["mysql:q1"]["sends"][0]["compiles"] >= 1
     assert by_name["http:q1"]["sends"][0]["compiles"] == 0
     assert res["resident_bytes"] > 0
+    # Q3's program compacts, and the smoke says how; Q1's and Q6's do not
+    assert by_name["mysql:q3"]["compactions"]["shrink_0mwb"]["method"]
+    assert "compactions" not in by_name["mysql:q6"]

@@ -41,6 +41,63 @@ def test_compact():
     assert int(k.num_rows()) == 3
 
 
+def _compact_case(cap, share, seed):
+    """A chunk of every column kind `compact` moves, one of them nullable,
+    and its live mask."""
+    from starrocks_tpu.column.column import Chunk, Field, Schema
+
+    rng = np.random.default_rng(seed)
+    live = (rng.random(cap) < share if 0.0 < share < 1.0
+            else np.full(cap, share >= 1.0))
+    cols = {
+        "i64": (T.BIGINT, rng.integers(-(1 << 62), 1 << 62, cap), None),
+        "i32": (T.INT, rng.integers(-(1 << 31), 1 << 31, cap)
+                .astype(np.int32), rng.random(cap) < 0.7),
+        "f64": (T.DOUBLE, rng.normal(size=cap), None),
+        "b": (T.BOOLEAN, rng.random(cap) < 0.5, None),
+    }
+    chunk = Chunk(
+        Schema(tuple(Field(n, t, v is not None)
+                     for n, (t, _, v) in cols.items())),
+        tuple(jnp.asarray(d) for _, d, _ in cols.values()),
+        tuple(None if v is None else jnp.asarray(v)
+              for _, _, v in cols.values()),
+        jnp.asarray(live))
+    return chunk, cols, live
+
+
+# (capacity, live share, out capacity as a share of the capacity or None):
+# 0%, 1%, 61% and 100% live; the output capacity below, at and above the
+# live count; capacities that are and are not powers of two, 10 rows to 2^17
+@pytest.mark.parametrize("cap,share,out", [
+    (10, 0.61, None), (1000, 0.0, None), (1000, 1.0, None),
+    (1000, 1.0, 0.5), (1024, 0.61, 0.25), (8192, 0.01, 0.125),
+    (8192, 0.61, 0.75), (8192, 0.61, 0.5), (9216, 0.0, 0.25),
+    (9216, 1.0, 1.0), (61440, 0.01, 0.05), (61440, 0.61, 0.61),
+    (131072, 0.61, 0.7), (131072, 0.01, 0.0078125), (1000, 0.61, 2.0),
+])
+def test_compact_matches_numpy(cap, share, out):
+    chunk, cols, live = _compact_case(cap, share, seed=cap + int(share * 100))
+    out_cap = cap if out is None else int(cap * out)
+    got, n = jax.jit(compact, static_argnums=1)(
+        chunk, None if out is None else out_cap)
+    rows = np.flatnonzero(live)
+    assert int(n) == len(rows)  # the true count, whatever was dropped
+    assert got.capacity == out_cap
+    rows = rows[:out_cap]
+    np.testing.assert_array_equal(
+        np.asarray(got.sel), np.arange(out_cap) < len(rows))
+    for (name, (_, d, v)), gd, gv in zip(cols.items(), got.data, got.valid):
+        want = np.zeros(out_cap, d.dtype)  # zeros / False past the rows
+        want[:len(rows)] = d[rows]
+        np.testing.assert_array_equal(np.asarray(gd), want, err_msg=name)
+        assert (gv is None) == (v is None)
+        if v is not None:
+            wv = np.zeros(out_cap, bool)
+            wv[:len(rows)] = v[rows]
+            np.testing.assert_array_equal(np.asarray(gv), wv, err_msg=name)
+
+
 def test_aggregate_basic_vs_pandas():
     rng = np.random.default_rng(0)
     n = 5000

@@ -15,7 +15,7 @@ import jax
 import pytest
 
 from starrocks_tpu.column import HostTable
-from starrocks_tpu.ops.common import PHASES
+from starrocks_tpu.ops.common import INDEX_METHOD, PHASES
 from starrocks_tpu.runtime.config import config
 from starrocks_tpu.runtime.session import Session
 from starrocks_tpu.storage.catalog import Catalog, tpch_catalog
@@ -72,6 +72,32 @@ def test_statement_lowers_with_operator_scopes(lowered, q):
     # every scope an operation sits in hangs under the root's
     root = sorted(EXPECTED[q])[0].split("/")[0]
     assert all(p.startswith(root) for p in paths)
+
+
+def test_q3_compacts_by_index_and_gather_without_a_scatter(lowered):
+    """`compact` stays the phase (EXPECTED[3]); below it an `index` and a
+    `gather` scope, and no scatter: on a v5e a scatter costs ~100 ns per
+    input row and int64 column, which was 32 of Q3's 35 s at SF10."""
+    stacks = [p.split("/") for p in SCOPED.findall(lowered[3])
+              if "/compact/" in p]
+    below = {s[s.index("compact") + 1] for s in stacks}
+    assert {"index", "gather"} <= below, sorted(below)
+    assert not [s for s in stacks if s[-1].startswith("scatter")]
+    assert any(s[-1] == "gather" and "gather" in s[:-1] for s in stacks)
+
+
+def test_q3_profile_names_each_compaction(ran):
+    """Beside an attempt's `capacities`: what each compaction of its program
+    shrank (rows in, slots out) and how the index was computed; on a
+    program-cache hit too (`ran` holds the second send)."""
+    _, result, _ = ran[3]
+    attempt = [a for a in result.profile.children
+               if "capacities" in a.infos][-1]
+    done = attempt.infos["compactions"]
+    assert any(key.startswith("shrink_") for key in done)
+    for key, c in done.items():
+        assert c["out_cap"] == attempt.infos["capacities"][key] < c["cap"]
+        assert c["method"] == INDEX_METHOD
 
 
 @pytest.mark.parametrize("q", sorted(EXPECTED))
