@@ -17,8 +17,11 @@ tests/tpch_oracle.py after the sends, by tests/test_tpch_sql.py's tolerance.
 Exits non-zero — with no JSON line — unless `jax.default_backend()` is
 "tpu"; no flag admits a CPU. Also non-zero on any mismatch, exception,
 missing counter, compile in a last send, off-device column or missing
-memory statistic. Seconds are printed as facts of this run, not as metrics.
-Last stdout line on success:
+memory statistic. With `--chips 4` it first runs the two collectives the
+engine works around (uint8 OR as an int32 psum, int64 min/max as an
+all_gather) on the real mesh against numpy, and prints every fragment
+program's module name with its compactions and exchanges. Seconds are
+printed as facts of this run, not as metrics. Last stdout line on success:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 """
@@ -103,17 +106,82 @@ def _oracle_frames(catalog) -> dict:
     return frames
 
 
-def _last_compactions() -> dict:
-    """The `compactions` info of the newest retained statement's last
-    attempt ({} where its program compacts nothing)."""
+def _last_attempt_info(name: str) -> dict:
+    """The info `name` of the newest retained statement's last attempt that
+    has it: `compactions` ({} where its program compacts nothing), and on a
+    mesh `programs`, module name -> that fragment program's compactions and
+    exchanges."""
     from starrocks_tpu.runtime.profile import PROFILE_MANAGER
 
     entries = PROFILE_MANAGER.snapshot()
     attempts = ((entries[-1]["profile"] or {}).get("children", ())
                 if entries else ())
-    done = [a["infos"]["compactions"] for a in attempts
-            if "compactions" in a.get("infos", {})]
+    done = [a["infos"][name] for a in attempts if name in a.get("infos", {})]
     return done[-1] if done else {}
+
+
+def _check_collectives(chips: int, seed: int) -> list:
+    """The two collectives the engine works around, on the real mesh against
+    numpy: a bitwise OR of uint8 lanes as an int32 psum, and an int64 min/max
+    as an all_gather and a local reduce (ops/join.py). On a 2x2 v5e mesh
+    `lax.pmax` on uint8 returned wrong lanes and int64 `pmin` did not
+    compile (PR 21); virtual CPU devices show neither fault, so only this run
+    holds the workarounds to the chips. What the plain collectives do today
+    is printed as a fact and fails nothing. Returns the failures."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from starrocks_tpu.ops.join import (_min_max_across_shards,
+                                        _or_across_shards)
+    from starrocks_tpu.parallel.mesh import DATA_AXIS, make_mesh, shard_map
+
+    rng = np.random.default_rng(seed)
+    lanes = (rng.random((chips, 65536)) < 0.1).astype(np.uint8)
+    bounds = rng.integers(-2**62, 2**62, size=(chips, 2), dtype=np.int64)
+    mesh, spec = make_mesh(chips), P(DATA_AXIS)
+
+    def on_mesh(step):
+        return jax.jit(shard_map(step, mesh, in_specs=(spec, spec),
+                                 out_specs=(spec, spec)))(lanes, bounds)
+
+    def workarounds(lanes, bounds):
+        lo, hi = _min_max_across_shards(bounds[0, 0], bounds[0, 1], DATA_AXIS)
+        return (_or_across_shards(lanes[0], DATA_AXIS)[None],
+                jnp.stack([lo, hi])[None])
+
+    def plain(lanes, bounds):
+        return (jax.lax.pmax(lanes[0], DATA_AXIS)[None],
+                jnp.stack([jax.lax.pmin(bounds[0, 0], DATA_AXIS),
+                           jax.lax.pmax(bounds[0, 1], DATA_AXIS)])[None])
+
+    want_lanes = lanes.any(axis=0).astype(np.uint8)
+    want_bounds = np.array([bounds[:, 0].min(), bounds[:, 1].max()])
+    failures = []
+    merged, reduced = (np.asarray(x) for x in on_mesh(workarounds))
+    wrong_lanes = int((merged != want_lanes[None]).sum())
+    wrong_bounds = int((reduced != want_bounds[None]).sum())
+    print(f"collective or_by_int32_psum shards={chips} lanes=65536 "
+          f"set={int(want_lanes.sum())} mismatches={wrong_lanes}")
+    print(f"collective int64_min_max_by_all_gather shards={chips} "
+          f"mismatches={wrong_bounds}")
+    if wrong_lanes:
+        failures.append(f"uint8 OR by int32 psum: {wrong_lanes} lanes differ "
+                        "from numpy")
+    if wrong_bounds:
+        failures.append(f"int64 min/max by all_gather: {reduced.tolist()} vs "
+                        f"numpy {want_bounds.tolist()}")
+    try:
+        merged, reduced = (np.asarray(x) for x in on_mesh(plain))
+        print(f"collective plain pmax_uint8_mismatches="
+              f"{int((merged != want_lanes[None]).sum())} "
+              f"pmin_pmax_int64_mismatches="
+              f"{int((reduced != want_bounds[None]).sum())} (not used)")
+    except Exception as e:  # noqa: BLE001 — a fact of the backend, not a failure
+        print(f"collective plain does_not_compile={type(e).__name__}: "
+              f"{str(e).splitlines()[0][:160]} (not used)")
+    return failures
 
 
 def _versions() -> dict:
@@ -158,6 +226,9 @@ def run(sf: float, chips: int, seed: int) -> dict:
     if chips > len(devices):
         failures.append(f"--chips {chips} but JAX has {len(devices)} devices")
         return {"ok": False, "failures": failures, "device": device}
+
+    if chips > 1:
+        failures += _check_collectives(chips, seed)
 
     t0 = time.monotonic()
     catalog = tpch_catalog(sf, seed)
@@ -213,12 +284,20 @@ def run(sf: float, chips: int, seed: int) -> dict:
                     f"({sends[-1]['compiles']} programs)")
             seen.add(qid)
             record = {"statement": name, "sends": sends}
-            done = _last_compactions()
+            done = _last_attempt_info("compactions")
             if done:
                 # rows in, slots out and index method of each `compact` in
                 # the program the last send ran
                 record["compactions"] = done
                 print(f"compactions {name} {json.dumps(done)}")
+            programs = _last_attempt_info("programs")
+            if programs:
+                # on a mesh: each fragment program the last send ran
+                record["programs"] = programs
+                for module, holds in programs.items():
+                    print(f"program {name} name={module} compactions="
+                          f"{json.dumps(holds['compactions'])} exchanges="
+                          f"{json.dumps(holds['exchanges'])}")
             statements.append((record, qid, key, rows))
 
         # what the statements left on the device, before anything is freed

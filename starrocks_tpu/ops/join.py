@@ -178,6 +178,15 @@ def _or_across_shards(lanes, axis: str):
         jax.lax.psum(jnp.asarray(lanes, jnp.int32), axis) > 0, jnp.uint8)
 
 
+def _min_max_across_shards(lo, hi, axis: str):
+    """(min of `lo`, max of `hi`) over the shards, for int64 scalars: the n
+    per-shard values gathered and reduced locally. NOT `lax.pmin`/`pmax`:
+    the TPU compiler lowers a 64-bit all-reduce for sums only (int64
+    pmin/pmax raise "Supported lowering only of Sum all reduce" on a v5e)."""
+    return (jnp.min(jax.lax.all_gather(lo, axis)),
+            jnp.max(jax.lax.all_gather(hi, axis)))
+
+
 @phase("rf")
 def runtime_filter_mask(
     probe: Chunk, build: Chunk, probe_keys, build_keys, bit_widths=None,
@@ -216,11 +225,7 @@ def runtime_filter_mask(
     bmin = jnp.min(jnp.where(b_ok, bk, _I64MAX))
     bmax = jnp.max(jnp.where(b_ok, bk, jnp.iinfo(jnp.int64).min))
     if axis is not None:
-        # gather the n per-shard bounds and reduce locally: the TPU compiler
-        # lowers a 64-bit all-reduce for sums only (int64 pmin/pmax raise
-        # "Supported lowering only of Sum all reduce" on a v5e)
-        bmin = jnp.min(jax.lax.all_gather(bmin, axis))
-        bmax = jnp.max(jax.lax.all_gather(bmax, axis))
+        bmin, bmax = _min_max_across_shards(bmin, bmax, axis)
     # All-NULL (or empty) build side: bmin stays I64MAX and bmax stays
     # I64MIN, so bmin > bmax and the conjunction below is ALL-FALSE. That is
     # the intended INNER/LEFT-SEMI semantics — an empty build key set

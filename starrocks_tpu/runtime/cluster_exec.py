@@ -249,6 +249,7 @@ class ClusterWorker:
         import jax
         import numpy as np
 
+        from .executor import program_name
         from .profile import RuntimeProfile
 
         fail_point("cluster::worker_exec")
@@ -275,10 +276,10 @@ class ClusterWorker:
 
         def attempt(caps, p):
             inputs = self.de._place(scans_meta)
-            out, checks = self.de._fragment_attempt(
-                plan, frag, caps, p, inputs, bnd, scans_meta)
-            return out, [(k, self.de._host_max(v))
-                         for k, v in checks.items()]
+            out, checks, ran = self.de._fragment_attempt(
+                plan, frag, caps, p, inputs, bnd, scans_meta,
+                f"{program_name(plan)}_f{fid}")
+            return out, self.de._attempt_infos(p, caps, [ran], checks)
 
         out = self.de._adaptive(prof, attempt)
         host = jax.tree_util.tree_map(lambda a: np.asarray(a), out)

@@ -35,9 +35,8 @@ from ..parallel.mesh import make_mesh, shard_map
 from ..sql.distributed import REPLICATED, compile_distributed, plan_scan_modes
 from . import lifecycle
 from .config import config
-from .executor import Executor
-from .failpoint import fail_point
-from .metrics import PROGRAM_COMPILES
+from .executor import Executor, program_name
+from .metrics import EXCHANGE_BYTES, EXCHANGE_SLOTS, EXCHANGES
 from .profile import RuntimeProfile
 
 
@@ -91,11 +90,16 @@ class DistExecutor(Executor):
         if out is not None:
             return out
 
+        key = ("dist", self.n, plan)
+        box: dict = {}
+
         def attempt(caps, p):
             def compile_cb():
                 compiled = compile_distributed(
                     plan, self.catalog, caps, self.n, self.axis
                 )
+                box["facts"] = self._name_program(
+                    compiled, key, program_name(plan, self._fb_fp()))
                 scans_meta = tuple(zip(compiled.scans, compiled.scan_modes))
                 inputs0 = self._place(scans_meta)
                 in_specs = tuple(
@@ -117,20 +121,87 @@ class DistExecutor(Executor):
                 return jax.jit(raw), scans_meta, raw
 
             out, checks = self._cached_attempt(
-                ("dist", self.n, plan), caps, p, compile_cb, self._place
-            )
-            p.set_info("n_shards", self.n)
-            return out, [
-                (k, self._host_max(v)) for k, v in checks.items()
-            ]
+                key, caps, p, compile_cb, self._place)
+            facts = self._ran(key, caps, box.pop("facts", None))
+            return out, self._attempt_infos(p, caps, [facts], checks)
 
         def publish(vals):
-            self.cache.bucket_last_set(
-                self.cache.program_bucket(("dist", self.n, plan)), vals)
+            self.cache.bucket_last_set(self.cache.program_bucket(key), vals)
 
         out = self._adaptive(profile, attempt, publish)
         self._bind_operators(profile, self._dist_node_ord(plan))
+        self._name_statement(profile, [key])
         return out
+
+    # --- names, facts and counters of mesh programs ---------------------------
+
+    def _fb_fp(self):
+        return (self._fb_ctx or {}).get("fp")
+
+    def _name_program(self, compiled, key, name: str) -> dict:
+        """The XLA module is named after the statement (`q_<8 hex>`, a
+        fragment's with `_f<fid>`), as on one chip: `compiled.fn` takes the
+        name before shard_map and jit wrap it. The name and the scope table
+        stay with the program's bucket, for cache hits, which never
+        re-trace. Returns what the trace will fill in about the program."""
+        compiled.fn.__name__ = compiled.fn.__qualname__ = name
+        self.cache.bucket_meta_set(
+            self.cache.program_bucket(key), "names", (name, compiled.scopes))
+        return {"name": name, "compactions": compiled.compactions,
+                "exchanges": compiled.exchanges}
+
+    def _ran(self, key, caps, fresh: dict | None) -> dict:
+        """After a mesh program ran, compiled just now (`fresh`) or cached:
+        its facts, and its exchanges on the counters — from the static
+        shapes of the program that ran, not the planner's estimates."""
+        facts = self._program_facts(
+            self.cache.program_bucket(key), caps, fresh)
+        done = facts.get("exchanges", ())
+        EXCHANGES.inc(len(done))
+        EXCHANGE_SLOTS.inc(sum(e["slots"] for e in done))
+        EXCHANGE_BYTES.inc(sum(e["bytes"] for e in done))
+        return facts
+
+    def _attempt_infos(self, p, caps, facts: list, checks: dict) -> list:
+        """The attempt's checks merged on the host, and on its profile what
+        its programs' facts say: `n_shards`, `programs` (module name -> its
+        compactions and exchanges), `compactions` (all of them, as on one
+        chip), and for every all_to_all `exchange_fill`, its fullest bucket
+        (the overflow check's value, on the host anyway) over the bucket's
+        capacity — skew and padding, read off a statement."""
+        keyed = [(k, self._host_max(v)) for k, v in checks.items()]
+        p.set_info("n_shards", self.n)
+        p.set_info("programs", {
+            f["name"]: {"compactions": dict(f["compactions"]),
+                        "exchanges": list(f["exchanges"])}
+            for f in facts if f})
+        done = {k: c for f in facts
+                for k, c in f.get("compactions", {}).items()}
+        if done:
+            p.set_info("compactions", done)
+        fullest = dict(keyed)
+        fill = {e["check"]: round(fullest[e["check"]]
+                                  / caps.values[e["check"]], 4)
+                for f in facts for e in f.get("exchanges", ())
+                if e["check"] in fullest}
+        if fill:
+            p.set_info("exchange_fill", fill)
+        return keyed
+
+    def _name_statement(self, profile, keys: list):
+        """A device trace back to this statement: `program` lists its
+        modules' names (jit_<name>; one a fragment, in fragment order),
+        their operations sit under sr.<kind>.<n> scopes; `query_id` joins
+        the fragment timers to information_schema.query_profiles."""
+        names = [n for n in (
+            self.cache.bucket_meta_get(self.cache.program_bucket(k), "names")
+            for k in keys) if n]
+        if names:
+            profile.set_info("program", [name for name, _ in names])
+            profile.set_info("scopes", names[0][1])
+        ctx = lifecycle.current()
+        if ctx is not None:
+            profile.set_info("query_id", ctx.qid)
 
     @staticmethod
     def _dist_node_ord(plan) -> dict:
@@ -278,25 +349,27 @@ class DistExecutor(Executor):
         if cluster is not None and self._cluster_eligible(ir, scans_meta):
             return self._run_cluster(cluster, plan, ir, scans_meta, profile)
 
+        name = program_name(plan, self._fb_fp())
+
         def attempt(caps, p):
             with p.timer("scan_to_device"):
                 inputs = self._place(scans_meta)
             outputs: dict = {}
             merged: dict = {}
+            facts: list = []
             for frag in ir.fragments:
                 bnd = tuple(outputs[d] for d in frag.deps)
-                out_f, checks = self._fragment_attempt(
-                    plan, frag, caps, p, inputs, bnd, scans_meta)
+                out_f, checks, ran = self._fragment_attempt(
+                    plan, frag, caps, p, inputs, bnd, scans_meta,
+                    f"{name}_f{frag.fid}")
                 outputs[frag.fid] = out_f
                 # capacity keys carry GLOBAL pre-order ordinals: a node's
                 # ops live in one fragment (re-emitted CSE twins compute
                 # identical values), so merging by update is exact
                 merged.update(checks)
-            p.set_info("n_shards", self.n)
+                facts.append(ran)
             final = outputs[ir.fragments[-1].fid]
-            return final, [
-                (k, self._host_max(v)) for k, v in merged.items()
-            ]
+            return final, self._attempt_infos(p, caps, facts, merged)
 
         def publish(vals):
             # the adoption seed: fragment 0's bucket is the first one
@@ -308,6 +381,8 @@ class DistExecutor(Executor):
 
         out = self._adaptive(profile, attempt, publish)
         self._bind_operators(profile, self._dist_node_ord(plan))
+        self._name_statement(profile, [
+            fragment_program_key(self.n, plan, f) for f in ir.fragments])
         return out
 
     @staticmethod
@@ -353,67 +428,46 @@ class DistExecutor(Executor):
         return outputs[ir.fragments[-1].fid]
 
     def _fragment_attempt(self, plan, frag, caps, p, inputs, bnd,
-                          scans_meta):
-        """Per-fragment program-cache protocol (the _cached_attempt analog
-        for step(inputs, bnd)). The capacity dict is SHARED across the
-        query's fragments — keys carry global plan ordinals — so a
-        fragment's program key is the full caps snapshot at its compile
-        time. A snapshot taken mid-first-run lacks downstream fragments'
-        keys, which costs one extra compile on the next run (the key then
+                          scans_meta, name: str):
+        """One fragment through the shared program-cache protocol
+        (Executor._cached_attempt), as step(inputs, bnd) under the module
+        name `name`. The capacity dict is SHARED across the query's
+        fragments — keys carry global plan ordinals — so a fragment's
+        program key is the full caps snapshot at its compile time. A
+        snapshot taken mid-first-run lacks downstream fragments' keys,
+        which costs one extra compile on the next run (the key then
         includes everything) and stabilizes from the run after — the same
         convergence the tightening pass already imposes on the monolithic
-        path."""
-        bucket = self.cache.program_bucket(
-            fragment_program_key(self.n, plan, frag))
-        self.cache.bucket_adopt_last(bucket, caps)
-        hit = self.cache.bucket_prog_get(
-            bucket, tuple(sorted(caps.values.items())))
-        raw = reads = None
-        if hit is None:
-            PROGRAM_COMPILES.inc()
-            p.add_counter("compiles", 1)
-            fail_point("executor::before_compile")
-            lifecycle.checkpoint("executor::before_compile")
-            # per-fragment compile vs execute split: the trace happens
-            # lazily inside the first call, so the compile timer covers
-            # lowering + trace and the execute timer the dispatched call
-            with p.timer(f"fragment_{frag.fid}_compile"), \
-                    config.record_reads() as reads:
-                fn, raw = self._compile_fragment(
-                    plan, frag, caps, inputs, bnd, scans_meta)
-                fail_point("executor::before_dispatch")
-                lifecycle.checkpoint("executor::before_dispatch")
-                out, checks = self._dispatch_and_wait(fn, (inputs, bnd), p)
-        else:
-            fn, _ = hit
-            fail_point("executor::before_dispatch")
-            lifecycle.checkpoint("executor::before_dispatch")
-            with p.timer(f"fragment_{frag.fid}_execute"):
-                out, checks = self._dispatch_and_wait(fn, (inputs, bnd), p)
-        if raw is not None:
-            self._verify_compile(raw, inputs, reads, p, extra_args=(bnd,))
-        self.cache.bucket_prog_put(
-            bucket, tuple(sorted(caps.values.items())), (fn, scans_meta))
-        self.cache.bucket_last_set(bucket, caps.values)
-        return out, checks
+        path. Returns (chunk, checks, the program's facts)."""
+        key = fragment_program_key(self.n, plan, frag)
+        box: dict = {}
 
-    def _compile_fragment(self, plan, frag, caps, inputs, bnd, scans_meta):
-        compiled = compile_distributed(
-            plan, self.catalog, caps, self.n, self.axis,
-            dict(self._scan_mode_dict(scans_meta, plan)), fragment=frag,
-        )
-        bnd_specs = tuple(
-            jax.tree_util.tree_map(lambda _: P(self.axis), ch)
-            for ch in bnd
-        )
-        out_spec = P() if frag.out_mode == REPLICATED else P(self.axis)
-        raw = shard_map(
-            compiled.fn, mesh=self.mesh,
-            in_specs=(self._scan_in_specs(inputs, scans_meta), bnd_specs),
-            out_specs=(out_spec, P(self.axis)),
-            check_vma=False,
-        )
-        return jax.jit(raw), raw
+        def compile_cb():
+            compiled = compile_distributed(
+                plan, self.catalog, caps, self.n, self.axis,
+                dict(self._scan_mode_dict(scans_meta, plan)), fragment=frag,
+            )
+            box["facts"] = self._name_program(compiled, key, name)
+            bnd_specs = tuple(
+                jax.tree_util.tree_map(lambda _: P(self.axis), ch)
+                for ch in bnd
+            )
+            out_spec = P() if frag.out_mode == REPLICATED else P(self.axis)
+            raw = shard_map(
+                compiled.fn, mesh=self.mesh,
+                in_specs=(self._scan_in_specs(inputs, scans_meta), bnd_specs),
+                out_specs=(out_spec, P(self.axis)),
+                check_vma=False,
+            )
+            return jax.jit(raw), scans_meta, raw
+
+        # per-fragment compile vs execute split: the trace happens lazily
+        # inside the first call, so the compile timer covers lowering +
+        # trace + that call, the execute timer a cached program's call
+        out, checks = self._cached_attempt(
+            key, caps, p, compile_cb, None, placed=inputs, extra_args=(bnd,),
+            phase=f"fragment_{frag.fid}")
+        return out, checks, self._ran(key, caps, box.pop("facts", None))
 
     @staticmethod
     def _scan_mode_dict(scans_meta, plan):

@@ -12,6 +12,7 @@ host loop around it implements
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import threading
@@ -1342,7 +1343,7 @@ class Executor:
             def compile_cb():
                 compiled = compile_plan(plan, self.catalog, caps)
                 trace_box["node_ord"] = compiled.node_ord
-                trace_box["compactions"] = compiled.compactions
+                trace_box["facts"] = {"compactions": compiled.compactions}
                 # the XLA module is named after the statement, not `run`
                 name = program_name(plan, fb_fp)
                 compiled.fn.__name__ = compiled.fn.__qualname__ = name
@@ -1378,15 +1379,10 @@ class Executor:
                 ("local", plan), caps, p, compile_cb, place_cb
             )
             # what this program's compactions were (rows in, slots out,
-            # index method): known from its trace, kept with the bucket
-            # under the capacities that key the program, for cache hits
-            bucket = self.cache.program_bucket(("local", plan))
-            key = ("compactions", tuple(sorted(caps.values.items())))
-            done = trace_box.pop("compactions", None)
-            if done is None:
-                done = self.cache.bucket_meta_get(bucket, key)
-            else:
-                self.cache.bucket_meta_set(bucket, key, done)
+            # index method)
+            done = self._program_facts(
+                self.cache.program_bucket(("local", plan)), caps,
+                trace_box.pop("facts", None)).get("compactions")
             if done:
                 p.set_info("compactions", dict(done))
             return out, [(k, int(v)) for k, v in checks.items()]
@@ -1409,6 +1405,17 @@ class Executor:
             profile.set_info("program", names[0])
             profile.set_info("scopes", names[1])
         return out
+
+    def _program_facts(self, bucket, caps, fresh: dict | None) -> dict:
+        """What a program's trace found out about it ({"compactions": ...},
+        for a mesh program also {"exchanges": ...}): `fresh` from the
+        attempt that compiled it, kept with the bucket under the capacities
+        that key the program, and read back there on a cache hit."""
+        key = ("facts", tuple(sorted(caps.values.items())))
+        if fresh is None:
+            return self.cache.bucket_meta_get(bucket, key) or {}
+        self.cache.bucket_meta_set(bucket, key, fresh)
+        return fresh
 
     @staticmethod
     def _bind_operators(profile, node_ord):
@@ -1603,8 +1610,10 @@ class Executor:
             jax.block_until_ready(out.data)
         return out, checks
 
-    def _cached_attempt(self, cache_key, caps, p, compile_cb, place_cb):
-        """Shared program-cache protocol for local + distributed attempts.
+    def _cached_attempt(self, cache_key, caps, p, compile_cb, place_cb,
+                        placed=None, extra_args=(), phase=None):
+        """Shared program-cache protocol for local, distributed and
+        per-fragment attempts.
 
         Caching is retrace-safe: the traced fns keep ALL mutable state inside
         the traced function and return overflow checks as a statically-keyed
@@ -1613,7 +1622,23 @@ class Executor:
 
         compile_cb returns (jitted_fn, scans, raw_fn): raw_fn is the
         un-jitted traceable program, handed to the trace auditor on every
-        fresh compile (cache hits were audited when first compiled)."""
+        fresh compile (cache hits were audited when first compiled). It is
+        called as fn(inputs, *extra_args): a fragment's boundary chunks ride
+        in extra_args. `placed`: inputs already on the device (the fragment
+        path places once for all of a statement's fragments), else
+        place_cb(scans) puts them there. `phase` names two more timers:
+        `<phase>_compile` around a fresh program's compile and first call,
+        `<phase>_execute` around a cached program's call (`fragment_<fid>`)."""
+        fresh_timer, cached_timer = [
+            p.timer(f"{phase}_{what}") if phase else contextlib.nullcontext()
+            for what in ("compile", "execute")]
+
+        def place(scans):
+            if placed is not None:
+                return placed
+            with p.timer("scan_to_device"):
+                return place_cb(scans)
+
         bucket = self.cache.program_bucket(cache_key)
         # adopt the last successful capacities: skips re-discovering
         # overflows (and usually any recompile) on repeated queries
@@ -1630,15 +1655,15 @@ class Executor:
             # (jit traces lazily INSIDE that call) — the key-completeness
             # checker's probe window
             w0, t0 = time.time(), time.perf_counter()
-            with config.record_reads() as reads:
+            with fresh_timer, config.record_reads() as reads:
                 fn, scans, raw = compile_cb()
-                with p.timer("scan_to_device"):
-                    inputs = place_cb(scans)
+                inputs = place(scans)
                 fail_point("executor::before_dispatch")
                 lifecycle.checkpoint("executor::before_dispatch")
                 _compile_sink.to = (p, getattr(raw, "__name__", None))
                 try:
-                    out, checks = self._dispatch_and_wait(fn, (inputs,), p)
+                    out, checks = self._dispatch_and_wait(
+                        fn, (inputs, *extra_args), p)
                 finally:
                     _compile_sink.to = None
             dur = time.perf_counter() - t0
@@ -1646,13 +1671,14 @@ class Executor:
             COMPILE_MS.observe(dur * 1000.0)
         else:
             fn, scans = hit
-            with p.timer("scan_to_device"):
-                inputs = place_cb(scans)
+            inputs = place(scans)
             fail_point("executor::before_dispatch")
             lifecycle.checkpoint("executor::before_dispatch")
-            out, checks = self._dispatch_and_wait(fn, (inputs,), p)
+            with cached_timer:
+                out, checks = self._dispatch_and_wait(
+                    fn, (inputs, *extra_args), p)
         if raw is not None:
-            self._verify_compile(raw, inputs, reads, p)
+            self._verify_compile(raw, inputs, reads, p, extra_args=extra_args)
         # caps defaults fill during the first trace; record entries after it
         self.cache.bucket_prog_put(
             bucket, tuple(sorted(caps.values.items())), (fn, scans))

@@ -203,6 +203,21 @@ PROGRAM_COMPILES = metrics.counter(
     "fresh program traces (cache misses across local/batched/hybrid paths)"
 )
 ROWS_LOADED = metrics.counter("sr_tpu_rows_loaded_total", "rows ingested")
+# Exchanges between a mesh's shards, counted on the host once per run of a
+# program from the static shapes of the program that ran
+# (parallel/exchange.py `_shape`; runtime/dist_executor.py), not from the
+# planner's estimates. A range exchange is two: its sample all_gather and
+# its all_to_all.
+EXCHANGES = metrics.counter(
+    "sr_tpu_exchanges_total",
+    "all_to_all and all_gather exchanges in the mesh programs that ran")
+EXCHANGE_SLOTS = metrics.counter(
+    "sr_tpu_exchange_slots_total",
+    "rows of send buffer one shard filled for them, padding included")
+EXCHANGE_BYTES = metrics.counter(
+    "sr_tpu_exchange_bytes_total",
+    "bytes one shard put on the interconnect for them: data and validity "
+    "columns and the live mask, to the n-1 other shards")
 
 
 class MetricsHistory:

@@ -30,6 +30,8 @@ shards.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -42,7 +44,7 @@ from ..ops import (
     limit_chunk, project, sort_chunk,
 )
 from ..ops.aggregate import FINAL, PARTIAL, decomposable, final_agg_exprs
-from ..ops.common import compact, eval_keys
+from ..ops.common import INDEX_METHOD, compact, eval_keys
 from ..ops.sort import _descending
 from ..ops.window import window_op
 from ..parallel.exchange import (
@@ -57,7 +59,7 @@ from .logical import (
 from .optimizer import and_all
 from .physical import (
     Caps, PlanError, _equi_pair, _key_bit_width, plan_scopes, scope_name,
-    unique_sets,
+    scope_table, unique_sets,
 )
 
 SHARDED = "sharded"
@@ -143,13 +145,22 @@ def _single_sort_rank(chunk, sort_keys):
 
 
 class DistCompiled:
-    def __init__(self, fn, scans, scan_modes, checks_meta, out_names, n_shards):
+    def __init__(self, fn, scans, scan_modes, checks_meta, out_names, n_shards,
+                 scopes=None, compactions=None, exchanges=None):
         self.fn = fn
         self.scans = scans  # list[(table, alias, columns)]
         self.scan_modes = scan_modes  # list[SHARDED|REPLICATED]
         self.checks_meta = checks_meta
         self.out_names = out_names
         self.n_shards = n_shards
+        # scope number -> node repr (physical.scope_table)
+        self.scopes = scopes or {}
+        # what fn's trace found out about the program, filled while it
+        # traces (as physical.Compiled.compactions is): `compactions` by
+        # key (`limit_<n>`, `topn_<n>`: rows in, slots out, index method),
+        # `exchanges` in program order (parallel/exchange.py `_shape`)
+        self.compactions = {} if compactions is None else compactions
+        self.exchanges = [] if exchanges is None else exchanges
 
 
 def plan_scan_modes(plan: LogicalPlan, catalog) -> dict:
@@ -213,6 +224,9 @@ def compile_distributed(
         return node_ord.setdefault(p, len(node_ord))
 
     scopes = plan_scopes(plan)
+    compactions: dict = {}
+    exchanges: list = []
+    all_gather = functools.partial(all_gather_chunk, axis=axis, log=exchanges)
 
     if recorder is not None:
         note = recorder.note
@@ -239,7 +253,7 @@ def compile_distributed(
     def gather(chunk, mode):
         if mode == REPLICATED:
             return chunk
-        return all_gather_chunk(chunk, axis)  # range- and hash-sharded alike
+        return all_gather(chunk)  # range- and hash-sharded alike
 
     def step(inputs, bnd=()):
         """Traced SPMD program; all mutable trace state lives inside (see
@@ -249,6 +263,7 @@ def compile_distributed(
         positionally; empty for monolithic compiles."""
         emit_memo: dict = {}
         checks: dict = {}
+        exchanges.clear()  # a retrace (the auditor's, a new input layout)
 
         def emit(p):
             if p in emit_memo:
@@ -305,7 +320,8 @@ def compile_distributed(
                     c = limit_chunk(c, k, 0)
                     kcap = pad_capacity(k)
                     if kcap < c.capacity:
-                        c, _ = compact(c, kcap)  # live <= k: no overflow
+                        # live <= k: no overflow
+                        c = compact_to(c, f"limit_{ordinal(p)}", kcap)
                 if _is_dist(m):
                     note(p, 0, p.child, "gather", (), REPLICATED, "limit",
                          m, c)
@@ -330,6 +346,11 @@ def compile_distributed(
             if isinstance(p, LJoin):
                 return emit_join(p)
             raise PlanError(f"cannot compile {type(p).__name__} distributed")
+
+        def compact_to(c, key: str, cap: int):
+            compactions[key] = {"cap": c.capacity, "out_cap": cap,
+                                "method": INDEX_METHOD}
+            return compact(c, cap)[0]
 
         def _emit_ctrs(p, ctrs, dist: bool):
             """'~ctr_' profile counters ride the checks channel, whose host
@@ -378,7 +399,8 @@ def compile_distributed(
                 note(p, 0, p.child, "hash", tuple(p.partition_by), out_mode,
                      "rows", m, c)
                 c, mxb = shuffle_chunk(
-                    c, tuple(p.partition_by), axis, n_shards, bcap
+                    c, tuple(p.partition_by), axis, n_shards, bcap,
+                    log=exchanges, check=key,
                 )
                 checks[key] = mxb[None]
             return win(c, True), out_mode
@@ -402,10 +424,11 @@ def compile_distributed(
                 local = srt(c, p.limit, True)
                 kcap = pad_capacity(p.limit)
                 if kcap < local.capacity:
-                    local, _ = compact(local, kcap)  # live<=limit: no overflow
+                    # live <= limit: no overflow
+                    local = compact_to(local, f"topn_{ordinal(p)}", kcap)
                 note(p, 0, p.child, "gather", (), REPLICATED, "topn",
                      m, local)
-                gathered = all_gather_chunk(local, axis)
+                gathered = all_gather(local)
                 return sort_chunk(gathered, p.keys, p.limit), REPLICATED
             rank = _single_sort_rank(c, p.keys)
             if rank is None:
@@ -418,7 +441,8 @@ def compile_distributed(
             bcap = caps.get(key, _default_bucket_cap(c.capacity, n_shards))
             note(p, 0, p.child, "range", (p.keys[0][0],), RANGE_SHARDED,
                  "rows", m, c)
-            part, mxb = range_partition_chunk(c, rank, axis, n_shards, bcap)
+            part, mxb = range_partition_chunk(
+                c, rank, axis, n_shards, bcap, log=exchanges, check=key)
             checks[key] = mxb[None]
             return sort_chunk(part, p.keys, None), RANGE_SHARDED
 
@@ -465,7 +489,7 @@ def compile_distributed(
                 # value in one place and the input is not colocated on the
                 # group keys: gather rows, aggregate COMPLETE.
                 note(p, 0, p.child, "gather", (), REPLICATED, "rows", m, c)
-                gathered = all_gather_chunk(c, axis)
+                gathered = all_gather(c)
                 kwargs = {}
                 if any(a.fn == "array_agg" for _, a in p.aggs):
                     akey = f"aggarr_{ordinal(p)}"
@@ -501,7 +525,9 @@ def compile_distributed(
                 )
                 note(p, 0, p.child, "hash", key_cols, out_mode, "partial",
                      m, part)
-                merged, mxb = shuffle_chunk(part, key_cols, axis, n_shards, bcap)
+                merged, mxb = shuffle_chunk(
+                    part, key_cols, axis, n_shards, bcap,
+                    log=exchanges, check=bkey)
                 checks[bkey] = mxb[None]
                 # final capacity = received capacity: group count there is
                 # bounded by received rows, so the final phase cannot overflow
@@ -514,7 +540,7 @@ def compile_distributed(
             cap = caps.get(key, agg_default)
             part, png = hash_aggregate(c, p.group_by, p.aggs, cap, mode=PARTIAL)
             note(p, 0, p.child, "gather", (), REPLICATED, "partial", m, part)
-            merged = all_gather_chunk(part, axis)
+            merged = all_gather(part)
             out, ng = hash_aggregate(
                 merged, final_group_by, final_agg_exprs(p.aggs), cap, mode=FINAL
             )
@@ -569,7 +595,7 @@ def compile_distributed(
                     # shard; gather the build side and cross-join locally
                     note(p, 1, p.right, "broadcast", (), REPLICATED,
                          "rows", rm0, rc)
-                    rc = all_gather_chunk(rc, axis)
+                    rc = all_gather(rc)
                     rm = REPLICATED
             else:
                 from .physical import choose_key_packing
@@ -605,7 +631,7 @@ def compile_distributed(
                                          and isinstance(bk_x, Col))):
                             note(p, 1, p.right, "broadcast", (), REPLICATED,
                                  "rows", rm0, rc)
-                            rc = all_gather_chunk(rc, axis)
+                            rc = all_gather(rc)
                             rm = REPLICATED
                             break
 
@@ -682,7 +708,8 @@ def compile_distributed(
                         key_name, _default_bucket_cap(chunk.capacity, n_shards)
                     )
                     out, mx = shuffle_chunk(
-                        chunk, tuple(keys_), axis, n_shards, cap_k, bit_widths
+                        chunk, tuple(keys_), axis, n_shards, cap_k, bit_widths,
+                        log=exchanges, check=key_name,
                     )
                     checks[key_name] = mx[None]
                     return out
@@ -727,7 +754,7 @@ def compile_distributed(
             elif _is_dist(rm):  # probe replicated, build sharded -> gather build
                 note(p, 1, p.right, "broadcast", (), REPLICATED,
                      "rows", rm0, rc)
-                rc = all_gather_chunk(rc, axis)
+                rc = all_gather(rc)
                 out_mode = REPLICATED if lm == REPLICATED else lm
             else:
                 # build replicated: local (broadcast) join; output follows probe
@@ -787,9 +814,11 @@ def compile_distributed(
             # interior fragments hand their sharded output to the consumer)
             note(None, 0, root_node, "gather", (), REPLICATED, "rows",
                  mode, chunk)
-            chunk = all_gather_chunk(chunk, axis)
+            chunk = all_gather(chunk)
         return chunk, checks
 
     return DistCompiled(
-        step, scans, scan_mode_list, None, root_node.output_names(), n_shards
+        step, scans, scan_mode_list, None, root_node.output_names(), n_shards,
+        scopes=scope_table(scopes), compactions=compactions,
+        exchanges=exchanges,
     )

@@ -26,14 +26,15 @@ def _serialized(name: str) -> bytes:
     return ProfileData.text_proto_to_serialized_xspace(text)
 
 
-def _run_over(tmp_path, trace: bytes, records):
+def _run_over(tmp_path, trace: bytes, records, chips: int = 1):
     """A run as `benchmarks/run.py` hands it to a metric's `compute`, with
     its trace where `run.py` leaves it."""
     trace_dir = tmp_path / "benchmarks" / ".traces" / "cell" / "plugins"
     os.makedirs(trace_dir)
     (trace_dir / "t.xplane.pb").write_bytes(trace)
-    cell = types.SimpleNamespace(root=str(tmp_path), name="cell", chips=1)
-    reduced = xplane.reduce(xplane.read(str(trace_dir / "t.xplane.pb")), 1)
+    cell = types.SimpleNamespace(root=str(tmp_path), name="cell", chips=chips)
+    reduced = xplane.reduce(xplane.read(str(trace_dir / "t.xplane.pb")),
+                            chips)
     return types.SimpleNamespace(
         cell=cell, trace=reduced,
         window={"epoch_start": 1_700_000_000.0, "records": records})
