@@ -108,8 +108,9 @@ def _oracle_frames(catalog) -> dict:
 
 def _last_attempt_info(name: str) -> dict:
     """The info `name` of the newest retained statement's last attempt that
-    has it: `compactions` ({} where its program compacts nothing), and on a
-    mesh `programs`, module name -> that fragment program's compactions and
+    has it: `compactions` ({} where its program compacts nothing),
+    `segment_sums` (an aggregate scope -> its batch of integer sums), and on
+    a mesh `programs`, module name -> that fragment program's compactions and
     exchanges."""
     from starrocks_tpu.runtime.profile import PROFILE_MANAGER
 
@@ -290,6 +291,13 @@ def run(sf: float, chips: int, seed: int) -> dict:
                 # the program the last send ran
                 record["compactions"] = done
                 print(f"compactions {name} {json.dumps(done)}")
+            sums = _last_attempt_info("segment_sums")
+            if sums:
+                # per aggregate scope: rows, groups, integer columns handed
+                # in and summed, limbs made and the formulation of its one
+                # batch of segment sums
+                record["segment_sums"] = sums
+                print(f"segment_sums {name} {json.dumps(sums)}")
             programs = _last_attempt_info("programs")
             if programs:
                 # on a mesh: each fragment program the last send ran

@@ -30,8 +30,7 @@ from ..exprs.compile import EVal, ExprCompiler
 from ..exprs.ir import AggExpr, Col, Expr
 from .common import boundaries, eval_keys, key_sort_arrays, phase
 from .segment import (
-    _group_bounds_sorted, seg_count, seg_first_index, seg_max, seg_min,
-    seg_sum,
+    _group_bounds_sorted, seg_first_index, seg_max, seg_min, seg_sums,
 )
 
 
@@ -394,28 +393,65 @@ def _emit_sketch_agg(cc, name, agg, cap, live_rows, reorder, gid,
 
 def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
                       num_groups, indices_sorted, arr_cap=256,
-                      aux_checks=None):
+                      aux_checks=None, extra_sums=(), sums_info=None,
+                      packed_groups=None):
     """Emit aggregate output columns — shared by the sort path (reorder
     permutes rows into group order) and the low-cardinality packed-gid path
-    (reorder is identity). live_rows is the row-liveness mask AFTER reorder."""
+    (reorder is identity). live_rows is the row-liveness mask AFTER reorder.
 
-    def _seg_sum(vals, nbits=64):
-        return seg_sum(vals, gid, num_groups, sorted_gid=indices_sorted,
-                       nbits=nbits)
+    Every segment sum of the node runs in ONE `seg_sums` batch: each
+    aggregate is a generator (`_agg_column`) that yields the sums it needs as
+    `[(vals, nbits), ...]`, is sent their results once the batch has run, and
+    returns its output columns `[(Field, data, valid), ...]`. `extra_sums`
+    ride in the same batch (the packed-gid path's group count).
+    `packed_groups`, on the packed-gid path: live rows' gids lie in [0,
+    packed_groups) and dead rows' outside (the packed domain, often far
+    under the capacity: Q1 has 6 of 1,024), so the sums run over that many
+    groups, padded with empty ones, and a sum's operand need not be masked
+    by liveness again. Returns (fields, data, valid, results of
+    extra_sums); `sums_info`, when given, is filled with what the batch
+    was (`seg_sums`)."""
+    masked_args: dict = {}  # arg expr -> (EVal, its live-and-valid mask)
+    sum_operands: dict = {}  # (arg expr, sum type) -> the masked values
 
-    out_fields, out_data, out_valid = [], [], []
-    for name, agg in aggs:
+    def live_and_valid(a):
+        return live_rows if a.valid is None else (
+            live_rows & reorder(jnp.broadcast_to(a.valid, (cap,))))
+
+    def masked_arg(arg):
+        """The argument and its row mask, one object an expression: equal
+        operands of different aggregates (sum(x), avg(x), their nonempty
+        masks) reach `seg_sums` as the same array and are summed once."""
+        if arg not in masked_args:
+            a = cc.eval(arg)
+            masked_args[arg] = (a, live_and_valid(a))
+        return masked_args[arg]
+
+    def sum_operand(arg, out_t):
+        key = (arg, out_t)
+        if key not in sum_operands:
+            a, m = masked_arg(arg)
+            d = reorder(jnp.broadcast_to(_to_rep(a, out_t), (cap,)))
+            # gid alone already drops the dead rows of the packed-gid path:
+            # a second select would only keep XLA from reading the column
+            # straight into the reduction (4 ms of Q1 at SF10)
+            sum_operands[key] = d if (
+                packed_groups is not None and m is live_rows
+            ) else jnp.where(m, d, 0)
+        return sum_operands[key]
+
+    # a count's operand is a 0/1 mask: one limb. FINAL sums partial counts.
+    cnt_bits = 1 if mode != FINAL else 64
+
+    def _agg_column(name, agg):
         if agg.fn in ("count_star",) or (agg.fn == "count" and agg.arg is None):
             if mode == FINAL:
                 st = cc.eval(Col(name))
                 v = jnp.where(live_rows, reorder(jnp.asarray(st.data, jnp.int64)), 0)
-                cnt = _seg_sum(v)
+                cnt, = yield [(v, 64)]
             else:
-                cnt = _seg_sum(live_rows, nbits=1)
-            out_fields.append(Field(name, T.BIGINT, False))
-            out_data.append(cnt)
-            out_valid.append(None)
-            continue
+                cnt, = yield [(live_rows, 1)]
+            return [(Field(name, T.BIGINT, False), cnt, None)]
 
         if agg.fn == "avg":
             if mode == FINAL:
@@ -425,33 +461,19 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
                 vals = jnp.where(live_rows, reorder(jnp.asarray(sv.data)), 0)
                 cnts = jnp.where(live_rows, reorder(jnp.asarray(cv.data)), 0)
             else:
-                a = cc.eval(agg.arg)
+                a, cnts = masked_arg(agg.arg)
                 sum_t = _sum_out_type(a.type)
-                d = reorder(jnp.broadcast_to(_to_rep(a, sum_t), (cap,)))
-                m = live_rows if a.valid is None else (
-                    live_rows & reorder(jnp.broadcast_to(a.valid, (cap,)))
-                )
-                vals = jnp.where(m, d, 0)
-                cnts = jnp.asarray(m, jnp.int64)
-            gsum = _seg_sum(vals)
-            gcnt = _seg_sum(cnts, nbits=1 if mode != FINAL else 64)
+                vals = sum_operand(agg.arg, sum_t)
+            gsum, gcnt = yield [(vals, 64), (cnts, cnt_bits)]
             if mode == PARTIAL:
-                out_fields.append(Field(f"{name}__sum", sum_t, False))
-                out_data.append(gsum)
-                out_valid.append(None)
-                out_fields.append(Field(f"{name}__cnt", T.BIGINT, False))
-                out_data.append(gcnt)
-                out_valid.append(None)
+                return [(Field(f"{name}__sum", sum_t, False), gsum, None),
+                        (Field(f"{name}__cnt", T.BIGINT, False), gcnt, None)]
+            denom = jnp.maximum(gcnt, 1)
+            if sum_t.is_decimal:
+                res = jnp.asarray(gsum, jnp.float64) / (10 ** sum_t.scale) / denom
             else:
-                denom = jnp.maximum(gcnt, 1)
-                if sum_t.is_decimal:
-                    res = jnp.asarray(gsum, jnp.float64) / (10 ** sum_t.scale) / denom
-                else:
-                    res = jnp.asarray(gsum, jnp.float64) / denom
-                out_fields.append(Field(name, T.DOUBLE, True))
-                out_data.append(res)
-                out_valid.append(gcnt > 0)
-            continue
+                res = jnp.asarray(gsum, jnp.float64) / denom
+            return [(Field(name, T.DOUBLE, True), res, gcnt > 0)]
 
         if agg.fn in _VAR_FNS:
             if mode == FINAL:
@@ -459,112 +481,81 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
                 s2 = _read_state(cc, f"{name}__ssq", live_rows, reorder)
                 cnts = _read_state(cc, f"{name}__cnt", live_rows, reorder)
             else:
-                a = cc.eval(agg.arg)
+                a, cnts = masked_arg(agg.arg)
                 d = reorder(jnp.broadcast_to(_as_f64(a), (cap,)))
-                m = live_rows if a.valid is None else (
-                    live_rows & reorder(jnp.broadcast_to(a.valid, (cap,)))
-                )
-                s1 = jnp.where(m, d, 0.0)
-                s2 = jnp.where(m, d * d, 0.0)
-                cnts = jnp.asarray(m, jnp.int64)
-            gs1 = _seg_sum(s1)
-            gs2 = _seg_sum(s2)
-            gn = _seg_sum(cnts, nbits=1 if mode != FINAL else 64)
+                s1 = jnp.where(cnts, d, 0.0)
+                s2 = jnp.where(cnts, d * d, 0.0)
+            gs1, gs2, gn = yield [(s1, 64), (s2, 64), (cnts, cnt_bits)]
             if mode == PARTIAL:
-                out_fields += [Field(f"{name}__sum", T.DOUBLE, False),
-                               Field(f"{name}__ssq", T.DOUBLE, False),
-                               Field(f"{name}__cnt", T.BIGINT, False)]
-                out_data += [gs1, gs2, gn]
-                out_valid += [None, None, None]
-            else:
-                samp = agg.fn.endswith("_samp")
-                denom = jnp.maximum(gn - (1 if samp else 0), 1)
-                var = jnp.maximum(
-                    (gs2 - gs1 * gs1 / jnp.maximum(gn, 1)) / denom, 0.0)
-                res = jnp.sqrt(var) if agg.fn.startswith("stddev") else var
-                out_fields.append(Field(name, T.DOUBLE, True))
-                out_data.append(res)
-                out_valid.append(gn > (1 if samp else 0))
-            continue
+                return [(Field(f"{name}__sum", T.DOUBLE, False), gs1, None),
+                        (Field(f"{name}__ssq", T.DOUBLE, False), gs2, None),
+                        (Field(f"{name}__cnt", T.BIGINT, False), gn, None)]
+            samp = agg.fn.endswith("_samp")
+            denom = jnp.maximum(gn - (1 if samp else 0), 1)
+            var = jnp.maximum(
+                (gs2 - gs1 * gs1 / jnp.maximum(gn, 1)) / denom, 0.0)
+            res = jnp.sqrt(var) if agg.fn.startswith("stddev") else var
+            return [(Field(name, T.DOUBLE, True), res,
+                     gn > (1 if samp else 0))]
 
         if agg.fn in _COVAR_FNS:
+            suffixes = ("sx", "sy", "sxy", "sxx", "syy")
             if mode == FINAL:
-                sx = _read_state(cc, f"{name}__sx", live_rows, reorder)
-                sy = _read_state(cc, f"{name}__sy", live_rows, reorder)
-                sxy = _read_state(cc, f"{name}__sxy", live_rows, reorder)
-                sxx = _read_state(cc, f"{name}__sxx", live_rows, reorder)
-                syy = _read_state(cc, f"{name}__syy", live_rows, reorder)
+                moments = [_read_state(cc, f"{name}__{sfx}", live_rows, reorder)
+                           for sfx in suffixes]
                 cnts = _read_state(cc, f"{name}__cnt", live_rows, reorder)
             else:
                 ax = cc.eval(agg.arg)
                 ay = cc.eval(agg.extra[0])
                 dx = reorder(jnp.broadcast_to(_as_f64(ax), (cap,)))
                 dy = reorder(jnp.broadcast_to(_as_f64(ay), (cap,)))
-                m = live_rows
+                cnts = live_rows
                 for v in (ax.valid, ay.valid):
                     if v is not None:
-                        m = m & reorder(jnp.broadcast_to(v, (cap,)))
-                sx = jnp.where(m, dx, 0.0)
-                sy = jnp.where(m, dy, 0.0)
-                sxy = jnp.where(m, dx * dy, 0.0)
-                sxx = jnp.where(m, dx * dx, 0.0)
-                syy = jnp.where(m, dy * dy, 0.0)
-                cnts = jnp.asarray(m, jnp.int64)
-            gx, gy, gxy = _seg_sum(sx), _seg_sum(sy), _seg_sum(sxy)
-            gxx, gyy = _seg_sum(sxx), _seg_sum(syy)
-            gn = _seg_sum(cnts, nbits=1 if mode != FINAL else 64)
+                        cnts = cnts & reorder(jnp.broadcast_to(v, (cap,)))
+                moments = [jnp.where(cnts, d, 0.0)
+                           for d in (dx, dy, dx * dy, dx * dx, dy * dy)]
+            *sums, gn = yield [(d, 64) for d in moments] + [(cnts, cnt_bits)]
             if mode == PARTIAL:
-                for suffix, dat in [("sx", gx), ("sy", gy), ("sxy", gxy),
-                                    ("sxx", gxx), ("syy", gyy)]:
-                    out_fields.append(Field(f"{name}__{suffix}", T.DOUBLE, False))
-                    out_data.append(dat)
-                    out_valid.append(None)
-                out_fields.append(Field(f"{name}__cnt", T.BIGINT, False))
-                out_data.append(gn)
-                out_valid.append(None)
+                return [(Field(f"{name}__{sfx}", T.DOUBLE, False), dat, None)
+                        for sfx, dat in zip(suffixes, sums)] + [
+                            (Field(f"{name}__cnt", T.BIGINT, False), gn, None)]
+            gx, gy, gxy, gxx, gyy = sums
+            nf = jnp.maximum(gn, 1)
+            if agg.fn == "corr":
+                num = gn * gxy - gx * gy
+                den2 = (gn * gxx - gx * gx) * (gn * gyy - gy * gy)
+                den = jnp.sqrt(jnp.maximum(den2, 0.0))
+                res = num / jnp.where(den > 0, den, 1.0)
+                ok = (gn > 0) & (den > 0)
             else:
-                nf = jnp.maximum(gn, 1)
-                if agg.fn == "corr":
-                    num = gn * gxy - gx * gy
-                    den2 = (gn * gxx - gx * gx) * (gn * gyy - gy * gy)
-                    den = jnp.sqrt(jnp.maximum(den2, 0.0))
-                    res = num / jnp.where(den > 0, den, 1.0)
-                    ok = (gn > 0) & (den > 0)
+                cov = gxy - gx * gy / nf
+                if agg.fn == "covar_samp":
+                    res = cov / jnp.maximum(gn - 1, 1)
+                    ok = gn > 1
                 else:
-                    cov = gxy - gx * gy / nf
-                    if agg.fn == "covar_samp":
-                        res = cov / jnp.maximum(gn - 1, 1)
-                        ok = gn > 1
-                    else:
-                        res = cov / nf
-                        ok = gn > 0
-                out_fields.append(Field(name, T.DOUBLE, True))
-                out_data.append(res)
-                out_valid.append(ok)
-            continue
+                    res = cov / nf
+                    ok = gn > 0
+            return [(Field(name, T.DOUBLE, True), res, ok)]
 
         if agg.fn in _SKETCH_FNS:
             if mode != COMPLETE:
                 raise NotImplementedError(
                     f"{agg.fn} cannot be split into partial/final")
-            f, d, v = _emit_sketch_agg(cc, name, agg, cap, live_rows,
-                                       reorder, gid, num_groups)
-            out_fields.append(f)
-            out_data.append(d)
-            out_valid.append(v)
-            continue
+            yield []
+            return [_emit_sketch_agg(cc, name, agg, cap, live_rows,
+                                     reorder, gid, num_groups)]
 
         if agg.fn in _HOLISTIC_FNS and agg.fn != "array_agg":
             if mode != COMPLETE:
                 raise NotImplementedError(
                     f"{agg.fn} cannot be split into partial/final")
+            yield []
             a = cc.eval(agg.arg)
             assert not a.type.is_string, f"{agg.fn} over strings"
             frac = float(agg.extra[0].value)
             d = reorder(jnp.broadcast_to(jnp.asarray(a.data), (cap,)))
-            m = live_rows if a.valid is None else (
-                live_rows & reorder(jnp.broadcast_to(a.valid, (cap,)))
-            )
+            m = live_and_valid(a)
             gidm = jnp.where(m, jnp.asarray(gid, jnp.int32), num_groups)
             order2 = jnp.lexsort((d, gidm))
             g2 = gidm[order2]
@@ -581,20 +572,18 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
                 t = fpos - lo
                 vlo = vf[jnp.clip(left + lo, 0, cap - 1)]
                 vhi = vf[jnp.clip(left + hi, 0, cap - 1)]
-                res = vlo * (1 - t) + vhi * t
-                out_fields.append(Field(name, T.DOUBLE, True))
-            else:  # percentile_disc: smallest value with cum_dist >= frac
-                k = jnp.clip(
-                    jnp.ceil(frac * jnp.asarray(cnt, jnp.float64)).astype(
-                        jnp.int64) - 1, 0, jnp.maximum(cnt - 1, 0))
-                res = v2[jnp.clip(left + k, 0, cap - 1)]
-                out_fields.append(Field(name, a.type, True, a.dict))
-            out_data.append(res)
-            out_valid.append(ok)
-            continue
+                return [(Field(name, T.DOUBLE, True),
+                         vlo * (1 - t) + vhi * t, ok)]
+            # percentile_disc: smallest value with cum_dist >= frac
+            k = jnp.clip(
+                jnp.ceil(frac * jnp.asarray(cnt, jnp.float64)).astype(
+                    jnp.int64) - 1, 0, jnp.maximum(cnt - 1, 0))
+            res = v2[jnp.clip(left + k, 0, cap - 1)]
+            return [(Field(name, a.type, True, a.dict), res, ok)]
 
         # sum / min / max / count(x)
-        a = cc.eval(Col(name)) if mode == FINAL else cc.eval(agg.arg)
+        a, m = (masked_arg(agg.arg) if mode != FINAL
+                else masked_arg(Col(name)))
         if a.type.is_decimal128 and agg.fn in ("min", "max"):
             # lexicographic limb refinement: per limb (ms->ls), keep only
             # rows still tied on all more-significant limbs and take the
@@ -602,8 +591,6 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
             from . import dec128 as d128
 
             is_min = agg.fn == "min"
-            m = live_rows if a.valid is None else (
-                live_rows & reorder(jnp.broadcast_to(a.valid, (cap,))))
             d = reorder(jnp.asarray(a.data))
             adj = d128.cmp_limbs(d)
             ident = (1 << 32) if is_min else -1
@@ -620,57 +607,42 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
             best_limbs[0] = best_limbs[0] ^ 0x80000000  # undo sign adjust
             res = jnp.stack([jnp.asarray(x, jnp.int64) & 0xFFFFFFFF
                              for x in best_limbs], axis=1)
-            nonempty = _seg_sum(m, nbits=1) > 0
-            out_fields.append(Field(name, a.type, True))
-            out_data.append(res)
-            out_valid.append(nonempty)
-            continue
+            live_cnt, = yield [(m, 1)]
+            return [(Field(name, a.type, True), res, live_cnt > 0)]
         if a.type.is_decimal128 and agg.fn not in ("sum", "count"):
             raise NotImplementedError(
                 f"{agg.fn} over DECIMAL(>18) is not supported yet "
                 "(sum/count/avg-via-sum are; cast to DOUBLE for the rest)")
-        m = live_rows if a.valid is None else (
-            live_rows & reorder(jnp.broadcast_to(a.valid, (cap,)))
-        )
 
         if agg.fn == "count":
             if mode == FINAL:
                 vals = jnp.where(m, reorder(jnp.asarray(a.data, jnp.int64)), 0)
-                res = _seg_sum(vals)
+                res, = yield [(vals, 64)]
             else:
-                res = _seg_sum(m, nbits=1)
-            out_fields.append(Field(name, T.BIGINT, False))
-            out_data.append(res)
-            out_valid.append(None)
-        elif agg.fn == "sum" and a.type.is_decimal128:
+                res, = yield [(m, 1)]
+            return [(Field(name, T.BIGINT, False), res, None)]
+        if agg.fn == "sum" and a.type.is_decimal128:
             # 128-bit exact sum: per-32-bit-limb segment sums (limb sums of
             # up to 2^31 rows fit int64), then one device carry-propagation
             # pass; wraps mod 2^128 like the reference's int128 accumulator
             d = reorder(jnp.asarray(a.data))  # [cap, 4] limbs, ms first
-            limb_sums = [
-                _seg_sum(jnp.where(m, d[:, i] & 0xFFFFFFFF, 0))
-                for i in range(4)
-            ]
+            *limb_sums, live_cnt = yield [
+                (jnp.where(m, d[:, i] & 0xFFFFFFFF, 0), 32)
+                for i in range(4)] + [(m, 1)]
             out_limbs = [None] * 4
             carry = jnp.zeros_like(limb_sums[0])
             for i in (3, 2, 1, 0):  # least significant first
                 tot = limb_sums[i] + carry
                 out_limbs[i] = tot & 0xFFFFFFFF
                 carry = tot >> 32
-            res = jnp.stack(out_limbs, axis=1)
-            nonempty = _seg_sum(m, nbits=1) > 0
-            out_fields.append(Field(name, a.type, True))
-            out_data.append(res)
-            out_valid.append(nonempty)
-        elif agg.fn == "sum":
+            return [(Field(name, a.type, True), jnp.stack(out_limbs, axis=1),
+                     live_cnt > 0)]
+        if agg.fn == "sum":
             out_t = a.type if mode == FINAL else _sum_out_type(a.type)
-            d = reorder(jnp.broadcast_to(_to_rep(a, out_t), (cap,)))
-            res = _seg_sum(jnp.where(m, d, 0))
-            nonempty = _seg_sum(m, nbits=1) > 0
-            out_fields.append(Field(name, out_t, True))
-            out_data.append(res)
-            out_valid.append(nonempty)
-        elif agg.fn in ("min", "max"):
+            vals = sum_operand(Col(name) if mode == FINAL else agg.arg, out_t)
+            res, live_cnt = yield [(vals, 64), (m, 1)]
+            return [(Field(name, out_t, True), res, live_cnt > 0)]
+        if agg.fn in ("min", "max"):
             is_min = agg.fn == "min"
             ident = _minmax_identity(a.type, is_min)
             d = reorder(jnp.broadcast_to(jnp.asarray(a.data), (cap,)))
@@ -678,11 +650,9 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
             segfn = seg_min if is_min else seg_max
             res = segfn(dd, gid, num_groups, identity=ident,
                         sorted_gid=indices_sorted)
-            nonempty = _seg_sum(m, nbits=1) > 0
-            out_fields.append(Field(name, a.type, True, a.dict))
-            out_data.append(res)
-            out_valid.append(nonempty)
-        elif agg.fn == "array_agg":
+            live_cnt, = yield [(m, 1)]
+            return [(Field(name, a.type, True, a.dict), res, live_cnt > 0)]
+        if agg.fn == "array_agg":
             if not indices_sorted:
                 raise NotImplementedError(
                     "array_agg requires the sorted aggregation path")
@@ -697,19 +667,33 @@ def _emit_agg_columns(cc, aggs, mode, cap, live_rows, reorder, gid,
             pi = jnp.where(ok, pos, 0)
             mat = jnp.zeros((num_groups + 1, arr_cap + 1), d.dtype)
             mat = mat.at[gi, 1 + pi].set(d, mode="drop")
-            counts = seg_count(m, gid, num_groups,
-                               sorted_gid=indices_sorted)
+            counts, = yield [(m, 1)]
             if aux_checks is not None:
                 aux_checks["array_agg_max"] = jnp.max(
                     jnp.concatenate([counts, jnp.zeros(1, counts.dtype)]))
             mat = mat.at[:num_groups, 0].set(
                 jnp.asarray(jnp.minimum(counts, arr_cap), d.dtype))
-            out_fields.append(Field(name, T.ARRAY(a.type), True, a.dict))
-            out_data.append(mat[:num_groups])
-            out_valid.append(counts > 0)
-        else:
-            raise NotImplementedError(f"aggregate {agg.fn}")
-    return out_fields, out_data, out_valid
+            return [(Field(name, T.ARRAY(a.type), True, a.dict),
+                     mat[:num_groups], counts > 0)]
+        raise NotImplementedError(f"aggregate {agg.fn}")
+
+    def finish(column, results):
+        try:
+            column.send(results)
+        except StopIteration as done:
+            return done.value
+        raise AssertionError("an aggregate asks for its sums once")
+
+    columns = [_agg_column(name, agg) for name, agg in aggs]
+    wanted = [next(col) for col in columns] + [list(extra_sums)]
+    sum_groups = num_groups if packed_groups is None else packed_groups
+    sums = (jnp.pad(r, (0, num_groups - sum_groups)) for r in seg_sums(
+        [w for want in wanted for w in want], gid, sum_groups,
+        sorted_gid=indices_sorted, info=sums_info))
+    got = [[next(sums) for _ in want] for want in wanted]
+    out = [c for col, res in zip(columns, got) for c in finish(col, res)]
+    return ([f for f, _, _ in out], [d for _, d, _ in out],
+            [v for _, _, v in out], got[-1])
 
 
 def hash_aggregate(
@@ -720,11 +704,14 @@ def hash_aggregate(
     mode: str = COMPLETE,
     arr_cap: int = 256,
     aux_checks: dict | None = None,
+    sums_info: dict | None = None,
 ):
     """Returns (output_chunk, true_group_count). Output capacity=num_groups.
 
     In FINAL mode, `aggs` args must be Cols referring to the PARTIAL state
     columns produced by the same spec (avg reads name__sum / name__cnt).
+    `sums_info`, when given, is filled at trace time with what the node's
+    batch of segment sums was (`ops/segment.seg_sums`).
     """
     cc = ExprCompiler(chunk)
     cap = chunk.capacity
@@ -734,7 +721,8 @@ def hash_aggregate(
     lowcard = _try_lowcard(chunk, group_by, keys, live, num_groups, mode, aggs)
     if lowcard is not None:
         return _aggregate_with_gid(
-            chunk, cc, group_by, aggs, num_groups, mode, *lowcard, live=live
+            chunk, cc, group_by, aggs, num_groups, mode, *lowcard, live=live,
+            sums_info=sums_info,
         )
 
     out_fields, out_data, out_valid = [], [], []
@@ -783,9 +771,10 @@ def hash_aggregate(
         reorder = lambda x: x  # noqa: E731
 
     # --- aggregate columns ----------------------------------------------------
-    agg_fields, agg_data, agg_valid = _emit_agg_columns(
+    agg_fields, agg_data, agg_valid, _ = _emit_agg_columns(
         cc, aggs, mode, cap, live_s, reorder, gid, num_groups,
         indices_sorted=True, arr_cap=arr_cap, aux_checks=aux_checks,
+        sums_info=sums_info,
     )
     out_fields += agg_fields
     out_data += agg_data
@@ -830,7 +819,7 @@ def final_agg_exprs(aggs: tuple) -> tuple:
 
 
 def _aggregate_with_gid(chunk, cc, group_by, aggs, num_groups, mode,
-                        gid, infos, total, live):
+                        gid, infos, total, live, sums_info=None):
     """Aggregate via direct (unsorted) segment reductions over packed gids."""
     cap = chunk.capacity
 
@@ -843,10 +832,10 @@ def _aggregate_with_gid(chunk, cc, group_by, aggs, num_groups, mode,
         out_data.append(code)
         out_valid.append(kvalid)
 
-    group_count = seg_count(live, gid, num_groups)
-    agg_fields, agg_data, agg_valid = _emit_agg_columns(
+    agg_fields, agg_data, agg_valid, (group_count,) = _emit_agg_columns(
         cc, aggs, mode, cap, live, lambda x: x, gid, num_groups,
-        indices_sorted=False,
+        indices_sorted=False, extra_sums=((live, 1),), sums_info=sums_info,
+        packed_groups=total,
     )
     out_fields += agg_fields
     out_data += agg_data

@@ -239,7 +239,8 @@ config.define("matmul_segsum_groups_max", 1024, True,
               "max group count for the one-hot-matmul segment-sum strategy",
               trace=True)
 config.define("bcast_segreduce_groups_max", 64, True,
-              "max group count for broadcast-reduce segment min/max/float-sum",
+              "max group count for broadcast-reduce segment min/max/float-sum "
+              "and the masked integer sums",
               trace=True)
 config.define("batch_rows_threshold", 0, True,
               "stream scan-aggregations in host batches when a table exceeds "

@@ -148,7 +148,8 @@ class DistExecutor(Executor):
         self.cache.bucket_meta_set(
             self.cache.program_bucket(key), "names", (name, compiled.scopes))
         return {"name": name, "compactions": compiled.compactions,
-                "exchanges": compiled.exchanges}
+                "exchanges": compiled.exchanges,
+                "segment_sums": compiled.segment_sums}
 
     def _ran(self, key, caps, fresh: dict | None) -> dict:
         """After a mesh program ran, compiled just now (`fresh`) or cached:
@@ -165,8 +166,8 @@ class DistExecutor(Executor):
     def _attempt_infos(self, p, caps, facts: list, checks: dict) -> list:
         """The attempt's checks merged on the host, and on its profile what
         its programs' facts say: `n_shards`, `programs` (module name -> its
-        compactions and exchanges), `compactions` (all of them, as on one
-        chip), and for every all_to_all `exchange_fill`, its fullest bucket
+        compactions and exchanges), `compactions` and `segment_sums` (all of
+        them, as on one chip), and for every all_to_all `exchange_fill`, its fullest bucket
         (the overflow check's value, on the host anyway) over the bucket's
         capacity — skew and padding, read off a statement."""
         keyed = [(k, self._host_max(v)) for k, v in checks.items()]
@@ -179,6 +180,10 @@ class DistExecutor(Executor):
                 for k, c in f.get("compactions", {}).items()}
         if done:
             p.set_info("compactions", done)
+        sums = {k: c for f in facts
+                for k, c in f.get("segment_sums", {}).items()}
+        if sums:
+            p.set_info("segment_sums", sums)
         fullest = dict(keyed)
         fill = {e["check"]: round(fullest[e["check"]]
                                   / caps.values[e["check"]], 4)

@@ -1343,7 +1343,9 @@ class Executor:
             def compile_cb():
                 compiled = compile_plan(plan, self.catalog, caps)
                 trace_box["node_ord"] = compiled.node_ord
-                trace_box["facts"] = {"compactions": compiled.compactions}
+                trace_box["facts"] = {
+                    "compactions": compiled.compactions,
+                    "segment_sums": compiled.segment_sums}
                 # the XLA module is named after the statement, not `run`
                 name = program_name(plan, fb_fp)
                 compiled.fn.__name__ = compiled.fn.__qualname__ = name
@@ -1379,12 +1381,13 @@ class Executor:
                 ("local", plan), caps, p, compile_cb, place_cb
             )
             # what this program's compactions were (rows in, slots out,
-            # index method)
-            done = self._program_facts(
+            # index method) and its aggregates' batches of segment sums
+            facts = self._program_facts(
                 self.cache.program_bucket(("local", plan)), caps,
-                trace_box.pop("facts", None)).get("compactions")
-            if done:
-                p.set_info("compactions", dict(done))
+                trace_box.pop("facts", None))
+            for fact in ("compactions", "segment_sums"):
+                if facts.get(fact):
+                    p.set_info(fact, dict(facts[fact]))
             return out, [(k, int(v)) for k, v in checks.items()]
 
         def publish(vals):
@@ -1407,8 +1410,8 @@ class Executor:
         return out
 
     def _program_facts(self, bucket, caps, fresh: dict | None) -> dict:
-        """What a program's trace found out about it ({"compactions": ...},
-        for a mesh program also {"exchanges": ...}): `fresh` from the
+        """What a program's trace found out about it ({"compactions": ...,
+        "segment_sums": ...}, for a mesh program also {"exchanges": ...}): `fresh` from the
         attempt that compiled it, kept with the bucket under the capacities
         that key the program, and read back there on a cache hit."""
         key = ("facts", tuple(sorted(caps.values.items())))

@@ -43,7 +43,9 @@ from ..ops import (
     filter_chunk, hash_aggregate, hash_join_expand, hash_join_unique,
     limit_chunk, project, sort_chunk,
 )
-from ..ops.aggregate import FINAL, PARTIAL, decomposable, final_agg_exprs
+from ..ops.aggregate import (
+    COMPLETE, FINAL, PARTIAL, decomposable, final_agg_exprs,
+)
 from ..ops.common import INDEX_METHOD, compact, eval_keys
 from ..ops.sort import _descending
 from ..ops.window import window_op
@@ -146,7 +148,8 @@ def _single_sort_rank(chunk, sort_keys):
 
 class DistCompiled:
     def __init__(self, fn, scans, scan_modes, checks_meta, out_names, n_shards,
-                 scopes=None, compactions=None, exchanges=None):
+                 scopes=None, compactions=None, exchanges=None,
+                 segment_sums=None):
         self.fn = fn
         self.scans = scans  # list[(table, alias, columns)]
         self.scan_modes = scan_modes  # list[SHARDED|REPLICATED]
@@ -158,9 +161,12 @@ class DistCompiled:
         # what fn's trace found out about the program, filled while it
         # traces (as physical.Compiled.compactions is): `compactions` by
         # key (`limit_<n>`, `topn_<n>`: rows in, slots out, index method),
-        # `exchanges` in program order (parallel/exchange.py `_shape`)
+        # `exchanges` in program order (parallel/exchange.py `_shape`),
+        # `segment_sums` by aggregate scope, `/partial` and `/final` apart
+        # (physical.Compiled.segment_sums)
         self.compactions = {} if compactions is None else compactions
         self.exchanges = [] if exchanges is None else exchanges
+        self.segment_sums = {} if segment_sums is None else segment_sums
 
 
 def plan_scan_modes(plan: LogicalPlan, catalog) -> dict:
@@ -226,6 +232,7 @@ def compile_distributed(
     scopes = plan_scopes(plan)
     compactions: dict = {}
     exchanges: list = []
+    segment_sums: dict = {}
     all_gather = functools.partial(all_gather_chunk, axis=axis, log=exchanges)
 
     if recorder is not None:
@@ -447,6 +454,19 @@ def compile_distributed(
             return sort_chunk(part, p.keys, None), RANGE_SHARDED
 
         def emit_agg(p: LAggregate):
+            def aggregate(chunk, group_by, aggs, cap, mode=COMPLETE,
+                          **kwargs):
+                """ops.hash_aggregate, its batch of segment sums noted under
+                the node's scope (a two-phase node notes two)."""
+                info: dict = {}
+                out = hash_aggregate(chunk, group_by, aggs, cap, mode=mode,
+                                     sums_info=info, **kwargs)
+                if info:
+                    name = scope_name(scopes, p)
+                    segment_sums[name if mode == COMPLETE
+                                 else f"{name}/{mode}"] = info
+                return out
+
             c, m = emit(p.child)
             key = f"agg_{ordinal(p)}"
             agg_default = 1024 if p.group_by else 1
@@ -457,7 +477,7 @@ def compile_distributed(
                     aux: dict = {}
                     kwargs = {"arr_cap": caps.get(akey, 256),
                               "aux_checks": aux}
-                out, ng = hash_aggregate(c, p.group_by, p.aggs,
+                out, ng = aggregate(c, p.group_by, p.aggs,
                                          caps.get(key, agg_default), **kwargs)
                 checks[key] = ng[None]
                 if kwargs:
@@ -480,7 +500,7 @@ def compile_distributed(
                 default = 1024 if est is None else pad_capacity(
                     int(min(est * 2 // n_shards + 1024, c.capacity))
                 )
-                out, ng = hash_aggregate(c, p.group_by, p.aggs,
+                out, ng = aggregate(c, p.group_by, p.aggs,
                                          caps.get(key, default))
                 checks[key] = ng[None]
                 return out, ("hash", hash_out)
@@ -496,7 +516,7 @@ def compile_distributed(
                     aux: dict = {}
                     kwargs = {"arr_cap": caps.get(akey, 256),
                               "aux_checks": aux}
-                out, ng = hash_aggregate(gathered, p.group_by, p.aggs,
+                out, ng = aggregate(gathered, p.group_by, p.aggs,
                                          caps.get(key, agg_default), **kwargs)
                 checks[key] = ng[None]
                 if kwargs:
@@ -508,7 +528,7 @@ def compile_distributed(
                 # Seed the partial capacity from the estimate (bounded by the
                 # input capacity) — the 1024 default would always overflow
                 cap = caps.get(key, pad_capacity(int(min(est, c.capacity))))
-                part, png = hash_aggregate(
+                part, png = aggregate(
                     c, p.group_by, p.aggs, cap, mode=PARTIAL
                 )
                 checks[key] = png[None]
@@ -531,17 +551,17 @@ def compile_distributed(
                 checks[bkey] = mxb[None]
                 # final capacity = received capacity: group count there is
                 # bounded by received rows, so the final phase cannot overflow
-                out, _ng = hash_aggregate(
+                out, _ng = aggregate(
                     merged, final_group_by, final_agg_exprs(p.aggs),
                     n_shards * bcap, mode=FINAL,
                 )
                 return out, out_mode
             # two-phase: local partial -> all_gather -> final
             cap = caps.get(key, agg_default)
-            part, png = hash_aggregate(c, p.group_by, p.aggs, cap, mode=PARTIAL)
+            part, png = aggregate(c, p.group_by, p.aggs, cap, mode=PARTIAL)
             note(p, 0, p.child, "gather", (), REPLICATED, "partial", m, part)
             merged = all_gather(part)
-            out, ng = hash_aggregate(
+            out, ng = aggregate(
                 merged, final_group_by, final_agg_exprs(p.aggs), cap, mode=FINAL
             )
             # both partial and final counts must fit the capacity
@@ -820,5 +840,5 @@ def compile_distributed(
     return DistCompiled(
         step, scans, scan_mode_list, None, root_node.output_names(), n_shards,
         scopes=scope_table(scopes), compactions=compactions,
-        exchanges=exchanges,
+        exchanges=exchanges, segment_sums=segment_sums,
     )

@@ -518,7 +518,8 @@ def scope_table(scopes: dict) -> dict:
 
 class Compiled:
     def __init__(self, fn, scans, checks_meta, out_names, aux=(),
-                 node_ord=None, scopes=None, compactions=None):
+                 node_ord=None, scopes=None, compactions=None,
+                 segment_sums=None):
         self.fn = fn  # (inputs tuple) -> (chunk, checks tuple)
         self.scans = scans  # list[(table, alias, columns)]
         self.checks_meta = checks_meta  # list[(cap_key,)] parallel to checks
@@ -539,6 +540,11 @@ class Compiled:
         # source-row index was computed}. Filled while fn traces, like
         # node_ord; the attempt's `compactions` info.
         self.compactions = {} if compactions is None else compactions
+        # aggregate scope (`sr.agg.<n>`) -> its batch of integer segment sums
+        # (`ops/segment.seg_sums`): {"rows", "groups", "columns" handed in,
+        # "distinct" summed, "limbs" made, "formulation"}. Filled while fn
+        # traces; the attempt's `segment_sums` info.
+        self.segment_sums = {} if segment_sums is None else segment_sums
 
 
 def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
@@ -548,6 +554,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
     aux_index: dict = {}
     node_ord: dict = {}  # plan node (by value) -> deterministic ordinal
     compactions: dict = {}  # capacity key -> what `compact` did under it
+    segment_sums: dict = {}  # aggregate scope -> what `seg_sums` did under it
 
     def ordinal(p) -> int:
         return node_ord.setdefault(p, len(node_ord))
@@ -762,7 +769,11 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                     agg_aux: dict = {}
                     kwargs = {"arr_cap": caps.get(akey, 256),
                               "aux_checks": agg_aux}
-                out, ng = hash_aggregate(c, p.group_by, p.aggs, cap, **kwargs)
+                sums_info: dict = {}
+                out, ng = hash_aggregate(c, p.group_by, p.aggs, cap,
+                                         sums_info=sums_info, **kwargs)
+                if sums_info:
+                    segment_sums[scope_name(scopes, p)] = sums_info
                 checks[key] = ng
                 # dense floor metadata for the adaptive loop: a cap equal
                 # to a dense domain seed must never tighten below it (that
@@ -1145,7 +1156,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
 
     return Compiled(run, scans, None, plan.output_names(), tuple(aux),
                     node_ord=node_ord, scopes=scope_table(scopes),
-                    compactions=compactions)
+                    compactions=compactions, segment_sums=segment_sums)
 
 
 def _equi_pair(conj: Expr, lcols: frozenset, rcols: frozenset):

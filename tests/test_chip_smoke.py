@@ -42,3 +42,12 @@ def test_run_passes_on_cpu_at_small_scale():
     # Q3's program compacts, and the smoke says how; Q1's and Q6's do not
     assert by_name["mysql:q3"]["compactions"]["shrink_0mwb"]["method"]
     assert "compactions" not in by_name["mysql:q6"]
+    # every aggregate's integer sums are one batch, and the smoke says what
+    # it was: Q1's six groups, its five value columns and the one count each
+    # summed once; on CPU `auto` takes the scatter (a TPU: `masked`)
+    q1 = by_name["mysql:q1"]["segment_sums"]["sr.agg.2"]
+    assert (q1["groups"], q1["distinct"], q1["formulation"]) == (
+        6, 6, "scatter")
+    assert q1["columns"] > q1["distinct"]
+    assert by_name["mysql:q6"]["segment_sums"]["sr.agg.1"][
+        "formulation"] == "global"

@@ -24,6 +24,16 @@ QUERIES = [
 ]
 
 
+def _same_rows(got, want, rel):
+    assert len(got) == len(want)
+    for gr, wr in zip(got, want):
+        for gv, wv in zip(gr, wr):
+            if isinstance(wv, float):
+                assert gv == pytest.approx(wv, rel=rel, abs=1e-12)
+            else:
+                assert gv == wv
+
+
 @pytest.fixture(scope="module")
 def cat():
     return tpch_catalog(sf=0.01)
@@ -38,13 +48,7 @@ def test_lowcard_matches_sort_path(cat, qi):
         slow = Session(cat).sql(q).rows()
     finally:
         config.set("enable_lowcard_agg", True)
-    assert len(fast) == len(slow)
-    for fr, sr in zip(fast, slow):
-        for fv, sv in zip(fr, sr):
-            if isinstance(fv, float):
-                assert sv == pytest.approx(fv, rel=1e-12, abs=1e-12)
-            else:
-                assert fv == sv
+    _same_rows(slow, fast, rel=1e-12)
 
 
 def test_lowcard_with_nulls_and_two_phase():
@@ -58,15 +62,9 @@ def test_lowcard_with_nulls_and_two_phase():
         slow = Session(s.catalog).sql(q).rows()
     finally:
         config.set("enable_lowcard_agg", True)
-    assert len(fast) == len(slow)
-    for fr, sr in zip(fast, slow):
-        for fv, sv in zip(fr, sr):
-            if isinstance(fv, float):
-                # the two paths reduce in different row orders; float sums
-                # may differ in the last ulp (esp. on TPU)
-                assert sv == pytest.approx(fv, rel=1e-12, abs=1e-12)
-            else:
-                assert fv == sv
+    # the two paths reduce in different row orders; float sums may differ in
+    # the last ulp (esp. on TPU)
+    _same_rows(slow, fast, rel=1e-12)
     assert fast[-1][0] is None and fast[-1][1] == 2  # NULL group
 
 
@@ -82,6 +80,60 @@ def test_lowcard_distributed_two_phase(eight_devices, cat):
         assert single == dist
     finally:
         D.SHARD_THRESHOLD_ROWS = old
+
+
+# every integer sum of a node is one `seg_sums` batch (PR 27): Q1 whole, and
+# the moment aggregates, whose counts ride with their float sums
+BATCHED = {
+    "q1": """select l_returnflag, l_linestatus, sum(l_quantity) sum_qty,
+       sum(l_extendedprice) sum_base_price,
+       sum(l_extendedprice * (1 - l_discount)) sum_disc_price,
+       sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) sum_charge,
+       avg(l_quantity) avg_qty, avg(l_extendedprice) avg_price,
+       avg(l_discount) avg_disc, count(*) count_order
+       from lineitem where l_shipdate <= date '1998-09-02'
+       group by l_returnflag, l_linestatus order by 1, 2""",
+    "moments": """select l_returnflag, l_linestatus, var_pop(l_quantity) vp,
+       stddev_samp(l_extendedprice) sd, sum(l_quantity) sq,
+       covar_samp(l_quantity, l_extendedprice) cv,
+       corr(l_quantity, l_discount) cr, count(l_tax) ct, count(*) c
+       from lineitem group by l_returnflag, l_linestatus order by 1, 2""",
+}
+
+
+@pytest.mark.parametrize("name", sorted(BATCHED))
+def test_batched_sums_match_sort_path_and_mesh(eight_devices, cat, name):
+    """With the TPU's strategies pinned: the packed-gid path (6 groups, the
+    masked reductions) against the sort path (capacity 1,024, the
+    contraction) and against PARTIAL a shard + FINAL on eight virtual
+    devices; integer and DECIMAL results to the digit."""
+    import starrocks_tpu.sql.distributed as D
+
+    q = BATCHED[name]
+    old = D.SHARD_THRESHOLD_ROWS
+    D.SHARD_THRESHOLD_ROWS = 10_000
+    config.set("segment_strategy", "mxu")
+    try:
+        r = Session(cat).sql(q)
+        fast = r.rows()
+        took = [a.infos["segment_sums"] for a in r.profile.children
+                if "segment_sums" in a.infos][-1]
+        assert {t["formulation"] for t in took.values()} == {"masked"}
+        config.set("enable_lowcard_agg", False)
+        try:
+            r = Session(cat).sql(q)
+            slow = r.rows()
+            took = [a.infos["segment_sums"] for a in r.profile.children
+                    if "segment_sums" in a.infos][-1]
+            assert {t["formulation"] for t in took.values()} == {"contract"}
+        finally:
+            config.set("enable_lowcard_agg", True)
+        dist = Session(cat, dist_shards=8).sql(q).rows()
+    finally:
+        config.set("segment_strategy", "auto")
+        D.SHARD_THRESHOLD_ROWS = old
+    _same_rows(slow, fast, rel=1e-12)
+    _same_rows(dist, fast, rel=1e-9)
 
 
 def test_pallas_segment_sum_matches_oracle():

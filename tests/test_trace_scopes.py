@@ -86,6 +86,59 @@ def test_q3_compacts_by_index_and_gather_without_a_scatter(lowered):
     assert any(s[-1] == "gather" and "gather" in s[:-1] for s in stacks)
 
 
+@pytest.fixture(scope="module")
+def q1_on_the_tpus_strategy(tpch):
+    """Q1 as a TPU runs it (`auto` takes the scatter on CPU): (the lowered
+    text, the last attempt's infos)."""
+    config.set("segment_strategy", "mxu")
+    try:
+        s = Session(tpch)
+        result = s.sql(QUERIES[1])
+        attempt = [a for a in result.profile.children
+                   if "capacities" in a.infos][-1]
+        return lowered_text(s, result), attempt.infos
+    finally:
+        config.set("segment_strategy", "auto")
+
+
+def test_q1_sums_every_integer_column_in_one_batch(q1_on_the_tpus_strategy):
+    """Q1 has eight aggregates that ask for sixteen integer sums: four sums
+    with their nonempty counts, three averages with theirs, count(*) and the
+    group count. Under `sr.agg.2/limbs` the program holds ONE reduce over the
+    rows, whose six operands are the distinct columns (five values, one
+    count) masked by group; no limb column is built or concatenated, and
+    with six groups there is no contraction; the attempt says so. Before PR
+    27: five contractions of 8 stacked f32 limbs each over 1,024 groups, 520
+    of Q1's 548 ms at SF10 on a v5e."""
+    text, infos = q1_on_the_tpus_strategy
+    stacks = [p.split("/") for p in SCOPED.findall(text) if "/limbs/" in p]
+    assert stacks and all("sr.agg.2" in s for s in stacks)
+    ops = {s[-1] for s in stacks}
+    assert "reduce" in ops, sorted(ops)
+    assert not [o for o in ops if o.startswith(("dot_general", "concatenate"))]
+    over_rows = [line for line in text.splitlines()
+                 if "stablehlo.reduce" in line and "x60416x" in line]
+    assert len(over_rows) == 1 and over_rows[0].count("init:") == 6
+    assert infos["segment_sums"] == {"sr.agg.2": {
+        "rows": 60416, "groups": 6, "columns": 16, "distinct": 6,
+        "limbs": 0, "formulation": "masked"}}
+
+
+@pytest.mark.parametrize("q", [3, 6])
+def test_q3_and_q6_have_no_limbs_phase(ran, lowered, q):
+    """Q6 has one group (a global masked reduction), Q3 millions (lexsort
+    and prefix sums): neither runs the batched formulations, on any
+    backend; their attempts name what they took."""
+    assert not [p for p in SCOPED.findall(lowered[q]) if "/limbs/" in p]
+    _, result, _ = ran[q]
+    attempt = [a for a in result.profile.children
+               if "capacities" in a.infos][-1]
+    (scope, took), = attempt.infos["segment_sums"].items()
+    assert scope == {3: "sr.agg.2", 6: "sr.agg.1"}[q]
+    assert took["formulation"] == {3: "scatter", 6: "global"}[q]
+    assert took["limbs"] == 0
+
+
 def test_q3_profile_names_each_compaction(ran):
     """Beside an attempt's `capacities`: what each compaction of its program
     shrank (rows in, slots out) and how the index was computed; on a
