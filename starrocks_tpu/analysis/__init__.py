@@ -31,7 +31,7 @@ import logging
 
 logger = logging.getLogger("starrocks_tpu.analysis")
 
-# process-wide finding counter (bench.py reports it in the JSON summary)
+# process-wide finding counter
 _totals = {"findings": 0}
 
 

@@ -27,8 +27,8 @@ against real interleavings):
    ``# lint: holds _lock`` (a documented called-with-lock-held helper),
    or from `__init__` (construction precedes sharing). Violations are
    strict-fatal. Unannotated mutable fields on lock-owning classes are
-   WARN findings — the coverage ratchet `bench.py` tracks as
-   `concur_findings`; ``# lint: unguarded-ok`` (same or preceding line)
+   WARN findings — the coverage ratchet
+   (`tests/test_concur_check.py` bounds their count); ``# lint: unguarded-ok`` (same or preceding line)
    documents a reviewed deliberately-unguarded field.
 
 Scope and honesty: resolution is name-based and intra-package — calls

@@ -33,7 +33,6 @@ HOST_LOOP_KNOBS = {
         "retrace; values never reach the trace",
     "compaction_trigger_rowsets": "storage write path, never traced",
     "profile_queries": "host-side profile collection toggle",
-    "bench_sf": "bench harness input sizing",
     "chunk_align": "immutable; baked into every capacity everywhere",
     "query_queue_timeout_s": "admission control, pre-planning",
     "default_agg_groups": "capacity default; caps dict keys the programs",

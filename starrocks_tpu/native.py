@@ -50,20 +50,6 @@ def _load():
         ]
         lib.sr_csv_count_rows.restype = ctypes.c_int64
         lib.sr_csv_parse.restype = ctypes.c_int64
-        try:
-            lib.sr_fused_filter_sum_i64_mt.argtypes = [
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_int64)),
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
-            ]
-        except AttributeError:
-            # stale .so from before the fused kernel: the wrapper below
-            # reports unavailable and callers keep the regular path
-            pass
         _lib = lib
         return _lib
 
@@ -91,40 +77,6 @@ def hash_partition_i64(keys: np.ndarray, nbuckets: int) -> np.ndarray:
         nthreads,
     )
     return out
-
-
-# compare-op tags shared with the C side (sr_fused_filter_sum_i64_mt)
-FS_OPS = {"eq": 0, "ne": 1, "lt": 2, "le": 3, "gt": 4, "ge": 5}
-
-
-def fused_filter_sum_i64(pred_cols, pred_ops, pred_vals, a, b=None):
-    """One-pass conjunctive filter + sum(a*b) (sum(a) when b is None) over
-    int64 columns. Returns (total, match_count), or None when the native
-    lib (or the kernel symbol, on a stale build) is unavailable — the
-    caller keeps the regular segmented path."""
-    lib = _load()
-    if lib is None or not hasattr(lib, "sr_fused_filter_sum_i64_mt"):
-        return None
-    cols = [np.ascontiguousarray(c, dtype=np.int64) for c in pred_cols]
-    a = np.ascontiguousarray(a, dtype=np.int64)
-    bp = None
-    if b is not None:
-        b = np.ascontiguousarray(b, dtype=np.int64)
-        bp = b.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
-    k = len(cols)
-    col_arr = (ctypes.POINTER(ctypes.c_int64) * k)(
-        *[c.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)) for c in cols])
-    op_arr = (ctypes.c_int32 * k)(*[int(o) for o in pred_ops])
-    val_arr = (ctypes.c_int64 * k)(*[int(v) for v in pred_vals])
-    out_sum = ctypes.c_int64(0)
-    out_cnt = ctypes.c_int64(0)
-    lib.sr_fused_filter_sum_i64_mt(
-        col_arr, op_arr, val_arr, k,
-        a.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), bp, len(a),
-        ctypes.byref(out_sum), ctypes.byref(out_cnt),
-        min(os.cpu_count() or 1, 8),
-    )
-    return int(out_sum.value), int(out_cnt.value)
 
 
 # column type tags shared with the C side

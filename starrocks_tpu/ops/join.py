@@ -29,7 +29,6 @@ from ..column.column import Chunk, Field, Schema
 from ..exprs.compile import ExprCompiler
 from ..exprs.ir import Col
 from .common import eval_keys, mix64, phase
-from .segment import on_tpu
 
 INNER = "inner"
 LEFT_OUTER = "left_outer"
@@ -330,47 +329,6 @@ def _merge_schemas(left: Chunk, right: Chunk, right_names) -> tuple:
     return tuple(out_fields)
 
 
-def _probe_block(n: int) -> int:
-    return 2048 if n % 2048 == 0 else (1024 if n % 1024 == 0 else n)
-
-
-def _probe_searchsorted(bk_sorted, pk):
-    """The unique-join probe ladder, flag-routable onto the explicit
-    Pallas kernel (`SET join_probe_strategy = 'pallas_sorted'`;
-    ops/pallas_kernels.probe_searchsorted_pallas — interpret mode off-TPU;
-    on a TPU Mosaic does not lower its int64 refs yet, so the strategy
-    fails loudly there). Default: jnp.searchsorted (XLA's own ladder)."""
-    from ..runtime.config import config as _cfg
-
-    if _cfg.get("join_probe_strategy") == "pallas_sorted":
-        from .pallas_kernels import probe_searchsorted_pallas
-
-        return probe_searchsorted_pallas(
-            bk_sorted, pk, block=_probe_block(int(pk.shape[0])),
-            interpret=not on_tpu())
-    return jnp.searchsorted(bk_sorted, pk)
-
-
-def hash_probe_rows(bk, pk, bcap: int, p_ok):
-    """Open-addressing hash-table build+probe (`SET join_probe_strategy =
-    'pallas'`): replaces the build argsort + searchsorted ladder with the
-    explicit Pallas kernel pair (ops/pallas_kernels.hash_build_pallas /
-    hash_probe_pallas — interpret mode off-TPU). NULL/dead rows on both
-    sides carry the int64-max sentinel, which doubles as the table's
-    empty-slot marker, so they never insert and never match.
-    Returns (match [P] bool, build_row [P] int32 clipped)."""
-    from .pallas_kernels import hash_build_pallas, hash_probe_pallas
-
-    table_size = 1 << (max(2 * bcap, 16) - 1).bit_length()
-    interpret = not on_tpu()
-    tkey, trow = hash_build_pallas(bk, table_size, interpret=interpret)
-    row = hash_probe_pallas(
-        tkey, trow, pk, block=_probe_block(int(pk.shape[0])),
-        interpret=interpret)
-    match = (row >= 0) & p_ok & (pk != _I64MAX)
-    return match, jnp.clip(row, 0, bcap - 1)
-
-
 def hash_join_unique(
     probe: Chunk,
     build: Chunk,
@@ -391,23 +349,13 @@ def hash_join_unique(
     )  # build NULL/dead rows pack to the sentinel
     bcap = build.capacity
 
-    from ..runtime.config import config as _cfg
-
-    if _cfg.get("join_probe_strategy") == "pallas":
-        # sort-free path: open-addressing hash table in Pallas (the cached
-        # build_order, an argsort artifact, is simply unused here)
-        with phase("probe"):
-            match, build_row = hash_probe_rows(bk, pk, bcap, p_ok)
-        return _unique_join_epilogue(
-            probe, build, payload, match, build_row, join_type)
-
     with phase("build"):
         order = (build_order if build_order is not None
                  else jnp.argsort(bk, stable=True))  # sentinels go last
         bk_sorted = bk[order]
 
     with phase("probe"):
-        pos = _probe_searchsorted(bk_sorted, pk)
+        pos = jnp.searchsorted(bk_sorted, pk)
         pos_c = jnp.clip(pos, 0, bcap - 1)
         match = (bk_sorted[pos_c] == pk) & p_ok & (pk != _I64MAX)
         build_row = order[pos_c]

@@ -7,7 +7,9 @@ provides segment sum/min/max/count that never emit a scatter on the hot
 paths; reference analog: the SIMD agg hash maps
 (be/src/exec/aggregate/agg_hash_map.h) re-designed for the TPU.
 
-Strategies, picked per dtype / group count / sortedness:
+Strategies, picked per dtype / group count / sortedness and by nothing else:
+the same ladder is traced on every backend, so tests on CPU devices run what
+the chip runs:
 
 1. **Integer sums, one pass for all of a node's columns** (`seg_sums`),
    EXACT mod 2^64 — the overflow contract of a native int64 accumulator.
@@ -24,8 +26,8 @@ Strategies, picked per dtype / group count / sortedness:
    window partitions): sums become cumsum diffs at group boundaries found by
    searchsorted; min/max become a segmented associative scan read at the
    segment ends. All gathers, no scatters.
-4. Fallback: jax.ops.segment_* (scatter) for shapes none of the above
-   covers (e.g. huge unsorted group counts with float min/max).
+4. Last rung: jax.ops.segment_* (scatter) for shapes none of the above
+   covers (more than `matmul_segsum_groups_max` unsorted groups).
 """
 
 from __future__ import annotations
@@ -217,64 +219,10 @@ def _seg_minmax_bcast(vals, gid, num_groups: int, is_min: bool, identity):
     return (jnp.min if is_min else jnp.max)(masked, axis=0)
 
 
-def on_tpu() -> bool:
-    """The one backend rule of the engine: Pallas kernels compile through
-    Mosaic, the scatter-free segment strategies are `auto`'s choice and the
-    planner's dense-aggregation domain is tight exactly when the default
-    backend is a TPU. Any other backend (the CPU of the test suite)
-    interprets the kernels and takes the scatter-friendly choices."""
-    return jax.default_backend() == "tpu"
-
-
-def _seg_sum_pallas(vals, gid, num_groups: int):
-    """Float segment sums through the explicit Pallas kernel
-    (ops/pallas_kernels.py): one-hot tiles in VMEM, partial sums on the MXU.
-    Flag-gated via segment_strategy=pallas; interpret mode off-TPU keeps the
-    path correctness-testable without hardware. f32 accumulation — callers
-    gate exact (int/decimal) sums away from it. A row count that does not
-    block-divide raises: an explicitly chosen strategy never quietly gives
-    way to another."""
-    n = vals.shape[0]
-    block = min(n & -n, 2048)
-    if block < 8:
-        raise ValueError(
-            f"segment_strategy=pallas needs a row count divisible by 8, "
-            f"got {n}")
-    from .pallas_kernels import segment_sum_pallas
-
-    g = jnp.clip(jnp.asarray(gid, jnp.int32), 0, num_groups)
-    out = segment_sum_pallas(
-        g, jnp.asarray(vals, jnp.float32)[:, None], num_groups, block=block,
-        interpret=not on_tpu(),
-    )
-    return jnp.asarray(out[:, 0], vals.dtype)
-
-
-def _use_mxu() -> bool:
-    """True when the scatter-free (matmul / broadcast / scan) strategies
-    should be used.  They exist because TPU scatters serialize on duplicate
-    indices; on the CPU backend a plain scatter is 100-1000x FASTER than the
-    one-hot matmul (CPU run: 1.2M rows x 1024 groups = 1.1ms scatter vs >1s
-    matmul), so `auto` picks by backend (`on_tpu`). `segment_strategy`
-    config: auto | mxu | scatter (tests pin `mxu` to keep the strategy
-    branches covered on CPU)."""
-    from ..runtime.config import config
-
-    if not config.get("enable_scatter_free_segments"):
-        return False
-    s = config.get("segment_strategy")
-    if s == "auto":
-        return on_tpu()
-    # "pallas" only reroutes float sums; every other reduction must keep
-    # its scatter-free strategy (degrading them to scatters would make the
-    # pallas A/B benchmark measure scatter serialization instead)
-    return s in ("mxu", "pallas")
-
-
 def _global_sum(vals, gid):
-    """num_groups == 1: one fused masked reduction, no scatter / one-hot on
-    ANY backend (the gid==0 compare folds away when gid is the constant
-    zeros of the no-group-key path)."""
+    """num_groups == 1: one fused masked reduction, no scatter / one-hot
+    (the gid==0 compare folds away when gid is the constant zeros of the
+    no-group-key path)."""
     m = jnp.asarray(gid, jnp.int32) == 0
     return jnp.sum(jnp.where(m, vals, jnp.zeros((), vals.dtype)),
                    keepdims=True)
@@ -292,34 +240,27 @@ def _seg_sums_int(cols, gid, num_groups: int, sorted_gid: bool):
     section 6, PR 27)."""
     if num_groups == 1:
         return [_global_sum(v, gid) for v, _ in cols], "global"
-    if _use_mxu():
-        if num_groups <= _bcast_groups_max():
-            with phase("limbs"):
-                return _seg_sums_masked(cols, gid, num_groups), "masked"
-        if num_groups <= _matmul_groups_max():
-            with phase("limbs"):
-                return _seg_sums_contract(cols, gid, num_groups), "contract"
-        if sorted_gid:
-            return [_seg_sum_sorted(v, gid, num_groups)
-                    for v, _ in cols], "sorted"
+    if num_groups <= _bcast_groups_max():
+        with phase("limbs"):
+            return _seg_sums_masked(cols, gid, num_groups), "masked"
+    if num_groups <= _matmul_groups_max():
+        with phase("limbs"):
+            return _seg_sums_contract(cols, gid, num_groups), "contract"
+    if sorted_gid:
+        return [_seg_sum_sorted(v, gid, num_groups)
+                for v, _ in cols], "sorted"
     return [jax.ops.segment_sum(v, gid, num_segments=num_groups,
                                 indices_are_sorted=sorted_gid)
             for v, _ in cols], "scatter"
 
 
 def _seg_sum_float(vals, gid, num_groups: int, sorted_gid: bool):
-    from ..runtime.config import config as _cfg
-
     if num_groups == 1:
         return _global_sum(vals, gid)
-    if (_cfg.get("segment_strategy") == "pallas"
-            and num_groups <= _matmul_groups_max()):
-        return _seg_sum_pallas(vals, gid, num_groups)
-    if _use_mxu():
-        if num_groups <= _bcast_groups_max():
-            return _seg_sum_float_bcast(vals, gid, num_groups)
-        if sorted_gid:
-            return _seg_sum_sorted_float(vals, gid, num_groups)
+    if num_groups <= _bcast_groups_max():
+        return _seg_sum_float_bcast(vals, gid, num_groups)
+    if sorted_gid:
+        return _seg_sum_sorted_float(vals, gid, num_groups)
     return jax.ops.segment_sum(vals, gid, num_segments=num_groups,
                                indices_are_sorted=sorted_gid)
 
@@ -389,11 +330,10 @@ def _seg_minmax(vals, gid, num_groups: int, is_min: bool, identity,
         m = jnp.asarray(gid, jnp.int32) == 0
         masked = jnp.where(m, vals, jnp.asarray(identity, vals.dtype))
         return (jnp.min if is_min else jnp.max)(masked, keepdims=True)
-    if _use_mxu():
-        if num_groups <= _bcast_groups_max():
-            return _seg_minmax_bcast(vals, gid, num_groups, is_min, identity)
-        if sorted_gid:
-            return _seg_minmax_sorted(vals, gid, num_groups, is_min, identity)
+    if num_groups <= _bcast_groups_max():
+        return _seg_minmax_bcast(vals, gid, num_groups, is_min, identity)
+    if sorted_gid:
+        return _seg_minmax_sorted(vals, gid, num_groups, is_min, identity)
     seg = jax.ops.segment_min if is_min else jax.ops.segment_max
     return seg(vals, gid, num_segments=num_groups, indices_are_sorted=sorted_gid)
 

@@ -11,10 +11,9 @@ already a parallel bitonic-class sort; this module narrows what feeds it:
   via a sentinel bit per nullable key, dead rows -> INT64_MAX), so the
   multi-operand lexsort comparator collapses to a single int64 compare;
 - threshold TopN: ORDER BY .. LIMIT k over a packed key runs a partial
-  select (lax.top_k, or the per-block Pallas selection kernel behind
-  `SET topn_strategy='pallas'`) — rows past the running k-th key never
-  reach a gather, and the output capacity SHRINKS to ~k (the reference's
-  heap-TopN runtime filter re-designed branch-free);
+  select (lax.top_k) — rows past the k-th key never reach a gather, and
+  the output capacity SHRINKS to ~k (the reference's heap-TopN runtime
+  filter re-designed branch-free);
 - the distributed merge phase lives in parallel/ (gather + re-sort, or
   all_gather of per-shard TopN).
 """
@@ -26,7 +25,6 @@ import jax.numpy as jnp
 
 from ..column.column import Chunk, pad_capacity
 from .common import eval_keys, phase
-from .segment import on_tpu
 
 _I64MAX = jnp.iinfo(jnp.int64).max
 
@@ -112,29 +110,6 @@ def packed_order_key(keys, sort_keys, live):
     return jnp.where(live, packed, _I64MAX)
 
 
-# --- TopN partial select -----------------------------------------------------
-
-
-def topn_order(packed, kk: int):
-    """Indices of the kk smallest packed keys, ascending, stable on ties
-    (lax.top_k breaks ties by lower index — the same order a stable
-    ascending argsort yields). `~packed` reverses int64 order exactly
-    (monotone bijection; negation would overflow on INT64_MIN)."""
-    from ..runtime.config import config as _cfg
-
-    neg = ~packed
-    if _cfg.get("topn_strategy") == "pallas" and packed.shape[0] % 1024 == 0 \
-            and kk <= 1024:
-        from .pallas_kernels import topn_select_pallas
-
-        cv, ci = topn_select_pallas(
-            neg, kk, interpret=not on_tpu())
-        _, pos = jax.lax.top_k(cv, kk)
-        return ci[pos]
-    _, idx = jax.lax.top_k(neg, kk)
-    return idx
-
-
 def sort_chunk(chunk: Chunk, sort_keys, limit: int | None = None,
                counters: dict | None = None) -> Chunk:
     """sort_keys: tuple of (expr, asc: bool, nulls_first: bool).
@@ -158,8 +133,12 @@ def sort_chunk(chunk: Chunk, sort_keys, limit: int | None = None,
         if (limit is not None and 0 < limit <= TOPN_MAX_K
                 and pad_capacity(limit) < cap):
             kk = pad_capacity(limit)
+            # the kk smallest packed keys, ascending, stable on ties
+            # (top_k breaks ties by lower index, as a stable ascending
+            # argsort does); `~packed` reverses int64 order exactly, where
+            # negation would overflow on INT64_MIN
             with phase("sort"):
-                order = topn_order(packed, kk)
+                _, order = jax.lax.top_k(~packed, kk)
             out = chunk.take(order)
             k = jnp.minimum(n, limit)
             if counters is not None:

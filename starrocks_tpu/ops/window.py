@@ -64,8 +64,8 @@ def window_topn_prefilter(chunk: Chunk, partition_by, order_by, k: int,
     the null peer group ranks 1, occupying top threshold slots) or the
     floor (NULLS LAST: kept only while the partition has fewer than k
     scored rows). Returns (keep_mask, seed_rows) — seed_rows is a
-    capacity seed for compacting the kept set (k * threshold-resolution
-    per partition, with slack) — or None.
+    capacity seed for compacting the kept set (k per partition, with
+    slack) — or None.
     """
     if k < 1 or len(order_by) != 1:
         return None
@@ -115,44 +115,17 @@ def window_topn_prefilter(chunk: Chunk, partition_by, order_by, k: int,
         return None
     kk = min(k, cap)
     gidc = jnp.clip(gid, 0, D - 1)
-    from .segment import _use_mxu
-
-    if _use_mxu():
-        # TPU: the [D, cap] masked-compare matrix is the usual one-hot
-        # trick and lax.top_k is hardware-lowered
-        mat = jnp.where(
-            jnp.arange(D, dtype=gid.dtype)[:, None] == gid[None, :],
-            score[None, :], floor,
-        )
-        kth = jax.lax.top_k(mat, kk)[0][:, -1]  # [D] per-partition k-th
-        stride = 1  # exact threshold
-    else:
-        # CPU: XLA lowers that matrix TopK to a per-row sort (measured
-        # 1.6s at 900k rows — worse than the lexsort it replaces). Run a
-        # k-round selection ladder (scatter-max + first-occurrence
-        # removal) over a STRIDED SUBSET instead: a subset's k-th largest
-        # is always <= the population's, so the threshold stays
-        # conservative (over-kept rows fall to the exact in-window rank
-        # mask) while the ladder touches ~128k rows, not all of them
-        stride = max(1, cap // (1 << 17))
-        sub = score[::stride]
-        gsub = gidc[::stride]
-        n_sub = sub.shape[0]
-        rowidx = jnp.arange(n_sub)
-        cur = sub
-        kth = jnp.full((D,), floor, score.dtype)
-        floor_v = jnp.asarray(floor, score.dtype)
-        for _ in range(kk):
-            kth = jnp.full((D,), floor, score.dtype).at[gsub].max(
-                cur, mode="drop")
-            is_max = cur == kth[gsub]
-            first = jnp.full((D,), n_sub).at[gsub].min(
-                jnp.where(is_max, rowidx, n_sub), mode="drop")
-            cur = jnp.where(first[gsub] == rowidx, floor_v, cur)
+    # the [D, cap] masked-compare matrix is the usual one-hot trick; the
+    # threshold is exact (ties at the k-th key stay: `>=`)
+    mat = jnp.where(
+        jnp.arange(D, dtype=gid.dtype)[:, None] == gid[None, :],
+        score[None, :], floor,
+    )
+    kth = jax.lax.top_k(mat, kk)[0][:, -1]  # [D] per-partition k-th
     keep = live & (score >= kth[gidc])
-    # a stride-s threshold keeps ~s rows per true top-k slot in
-    # expectation; the overflow check covers adversarial layouts
-    return keep, (kk * stride + 8) * (D + 1)
+    # ties at the threshold can keep more than k rows a partition; the
+    # overflow check covers them
+    return keep, (kk + 8) * (D + 1)
 
 
 def _seg_cummax_from_flags(vals, is_new):

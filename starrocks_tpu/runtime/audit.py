@@ -62,8 +62,8 @@ _HIT_COUNTERS = (
 )
 
 # ring entries are flat tuples in this order (a per-record dict build and
-# a list-ring's O(n) head trim both showed up in the serve_bench --obs
-# point-lane budget); snapshot() materializes dicts for every consumer
+# a list-ring's O(n) head trim both showed up in the point lane's
+# per-statement cost); snapshot() materializes dicts for every consumer
 _FIELDS = ("seq", "query_id", "ts", "user", "stmt", "stmt_class",
            "tables", "state", "stage", "ms", "queue_wait_ms", "rows",
            "mem_peak_bytes", "degraded", "error") + tuple(
@@ -91,8 +91,8 @@ class AuditLog:
         self._dropped = 0       # guarded_by: _lock
         # knob cache, pushed via config.on_set (registered below): the
         # record path runs once per statement, and four config.get hops
-        # per record measurably taxed the point lane (~2-3us of the <5%
-        # serve_bench --obs budget). Plain attrs; a torn read during a
+        # per record measurably taxed the point lane (~2-3us a
+        # statement). Plain attrs; a torn read during a
         # concurrent SET only mis-sizes one append. lint: unguarded-ok x4
         self._enabled = True            # lint: unguarded-ok
         self._cap = 1024                # lint: unguarded-ok

@@ -209,31 +209,12 @@ config.define("enable_mv_rewrite", True, True,
 config.define("enable_lowcard_agg", True, True,
               "sort-free packed-code aggregation for dictionary-bounded group keys",
               trace=True)
-config.define("enable_scatter_free_segments", True, True,
-              "lower segment reductions to one-hot matmuls / sorted prefix "
-              "tricks instead of XLA scatters (TPU scatter serializes on "
-              "duplicate indices)",
-              trace=True)
 config.define("enable_cached_build_sort", True, True,
               "pass cached per-(table, key) build-side sort permutations "
               "into compiled joins (skips the per-query build argsort)",
               trace=True)
 config.define("rand_seed", 42, True,
               "seed for rand()/random() (deterministic per trace)",
-              trace=True)
-config.define("dense_agg_domain_max", 0, True,
-              "max bounded group-key domain covered by a dense packed-gid "
-              "aggregation capacity (0 = auto by backend)",
-              trace=True)
-config.define("segment_strategy", "auto", True,
-              "auto | mxu | scatter | pallas | native: auto picks the "
-              "MXU-friendly scatter-free strategies on TPU and plain "
-              "scatters on CPU (where they are orders of magnitude faster); "
-              "mxu/scatter force one side; pallas routes float segment sums "
-              "through the explicit Pallas kernel (interpret-mode on CPU) — "
-              "flip this on hardware to benchmark it; native additionally "
-              "serves ungrouped filter+sum scans through the fused C++ "
-              "kernel on the CPU fallback",
               trace=True)
 config.define("matmul_segsum_groups_max", 1024, True,
               "max group count for the one-hot-matmul segment-sum strategy",
@@ -248,7 +229,6 @@ config.define("batch_rows_threshold", 0, True,
 config.define("spill_batch_rows", 0, True,
               "rows per streamed batch for the spill path (0 = use the "
               "activation threshold as the batch size)")
-config.define("bench_sf", 1.0, True, "scale factor used by bench.py")
 config.define("profile_queries", True, True, "collect RuntimeProfile for every query")
 config.define("enable_packed_sort_keys", True, True,
               "pack bounded ORDER BY / window sort keys (dict codes, "
@@ -258,28 +238,16 @@ config.define("enable_packed_sort_keys", True, True,
               "sentinel bit per nullable key)",
               trace=True)
 config.define("topn_strategy", "auto", True,
-              "auto | lexsort | pallas: ORDER BY .. LIMIT k strategy for "
-              "packable keys. auto = threshold top-N (lax.top_k partial "
-              "select, prunes rows past the k-th key before any gather); "
-              "pallas routes the partial select through the explicit "
-              "per-block Pallas selection kernel (interpret mode off-TPU); "
-              "lexsort forces the full multi-operand sort",
+              "auto | lexsort: ORDER BY .. LIMIT k strategy for packable "
+              "keys. auto = threshold top-N (lax.top_k partial select, "
+              "prunes rows past the k-th key before any gather); lexsort "
+              "forces the full multi-operand sort",
               trace=True)
 config.define("enable_window_topn", True, True,
               "rewrite rank()/row_number()/dense_rank() <= k filters over "
               "a window into per-partition segmented top-N pruning (the "
               "TopN runtime-filter analog: downstream sorts run over "
               "~k*partitions rows instead of the full window input)")
-config.define("join_probe_strategy", "auto", True,
-              "auto | pallas | pallas_sorted: unique-join probe strategy. "
-              "pallas = open-addressing hash-table build+probe Pallas "
-              "kernels (ops/pallas_kernels.hash_build_pallas/"
-              "hash_probe_pallas — replaces sort+searchsorted entirely); "
-              "pallas_sorted = keep the sorted build but run the "
-              "searchsorted ladder as an explicit Pallas kernel; auto = "
-              "XLA jnp.searchsorted. Interpret mode off-TPU for both "
-              "kernel paths",
-              trace=True)
 config.define("join_multiway_strategy", "auto", True,
               "auto | off: fuse a left-deep chain of 2+ unique-build "
               "single-key LUT-eligible INNER joins (3+ tables — the "

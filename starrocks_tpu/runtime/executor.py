@@ -536,119 +536,10 @@ class Executor:
     def execute_logical(
         self, plan: LogicalPlan, profile: RuntimeProfile | None = None
     ) -> QueryResult:
-        if config.get("segment_strategy") == "native":
-            res = self._try_native_scan_agg(plan, profile)
-            if res is not None:
-                return res
         gc = _extract_group_concat(plan)
         if gc is not None:
             return self._execute_group_concat(plan, gc, profile)
         return self._execute_plain(plan, profile)
-
-    def _try_native_scan_agg(
-        self, plan: LogicalPlan, profile: RuntimeProfile | None
-    ):
-        """`SET segment_strategy='native'`: the SSB q1.x scan-agg shape —
-        Project?(Agg(Filter(Scan))) with one ungrouped non-distinct
-        sum(a*b | a) under a conjunctive integer-compare predicate — runs
-        as ONE pass of the fused C++ kernel (native/sr_native.cpp
-        sr_fused_filter_sum_i64_mt): no per-operator materialization, no
-        device program. Any mismatch (shape, non-integer types, NULLs,
-        missing lib) returns None and the regular path runs unchanged."""
-        from .. import native
-
-        node = plan
-        renames = None
-        if isinstance(node, LProject):
-            renames = node.exprs
-            node = node.child
-        if (not isinstance(node, LAggregate) or node.group_by
-                or len(node.aggs) != 1
-                or not isinstance(node.child, LFilter)
-                or not isinstance(node.child.child, LScan)):
-            return None
-        agg_name, agg = node.aggs[0]
-        out_name = agg_name
-        if renames is not None:
-            if len(renames) != 1:
-                return None
-            out_name, e = renames[0]
-            if not (isinstance(e, Col) and e.name == agg_name):
-                return None
-        if (not isinstance(agg, AggExpr) or agg.fn != "sum"
-                or agg.distinct or agg.extra or agg.arg is None):
-            return None
-        scan = node.child.child
-        handle = self.catalog.tables.get(scan.table)
-        if handle is None:
-            return None
-        prefix = scan.alias + "."
-
-        def base_col(e):
-            if isinstance(e, Col) and e.name.startswith(prefix):
-                return e.name[len(prefix):]
-            return None
-
-        if isinstance(agg.arg, Call) and agg.arg.fn == "multiply" \
-                and len(agg.arg.args) == 2:
-            a_col = base_col(agg.arg.args[0])
-            b_col = base_col(agg.arg.args[1])
-            if a_col is None or b_col is None:
-                return None
-        else:
-            a_col, b_col = base_col(agg.arg), None
-            if a_col is None:
-                return None
-        terms: list = []
-
-        def flat(e) -> bool:
-            if isinstance(e, Call) and e.fn == "and":
-                return all(flat(x) for x in e.args)
-            if (isinstance(e, Call) and e.fn in native.FS_OPS
-                    and len(e.args) == 2):
-                c = base_col(e.args[0])
-                lit = e.args[1]
-                if (c is not None and isinstance(lit, Lit)
-                        and isinstance(lit.value, int)
-                        and not isinstance(lit.value, bool)):
-                    terms.append((c, e.fn, lit.value))
-                    return True
-            return False
-
-        if not flat(node.child.predicate) or not terms:
-            return None
-        ht = handle.table
-        need = {c for c, _, _ in terms} | {a_col} | (
-            {b_col} if b_col else set())
-        for c in need:
-            try:
-                f = ht.schema.field(c)
-            except KeyError:
-                return None
-            if not f.type.is_integer:
-                return None
-            v = ht.valids.get(c)
-            if v is not None and not v.all():
-                return None  # NULL compare/sum semantics: regular path
-        r = native.fused_filter_sum_i64(
-            [ht.arrays[c] for c, _, _ in terms],
-            [native.FS_OPS[op] for _, op, _ in terms],
-            [v for _, _, v in terms],
-            ht.arrays[a_col],
-            ht.arrays[b_col] if b_col else None,
-        )
-        if r is None:
-            return None
-        total, cnt = r
-        out = HostTable.from_pydict(
-            {out_name: [total if cnt else None]}, types={out_name: T.BIGINT})
-        profile = profile or RuntimeProfile("query")
-        profile.add_counter("native_fused_rows", int(ht.num_rows))
-        profile.set_info("native_fused", "filter_sum")
-        lifecycle.account(out, "native::fused_agg")
-        QUERIES_TOTAL.inc()
-        ROWS_RETURNED.inc(out.num_rows)
-        return QueryResult(out, plan, profile)
 
     def _execute_plain(
         self, plan: LogicalPlan, profile: RuntimeProfile | None = None

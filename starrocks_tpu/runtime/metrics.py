@@ -225,7 +225,7 @@ class MetricsHistory:
     holds counter DELTAS since the previous sample, gauge values, and
     histogram p50/p95/p99 estimates — the "what did the metrics look
     like five minutes ago" surface (`information_schema.metrics_history`,
-    `GET /api/metrics/history`, serve_bench trajectory reporting).
+    `GET /api/metrics/history`).
 
     A daemon sampler thread fills the ring every
     `metrics_history_interval_s`; `ensure_started()` is idempotent and

@@ -401,8 +401,9 @@ class WorkgroupManager:
             ]
 
     def queue_stats(self) -> dict:
-        """Aggregate lane stats (serve_bench + stress tests): admitted /
-        timed-out counts, cumulative queue wait, live running/queued."""
+        """Aggregate lane stats (the serving stress tests read them):
+        admitted / timed-out counts, cumulative queue wait, live
+        running/queued."""
         with self._lock:
             return {
                 "admitted": self.admitted_total,

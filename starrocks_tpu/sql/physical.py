@@ -43,19 +43,10 @@ class PlanError(ValueError):
     pass
 
 
-def _dense_agg_domain_max(cfg) -> int:
-    """Largest group-key domain the planner will cover with a dense packed-gid
-    capacity. 0 (default) = auto: generous on CPU (scatters are cheap), tight
-    on TPU (wide segment reduces cost HBM bandwidth; the lexsort path wins)."""
-    from ..ops.segment import on_tpu
-
-    v = cfg.get("dense_agg_domain_max")
-    if v:
-        return v
-    # CPU: must cover the TPC-H-scale dense PK domains (l_orderkey at SF1 is
-    # 6M) — a 6M-slot scatter-add is ~10ms there while the lexsort
-    # alternative is seconds (argsort is single-threaded in XLA CPU)
-    return 4096 if on_tpu() else (1 << 24)
+# Largest group-key domain the planner covers with a dense packed-gid
+# capacity: a wider segment reduce costs HBM bandwidth and the lexsort path
+# wins (TPC-H Q3's GROUP BY l_orderkey is a lexsort aggregate).
+_DENSE_AGG_DOMAIN_MAX = 4096
 
 
 # --- plan properties ---------------------------------------------------------
@@ -731,7 +722,6 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                     # to its true count for subsequent runs
                     default = max(default, c0.capacity)
                 from ..ops.aggregate import bounded_domain
-                from ..runtime.config import config as _acfg
 
                 dom = bounded_domain(c0, p.group_by)
                 if dom is not None and p.group_by:
@@ -746,7 +736,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                     est = estimate_rows(p.child, catalog)
                     if dom > 32 * max(est, 1024.0):
                         dom = None
-                if dom is not None and dom <= _dense_agg_domain_max(_acfg):
+                if dom is not None and dom <= _DENSE_AGG_DOMAIN_MAX:
                     # dense bounded domain: capacity covers it outright, the
                     # sort-free packed-gid path applies at any cardinality
                     default = max(default, dom)
@@ -1105,22 +1095,13 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
                     matched.sel_mask(), jnp.asarray(mdata, jnp.int64),
                     lc.capacity,
                 )
-                from ..ops.segment import _use_mxu
-
-                if _use_mxu():
-                    # scatter-free membership: midx holds DUPLICATE rowids
-                    # (many matches per probe row), the scatter shape TPU
-                    # serializes on — sort once, membership by searchsorted
-                    srt = jnp.sort(midx)
-                    rowid_q = jnp.arange(lc.capacity, dtype=jnp.int64)
-                    pos = jnp.clip(jnp.searchsorted(srt, rowid_q), 0,
-                                   srt.shape[0] - 1)
-                    present = srt[pos] == rowid_q
-                else:
-                    # CPU: the duplicate-index bitmap scatter is cheapest
-                    present = jnp.zeros((lc.capacity,), jnp.bool_).at[
-                        midx
-                    ].max(jnp.ones_like(midx, jnp.bool_), mode="drop")
+                # scatter-free membership: midx holds DUPLICATE rowids
+                # (many matches per probe row), the scatter shape a TPU
+                # serializes on — sort once, membership by searchsorted
+                srt = jnp.sort(midx)
+                pos = jnp.clip(jnp.searchsorted(srt, rowid), 0,
+                               srt.shape[0] - 1)
+                present = srt[pos] == rowid
                 return lc.and_sel(present if p.kind == "semi" else ~present)
 
             if unique and p.kind in ("inner", "left", "semi", "anti"):

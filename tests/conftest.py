@@ -3,9 +3,12 @@
 Mirrors the reference's PseudoCluster strategy (fe test
 pseudocluster/PseudoCluster.java:1 — multi-"node" cluster in one JVM): we fake
 a multi-chip TPU slice with 8 host CPU devices so sharding/exchange logic is
-exercised without hardware. Tests run on the CPU backend only — the chip is
-driven by chip_smoke.py, never by pytest — so the platform is pinned here
-before any backend initializes, whatever JAX_PLATFORMS says.
+exercised without hardware. Tests run on CPU devices only — the chip is
+driven by chip_smoke.py and the benchmark, never by pytest — so the platform
+is pinned here before any backend initializes, whatever JAX_PLATFORMS says.
+What they run is the chip's program: no module of the package asks which
+backend it is on (tests/test_one_program.py), so a statement traces here to
+the formulations it traces to on a TPU.
 """
 
 import os

@@ -44,10 +44,14 @@ def test_run_passes_on_cpu_at_small_scale():
     assert "compactions" not in by_name["mysql:q6"]
     # every aggregate's integer sums are one batch, and the smoke says what
     # it was: Q1's six groups, its five value columns and the one count each
-    # summed once; on CPU `auto` takes the scatter (a TPU: `masked`)
+    # summed once by the masked reduction, here as on the chip
     q1 = by_name["mysql:q1"]["segment_sums"]["sr.agg.2"]
     assert (q1["groups"], q1["distinct"], q1["formulation"]) == (
-        6, 6, "scatter")
+        6, 6, "masked")
     assert q1["columns"] > q1["distinct"]
     assert by_name["mysql:q6"]["segment_sums"]["sr.agg.1"][
         "formulation"] == "global"
+    # Q3's groups: `sorted` at SF10 on the chip (129,024 groups); at this
+    # scale its capacity is within the contraction's limit
+    assert by_name["mysql:q3"]["segment_sums"]["sr.agg.2"][
+        "formulation"] == "contract"

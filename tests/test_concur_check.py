@@ -418,7 +418,7 @@ def test_package_concur_strict_clean():
     rep = concur_check.check_package()
     assert rep.errors == [], "\n".join(str(f) for f in rep.errors)
     # the coverage ratchet may carry warns, but they are bounded and
-    # tracked (bench.py concur_findings) — a jump means new unreviewed
+    # tracked here — a jump means new unreviewed
     # shared state landed on a lock-owning class
     assert len(rep.warnings) <= 6, "\n".join(str(f) for f in rep.warnings)
     # sanity: the inventory actually sees the engine's locks and the

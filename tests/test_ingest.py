@@ -251,7 +251,7 @@ def test_label_replay_survives_restart_via_tail_and_image(tmp_path):
 
 # --- routine-load poller -----------------------------------------------------
 
-def _wait_until(pred, timeout=8.0):
+def _wait_until(pred, timeout=30.0):
     t0 = time.monotonic()
     while time.monotonic() - t0 < timeout:
         if pred():

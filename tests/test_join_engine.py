@@ -12,9 +12,9 @@ Three families:
    emit_multiway): star + snowflake shapes vs the oracle, off-A/B
    equality, fallback on non-unique builds, and the plan checker's
    independent re-verification of fused invariants;
-3. the Pallas open-addressing hash-table build+probe kernel pair
-   (ops/pallas_kernels.hash_build_pallas / hash_probe_pallas) standalone
-   and through SQL via SET join_probe_strategy='pallas'.
+3. the sorted unique-join kernel (ops/join.hash_join_unique: build
+   argsort + searchsorted probe) on sparse wide-range keys that defeat the
+   LUT path, INNER/LEFT/SEMI/ANTI against pandas.
 """
 
 import numpy as np
@@ -362,79 +362,46 @@ def test_multiway_plan_checker_flags_relaxed_eligibility(monkeypatch):
     assert any("not provably unique" in f.message for f in findings), findings
 
 
-# --- 3. Pallas open-addressing hash table ------------------------------------
+# --- 3. the sorted unique-join kernel on sparse keys ---------------------------
 
 
-def test_hash_kernels_parity_standalone():
-    import jax.numpy as jnp
-
-    from starrocks_tpu.ops.pallas_kernels import (
-        _EMPTY, hash_build_pallas, hash_probe_pallas,
-    )
-
-    rng = np.random.RandomState(7)
-    keys = rng.permutation(1 << 20)[:900].astype(np.int64)
-    keys[3] = _EMPTY    # NULL/dead build rows carry the sentinel
-    keys[77] = _EMPTY
-    table = 2048
-    tk, tr = hash_build_pallas(jnp.asarray(keys), table, interpret=True)
-    probe = np.concatenate([
-        keys, rng.randint(-100, 1 << 20, 3196)]).astype(np.int64)[:4096]
-    got = np.asarray(hash_probe_pallas(tk, tr, jnp.asarray(probe),
-                                       block=1024, interpret=True))
-    oracle = {int(k): i for i, k in enumerate(keys) if k != _EMPTY}
-    exp = np.array([oracle.get(int(p), -1) for p in probe], np.int32)
-    assert (got == exp).all()
-
-
-def test_hash_kernels_dense_collisions():
-    """Adjacent keys hash to clustered slots — the linear-probing worst
-    case; every key must still place and probe back to its own row."""
-    import jax.numpy as jnp
-
-    from starrocks_tpu.ops.pallas_kernels import (
-        hash_build_pallas, hash_probe_pallas,
-    )
-
-    keys = np.arange(1000, dtype=np.int64)
-    tk, tr = hash_build_pallas(jnp.asarray(keys), 2048, interpret=True)
-    got = np.asarray(hash_probe_pallas(
-        tk, tr, jnp.asarray(np.arange(2000, dtype=np.int64)),
-        block=1000, interpret=True))
-    assert (got[:1000] == np.arange(1000)).all()
-    assert (got[1000:] == -1).all()
-
-
-@pytest.mark.parametrize("strategy", ["pallas", "pallas_sorted"])
-def test_join_probe_strategies_full_sql(strategy):
-    """Both kernel strategies answer INNER/LEFT/SEMI/ANTI unique joins
-    identically to the default searchsorted path."""
+@pytest.fixture(scope="module")
+def sparse_unique():
     rng = np.random.default_rng(43)
     n = 6_000
+    # sparse wide-range keys defeat the LUT path, forcing the sorted
+    # unique-join kernel; probe keys overlap the build's domain by half
+    f = pd.DataFrame({
+        "k": ((rng.integers(0, 1200, n) * 1_000_003) % (1 << 40)).astype(
+            np.int64),
+        "v": rng.integers(0, 50, n).astype(np.int64)})
+    d = pd.DataFrame({
+        "k": (np.arange(600) * 1_000_003 % (1 << 40)).astype(np.int64),
+        "w": rng.integers(0, 5, 600).astype(np.int64)})
     cat = Catalog()
-    cat.register("f", HostTable.from_pydict({
-        "k": list(rng.integers(0, 900, n).astype(int)),
-        "v": list(rng.integers(0, 50, n).astype(int))}))
-    cat.register("d", HostTable.from_pydict({
-        # sparse wide-range keys defeat the LUT path, forcing the
-        # sorted/hash unique-join kernels under test
-        "k": list((np.arange(600) * 1_000_003 % (1 << 40)).astype(int)),
-        "w": list(rng.integers(0, 5, 600).astype(int))}),
-        unique_keys=[("k",)])
-    # probe keys must overlap the build's sparse domain for real matches
-    f = cat.get_table("f").table
-    f.arrays["k"] = np.asarray(
-        (rng.integers(0, 1200, n) * 1_000_003) % (1 << 40)).astype(np.int64)
-    s = Session(cat)
-    queries = [
-        "SELECT count(*) c, sum(v) sv, sum(w) sw FROM f, d WHERE f.k = d.k",
-        "SELECT count(*) c, count(w) cw FROM f LEFT JOIN d ON f.k = d.k",
-        "SELECT count(*) c FROM f WHERE k IN (SELECT k FROM d)",
-        "SELECT count(*) c FROM f WHERE k NOT IN (SELECT k FROM d)",
-    ]
-    base = [s.sql(q).rows() for q in queries]
-    s.sql(f"SET join_probe_strategy = '{strategy}'")
-    try:
-        assert [s.sql(q).rows() for q in queries] == base
-    finally:
-        config.set("join_probe_strategy", "auto")
+    cat.register("f", HostTable.from_pydict(f.to_dict("list")))
+    cat.register("d", HostTable.from_pydict(d.to_dict("list")), unique_keys=[("k",)])
+    return Session(cat), f, d
+
+
+@pytest.mark.parametrize("kind", ["inner", "left", "semi", "anti"])
+def test_sorted_unique_join_matches_pandas(sparse_unique, kind):
+    s, f, d = sparse_unique
+    m = f.merge(d, on="k", how="left", indicator=True)
+    hit = m["_merge"] == "both"
+    q, want = {
+        "inner": ("SELECT count(*) c, sum(v) sv, sum(w) sw FROM f, d "
+                  "WHERE f.k = d.k",
+                  (int(hit.sum()), int(m.v[hit].sum()), int(m.w[hit].sum()))),
+        "left": ("SELECT count(*) c, count(w) cw, sum(v) sv FROM f "
+                 "LEFT JOIN d ON f.k = d.k",
+                 (len(m), int(hit.sum()), int(m.v.sum()))),
+        "semi": ("SELECT count(*) c, sum(v) sv FROM f "
+                 "WHERE k IN (SELECT k FROM d)",
+                 (int(hit.sum()), int(m.v[hit].sum()))),
+        "anti": ("SELECT count(*) c, sum(v) sv FROM f "
+                 "WHERE k NOT IN (SELECT k FROM d)",
+                 (int((~hit).sum()), int(m.v[~hit].sum()))),
+    }[kind]
+    assert 0 < int(hit.sum()) < len(m)
+    assert s.sql(q).rows() == [want]

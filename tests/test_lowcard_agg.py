@@ -1,10 +1,6 @@
-"""Low-cardinality (sort-free) aggregation fast path + pallas kernel tests."""
+"""Low-cardinality (sort-free) aggregation fast path tests."""
 
-import numpy as np
 import pytest
-
-import jax
-import jax.numpy as jnp
 
 from starrocks_tpu.runtime.config import config
 from starrocks_tpu.runtime.session import Session
@@ -103,7 +99,7 @@ BATCHED = {
 
 @pytest.mark.parametrize("name", sorted(BATCHED))
 def test_batched_sums_match_sort_path_and_mesh(eight_devices, cat, name):
-    """With the TPU's strategies pinned: the packed-gid path (6 groups, the
+    """The packed-gid path (6 groups, the
     masked reductions) against the sort path (capacity 1,024, the
     contraction) and against PARTIAL a shard + FINAL on eight virtual
     devices; integer and DECIMAL results to the digit."""
@@ -112,7 +108,6 @@ def test_batched_sums_match_sort_path_and_mesh(eight_devices, cat, name):
     q = BATCHED[name]
     old = D.SHARD_THRESHOLD_ROWS
     D.SHARD_THRESHOLD_ROWS = 10_000
-    config.set("segment_strategy", "mxu")
     try:
         r = Session(cat).sql(q)
         fast = r.rows()
@@ -130,79 +125,6 @@ def test_batched_sums_match_sort_path_and_mesh(eight_devices, cat, name):
             config.set("enable_lowcard_agg", True)
         dist = Session(cat, dist_shards=8).sql(q).rows()
     finally:
-        config.set("segment_strategy", "auto")
         D.SHARD_THRESHOLD_ROWS = old
     _same_rows(slow, fast, rel=1e-12)
     _same_rows(dist, fast, rel=1e-9)
-
-
-def test_pallas_segment_sum_matches_oracle():
-    from starrocks_tpu.ops.pallas_kernels import (
-        segment_sum_onehot, segment_sum_pallas,
-    )
-
-    rng = np.random.default_rng(0)
-    N, G, M = 8192, 8, 4
-    gid = jnp.asarray(rng.integers(0, G + 1, N).astype(np.int32))
-    vals = jnp.asarray(rng.normal(size=(N, M)).astype(np.float32))
-    ref = segment_sum_onehot(gid, vals, G)
-    pal = segment_sum_pallas(gid, vals, G, block=2048, interpret=True)
-    assert jnp.allclose(ref, pal, rtol=1e-4, atol=1e-3)
-    exp = np.stack([
-        np.asarray(vals)[np.asarray(gid) == g].sum(axis=0) for g in range(G)
-    ])
-    np.testing.assert_allclose(np.asarray(ref), exp, rtol=1e-3, atol=1e-2)
-
-
-def test_pallas_strategy_end_to_end(cat):
-    """segment_strategy=pallas routes float segment sums through the Pallas
-    kernel (interpret mode on CPU) and the query still matches the default
-    strategy — the flag-flip correctness gate for real hardware."""
-    q = ("select l_returnflag, avg(l_discount) a, var_samp(l_discount) v "
-         "from lineitem group by l_returnflag order by 1")
-    base = Session(cat).sql(q).rows()
-    config.set("segment_strategy", "pallas")
-    try:
-        pal = Session(cat).sql(q).rows()
-    finally:
-        config.set("segment_strategy", "auto")
-    assert len(base) == len(pal)
-    for br, pr in zip(base, pal):
-        assert br[0] == pr[0]
-        for bv, pv in zip(br[1:], pr[1:]):
-            assert pv == pytest.approx(bv, rel=1e-5)
-
-
-def test_pallas_join_probe_parity():
-    """The second Pallas kernel (probe_searchsorted_pallas) matches
-    jnp.searchsorted in interpret mode, standalone and through a full
-    SQL join flipped on via SET join_probe_strategy='pallas'."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    from starrocks_tpu.ops.pallas_kernels import probe_searchsorted_pallas
-
-    rng = np.random.RandomState(3)
-    build = np.sort(rng.randint(0, 10_000, 512).astype(np.int64))
-    probe = rng.randint(-100, 10_100, 4096).astype(np.int64)
-    got = np.asarray(probe_searchsorted_pallas(
-        jnp.asarray(build), jnp.asarray(probe), block=1024, interpret=True))
-    exp = np.searchsorted(build, probe, side="left")
-    assert (got == exp).all()
-
-    from starrocks_tpu.runtime.config import config
-    from starrocks_tpu.runtime.session import Session
-
-    s = Session()
-    s.sql("create table dimp (k int, name varchar, primary key (k))")
-    s.sql("insert into dimp values (1, 'a'), (2, 'b'), (3, 'c')")
-    s.sql("create table facts (k int, v int)")
-    s.sql("insert into facts values (1, 10), (3, 30), (3, 31), (9, 90)")
-    q = ("select name, sum(v) sv from facts, dimp "
-         "where facts.k = dimp.k group by name order by name")
-    base = s.sql(q).rows()
-    s.sql("set join_probe_strategy = 'pallas'")
-    try:
-        assert s.sql(q).rows() == base == [("a", 10), ("c", 61)]
-    finally:
-        config.set("join_probe_strategy", "auto")

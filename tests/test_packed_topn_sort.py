@@ -134,7 +134,7 @@ def test_packed_sort_matches_lexsort_property(seed):
     assert got_lex == want_rows
 
 
-@pytest.mark.parametrize("strategy", ["auto", "pallas"])
+@pytest.mark.parametrize("strategy", ["auto"])
 def test_threshold_topn_matches_full_sort(strategy):
     rng = np.random.default_rng(7)
     n = 5000

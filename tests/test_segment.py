@@ -18,17 +18,6 @@ from starrocks_tpu.ops.segment import (
 from starrocks_tpu.runtime.config import config
 
 
-@pytest.fixture(autouse=True)
-def _force_mxu_strategies():
-    """On CPU `auto` routes everything to plain scatters; pin the MXU
-    strategies so the differential tests keep covering those branches."""
-    config.set("segment_strategy", "mxu")
-    try:
-        yield
-    finally:
-        config.set("segment_strategy", "auto")
-
-
 def _rand_case(n, g, rng, big=False):
     gid = rng.integers(0, g + 1, size=n)  # g == dead marker
     if big:
@@ -121,16 +110,26 @@ def test_seg_first_index():
     np.testing.assert_array_equal(got, [0, 6, 2, 6, 6, 5])
 
 
-def test_disabled_falls_back():
-    config.set("enable_scatter_free_segments", False)
-    try:
-        rng = np.random.default_rng(1)
-        vals, gid = _rand_case(2048, 8, rng)
-        want = jax.ops.segment_sum(vals, gid, num_segments=8)
-        got = seg_sum(vals, gid, 8)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    finally:
-        config.set("enable_scatter_free_segments", True)
+@pytest.mark.parametrize("g,sorted_gid,formulation", [
+    (1, False, "global"), (64, False, "masked"), (65, False, "contract"),
+    (1024, False, "contract"), (1025, True, "sorted"),
+    (1025, False, "scatter"),  # the last rung: many unsorted groups
+])
+def test_ladder_follows_group_count_and_sortedness(g, sorted_gid,
+                                                   formulation):
+    """Which formulation sums an aggregate's integers follows the group
+    count and whether gid is sorted, nothing else: the same ladder on
+    every backend."""
+    rng = np.random.default_rng(g)
+    n = 4096
+    raw = rng.integers(0, g + 1, size=n)  # g == dead marker
+    gid = jnp.asarray(np.sort(raw) if sorted_gid else raw, jnp.int32)
+    vals = jnp.asarray(rng.integers(-(2**40), 2**40, size=n, dtype=np.int64))
+    info = {}
+    (got,) = seg_sums([(vals, 64)], gid, g, sorted_gid=sorted_gid, info=info)
+    assert info["formulation"] == formulation
+    want = jax.ops.segment_sum(vals, gid, num_segments=g)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_seg_sum_float_sorted_no_cancellation():

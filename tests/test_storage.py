@@ -580,72 +580,33 @@ def test_checkpoint_concurrent_log_no_lost_ops(tmp_path):
     assert lost == [], f"ops lost by checkpoint/log race: {lost}"
 
 
-def test_native_fused_filter_sum_unit():
-    from starrocks_tpu import native
-
-    if not native.available() or not hasattr(
-            native._load(), "sr_fused_filter_sum_i64_mt"):
-        pytest.skip("native toolchain unavailable")
-    rng = np.random.default_rng(7)
-    c1 = rng.integers(0, 100, 50000).astype(np.int64)
-    c2 = rng.integers(0, 100, 50000).astype(np.int64)
-    a = rng.integers(-50, 50, 50000).astype(np.int64)
-    b = rng.integers(-50, 50, 50000).astype(np.int64)
-    mask = (c1 >= 30) & (c2 < 70)
-    # sum(a*b) with two conjunctive predicates
-    got = native.fused_filter_sum_i64(
-        [c1, c2], [native.FS_OPS["ge"], native.FS_OPS["lt"]], [30, 70], a, b)
-    assert got == (int((a[mask] * b[mask]).sum()), int(mask.sum()))
-    # sum(a) single-column form
-    got = native.fused_filter_sum_i64([c1], [native.FS_OPS["eq"]], [42], a)
-    m = c1 == 42
-    assert got == (int(a[m].sum()), int(m.sum()))
-    # empty match
-    got = native.fused_filter_sum_i64([c1], [native.FS_OPS["gt"]], [10**9], a)
-    assert got == (0, 0)
-
-
-def test_native_fused_scan_agg_ab(tmp_path):
-    """segment_strategy=native serves the SSB q1.x shape (ungrouped
-    sum(a*b) under conjunctive int predicates) through the fused C++
-    kernel; results must be value-identical to the regular path,
-    including sum-over-empty -> NULL."""
-    from starrocks_tpu import native
-    from starrocks_tpu.runtime.config import config
-
-    if not native.available() or not hasattr(
-            native._load(), "sr_fused_filter_sum_i64_mt"):
-        pytest.skip("native toolchain unavailable")
+def test_ungrouped_filter_sum_matches_numpy(tmp_path):
+    """The SSB q1.x shape (ungrouped sum(a*b) under conjunctive integer
+    predicates) over a stored table: one `global` masked reduction, held
+    to numpy, sum-over-empty -> NULL and a NULL-bearing column included."""
     s = Session(data_dir=str(tmp_path / "dbf"))
     s.sql("create table f (d bigint, disc bigint, qty bigint, "
           "price bigint, nn bigint)")
+    i = np.arange(5000)
+    d, disc, qty, price = 19940101 + i % 300, i % 11, i % 50, i * 7 % 1000
     rows = ",".join(
-        f"({19940101 + i % 300}, {i % 11}, {i % 50}, {i * 7 % 1000}, "
-        f"{'null' if i % 97 == 0 else i})"
-        for i in range(5000))
+        f"({d[k]}, {disc[k]}, {qty[k]}, {price[k]}, "
+        f"{'null' if k % 97 == 0 else k})"
+        for k in i)
     s.sql(f"insert into f values {rows}")
-    queries = [
-        # the q1.2/q1.3 family shape the kernel exists for
-        "select sum(price * disc) rev from f "
-        "where d >= 19940110 and d <= 19940210 and disc >= 4 "
-        "and disc <= 6 and qty < 25",
-        "select sum(price) p from f where disc = 3",
-        # empty match: sum-of-nothing must stay NULL on both paths
-        "select sum(price * disc) rev from f where qty > 10000",
-        # NULL-bearing column in the sum: kernel must decline, paths agree
-        "select sum(nn) z from f where disc >= 9",
-    ]
-    base = [s.sql(q).rows() for q in queries]
-    config.set("segment_strategy", "native")
-    try:
-        fused, profiles = [], []
-        for q in queries:
-            r = s.sql(q)
-            fused.append(r.rows())
-            profiles.append(r.profile)
-        assert fused == base
-        # the first query really did take the fused lane
-        assert profiles[0] is not None and \
-            profiles[0].infos.get("native_fused") == "filter_sum"
-    finally:
-        config.set("segment_strategy", "auto")
+    m = ((d >= 19940110) & (d <= 19940210) & (disc >= 4) & (disc <= 6)
+         & (qty < 25))
+    r = s.sql("select sum(price * disc) rev from f "
+              "where d >= 19940110 and d <= 19940210 and disc >= 4 "
+              "and disc <= 6 and qty < 25")
+    assert r.rows() == [(int((price * disc)[m].sum()),)]
+    took = [a.infos["segment_sums"] for a in r.profile.children
+            if "segment_sums" in a.infos][-1]
+    assert {t["formulation"] for t in took.values()} == {"global"}
+    assert s.sql("select sum(price) p from f where disc = 3").rows() == [
+        (int(price[disc == 3].sum()),)]
+    assert s.sql("select sum(price * disc) rev from f "
+                 "where qty > 10000").rows() == [(None,)]
+    keep = (disc >= 9) & (i % 97 != 0)
+    assert s.sql("select sum(nn) z from f where disc >= 9").rows() == [
+        (int(i[keep].sum()),)]

@@ -9,7 +9,7 @@ import jax
 from starrocks_tpu.column import HostTable
 
 # the single source of truth for the hand-built Q1 plan lives in the driver
-# entry module; the test validates the exact plan bench.py measures
+# entry module; the test validates that exact plan
 from __graft_entry__ import _q1_plan as tpch_q1
 
 

@@ -27,10 +27,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(HERE, "trace_scopes_parent.json")) as _f:
     PARENT = json.load(_f)  # taken at 9b96640, before any scope existed
 
-# scopes each statement must lower with at SF0.01 on the CPU backend
-# (Q3's three-way join fuses into one multiway probe under its top join)
+# scopes each statement must lower with at SF0.01 (Q3's three-way join
+# fuses into one multiway probe under its top join)
 EXPECTED = {
     1: {"sr.sort.0/sort", "sr.sort.0/sr.project.1/sr.agg.2/segments",
+        "sr.sort.0/sr.project.1/sr.agg.2/segments/limbs",
         "sr.sort.0/sr.project.1/sr.agg.2/sr.filter.3"},
     3: {"sr.sort.0/sort", "sr.sort.0/sr.project.1/sr.agg.2/lexsort",
         "sr.sort.0/sr.project.1/sr.agg.2/sr.join.3/build",
@@ -86,22 +87,12 @@ def test_q3_compacts_by_index_and_gather_without_a_scatter(lowered):
     assert any(s[-1] == "gather" and "gather" in s[:-1] for s in stacks)
 
 
-@pytest.fixture(scope="module")
-def q1_on_the_tpus_strategy(tpch):
-    """Q1 as a TPU runs it (`auto` takes the scatter on CPU): (the lowered
-    text, the last attempt's infos)."""
-    config.set("segment_strategy", "mxu")
-    try:
-        s = Session(tpch)
-        result = s.sql(QUERIES[1])
-        attempt = [a for a in result.profile.children
-                   if "capacities" in a.infos][-1]
-        return lowered_text(s, result), attempt.infos
-    finally:
-        config.set("segment_strategy", "auto")
+def _last_attempt(result):
+    return [a for a in result.profile.children
+            if "capacities" in a.infos][-1]
 
 
-def test_q1_sums_every_integer_column_in_one_batch(q1_on_the_tpus_strategy):
+def test_q1_sums_every_integer_column_in_one_batch(ran, lowered):
     """Q1 has eight aggregates that ask for sixteen integer sums: four sums
     with their nonempty counts, three averages with theirs, count(*) and the
     group count. Under `sr.agg.2/limbs` the program holds ONE reduce over the
@@ -110,7 +101,7 @@ def test_q1_sums_every_integer_column_in_one_batch(q1_on_the_tpus_strategy):
     with six groups there is no contraction; the attempt says so. Before PR
     27: five contractions of 8 stacked f32 limbs each over 1,024 groups, 520
     of Q1's 548 ms at SF10 on a v5e."""
-    text, infos = q1_on_the_tpus_strategy
+    text, infos = lowered[1], _last_attempt(ran[1][1]).infos
     stacks = [p.split("/") for p in SCOPED.findall(text) if "/limbs/" in p]
     assert stacks and all("sr.agg.2" in s for s in stacks)
     ops = {s[-1] for s in stacks}
@@ -124,28 +115,57 @@ def test_q1_sums_every_integer_column_in_one_batch(q1_on_the_tpus_strategy):
         "limbs": 0, "formulation": "masked"}}
 
 
-@pytest.mark.parametrize("q", [3, 6])
-def test_q3_and_q6_have_no_limbs_phase(ran, lowered, q):
-    """Q6 has one group (a global masked reduction), Q3 millions (lexsort
-    and prefix sums): neither runs the batched formulations, on any
-    backend; their attempts name what they took."""
-    assert not [p for p in SCOPED.findall(lowered[q]) if "/limbs/" in p]
-    _, result, _ = ran[q]
-    attempt = [a for a in result.profile.children
-               if "capacities" in a.infos][-1]
-    (scope, took), = attempt.infos["segment_sums"].items()
-    assert scope == {3: "sr.agg.2", 6: "sr.agg.1"}[q]
-    assert took["formulation"] == {3: "scatter", 6: "global"}[q]
-    assert took["limbs"] == 0
+@pytest.mark.parametrize("q,has_limbs", [(3, True), (6, False)])
+def test_limbs_phase_is_there_where_sums_are_batched(lowered, q, has_limbs):
+    """Q6 has one group: a global masked reduction, no `limbs` phase. Q3
+    groups by `l_orderkey`: at this scale its capacity of 1,024 is within
+    `matmul_segsum_groups_max`, so its two sums are one contraction under
+    `sr.agg.2/limbs` (at SF10, 129,024 groups: the lexsort's prefix sums,
+    no `limbs`)."""
+    limbs = [p for p in SCOPED.findall(lowered[q]) if "/limbs/" in p]
+    assert bool(limbs) == has_limbs
+    assert all("sr.agg.2" in p for p in limbs)
+
+
+# what `chip_smoke.py` printed on a v5e at SF10 (PERF.md section 6): Q1
+# `masked` over 6 groups, Q6 `global`, Q3 `sorted` (129,024 groups). Q3
+# reaches `sorted` once its groups exceed `matmul_segsum_groups_max`: here
+# the limit is set under the fixture's 1,024 for that case.
+CHIP_FORMULATIONS = [
+    (1, None, "sr.agg.2", "masked", 6),
+    (6, None, "sr.agg.1", "global", 1),
+    (3, None, "sr.agg.2", "contract", 1024),
+    (3, 512, "sr.agg.2", "sorted", 1024),
+]
+
+
+@pytest.mark.parametrize(
+    "q,matmul_max,scope,formulation,groups", CHIP_FORMULATIONS,
+    ids=[f"q{q}-{f}" for q, _, _, f, _ in CHIP_FORMULATIONS])
+def test_tpch_programs_take_the_chips_formulations(
+        tpch, ran, q, matmul_max, scope, formulation, groups):
+    """Tier-1 runs the ladder the chip runs: no backend test stands
+    between a statement and its formulation, only its group count. With
+    the limit moved the rows stay those of the default program (held to
+    pandas in test_tpch_sql.py)."""
+    result = ran[q][1]
+    if matmul_max is not None:
+        default = config.get("matmul_segsum_groups_max")
+        config.set("matmul_segsum_groups_max", matmul_max)
+        try:
+            result = Session(tpch).sql(QUERIES[q])
+        finally:
+            config.set("matmul_segsum_groups_max", default)
+        assert result.rows() == ran[q][1].rows()
+    took = _last_attempt(result).infos["segment_sums"][scope]
+    assert (took["formulation"], took["groups"]) == (formulation, groups)
 
 
 def test_q3_profile_names_each_compaction(ran):
     """Beside an attempt's `capacities`: what each compaction of its program
     shrank (rows in, slots out) and how the index was computed; on a
     program-cache hit too (`ran` holds the second send)."""
-    _, result, _ = ran[3]
-    attempt = [a for a in result.profile.children
-               if "capacities" in a.infos][-1]
+    attempt = _last_attempt(ran[3][1])
     done = attempt.infos["compactions"]
     assert any(key.startswith("shrink_") for key in done)
     for key, c in done.items():

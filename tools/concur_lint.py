@@ -9,15 +9,14 @@ Runs ahead of pytest in tools/run_tier1.sh (next to src_lint/plan_lint):
   lexical self-nesting of non-reentrant locks = certain deadlocks), and
   the `# guarded_by:` field discipline, strict: any error finding fails
   the gate. Warn findings (the unannotated-mutable-attr coverage ratchet)
-  print and count but do not fail — bench.py tracks the count across
-  rounds as `concur_findings`; use --strict-warn to ratchet hard.
+  print and count but do not fail — tests/test_concur_check.py bounds
+  the count; use --strict-warn to ratchet hard.
 
 - analysis/effects_check.py — interprocedural effect summaries over the
   same parse + name index: exception-safe acquire, checkpoint density of
   blocking loops, no blocking under lock, daemon-thread lifecycle. Warn
   findings are suppression annotations missing a reason (the
-  `--strict-warn` ratchet keeps unexplained exceptions at zero);
-  bench.py tracks the warn count as `effects_findings`.
+  `--strict-warn` ratchet keeps unexplained exceptions at zero).
 
 - analysis/boundary_check.py — the repo-root module_boundary_manifest.json
   (the reference's be/module_boundary_manifest.json analog): every

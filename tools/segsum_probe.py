@@ -27,9 +27,9 @@ quarter of the rows (one shard of four), for 6, 64 and 1,024 groups:
   `c64/rows-major`: the `[rows, G]` orientation `_seg_sum_float_bcast` has;
 - `d/scan-b@B`, `d/scan-c64@B`: (b) and (c64) under a `fori_loop` over row
   blocks, so the temporaries are one block's whatever XLA fuses;
-- `engine`: `ops/segment.seg_sums` itself, as the tree has it;
-- `pallas/f32`: `segment_sum_pallas` on one f32 column, for the record (it
-  is not exact for integers and no candidate).
+- `engine`: `ops/segment.seg_sums` itself, as the tree has it.
+(PR 27 also timed `segment_sum_pallas` on one f32 column; the kernel went in
+PR 28 and its readings stay in PERF.md section 6.)
 
 Each line: milliseconds (best of `--runs` after a warm-up call), the
 program's own bytes (XLA's memory analysis: temporaries + outputs, arguments
@@ -205,25 +205,8 @@ def _candidates(n: int, G: int, nbits_of):
 
     def engine(cols, gid):
         from starrocks_tpu.ops import segment
-        from starrocks_tpu.runtime.config import config
 
-        if not hasattr(segment, "seg_sums"):
-            raise NotImplementedError("ops/segment.py has no seg_sums")
-        config.set("segment_strategy", "mxu")  # the TPU's choice, on CPU too
-        try:
-            return tuple(segment.seg_sums(
-                list(zip(cols, nbits_of)), gid, G))
-        finally:
-            config.set("segment_strategy", "auto")
-
-    def pallas_f32(cols, gid):
-        from starrocks_tpu.ops.pallas_kernels import segment_sum_pallas
-
-        out = segment_sum_pallas(
-            jnp.clip(gid, 0, G), jnp.asarray(cols[0], jnp.float32)[:, None],
-            G, block=min(n & -n, 2048),
-            interpret=jax.default_backend() != "tpu")
-        return (out[:, 0],)
+        return tuple(segment.seg_sums(list(zip(cols, nbits_of)), gid, G))
 
     return {
         "c64/masked": masked64,
@@ -237,7 +220,6 @@ def _candidates(n: int, G: int, nbits_of):
         "b/contract@1024": contract(1024),
         "b/contract@32768": contract(32768),
         "a/column-f32": column_f32,
-        "pallas/f32": pallas_f32,
     }
 
 
@@ -344,12 +326,9 @@ def probe(name: str, n: int, G: int, runs: int, only=(), compile_only=False):
                 row["ns_per_row"] = dt * 1e9 / n
                 stats = dev.memory_stats() or {}
                 row["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
-                if cname == "pallas/f32":
-                    row["exact"] = None
-                else:
-                    row["exact"] = all(
-                        np.array_equal(np.asarray(a), b)
-                        for a, b in zip(out, want))
+                row["exact"] = all(
+                    np.array_equal(np.asarray(a), b)
+                    for a, b in zip(out, want))
                 del out
         except Exception as e:  # noqa: BLE001 — a candidate the compiler or
             # the memory refuses is a finding; the probe goes on
