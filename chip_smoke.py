@@ -19,8 +19,9 @@ Exits non-zero — with no JSON line — unless `jax.default_backend()` is
 missing counter, compile in a last send, off-device column or missing
 memory statistic. With `--chips 4` it first runs the two collectives the
 engine works around (uint8 OR as an int32 psum, int64 min/max as an
-all_gather) on the real mesh against numpy, and prints every fragment
-program's module name with its compactions and exchanges. Seconds are
+all_gather) on the real mesh against numpy, prints every fragment
+program's module name with its compactions (`cap`, `out_cap`, `live`) and
+exchanges, and fails if Q3's `_f2` holds no `shrink_<n>l`. Seconds are
 printed as facts of this run, not as metrics. Last stdout line on success:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
@@ -34,6 +35,7 @@ import http.client
 import json
 import math
 import os
+import re
 import sys
 import time
 
@@ -119,6 +121,14 @@ def _last_attempt_info(name: str) -> dict:
                 if entries else ())
     done = [a["infos"][name] for a in attempts if name in a.get("infos", {})]
     return done[-1] if done else {}
+
+
+def _probe_shrinks(programs: dict, fid: int) -> list:
+    """The keys `shrink_<n>l` among the compactions of fragment program
+    `_f<fid>`: the probe sides of its joins that ran at their live rows."""
+    return [key for module, holds in programs.items()
+            if module.endswith(f"_f{fid}") for key in holds["compactions"]
+            if re.fullmatch(r"shrink_\d+l", key)]
 
 
 def _check_collectives(chips: int, seed: int) -> list:
@@ -306,6 +316,12 @@ def run(sf: float, chips: int, seed: int) -> dict:
                     print(f"program {name} name={module} compactions="
                           f"{json.dumps(holds['compactions'])} exchanges="
                           f"{json.dumps(holds['exchanges'])}")
+                # Q3's `_f2` searches and groups lineitem: its probe side
+                # must be down to its live rows first (`shrink_<n>l`, with
+                # `live`, the fullest shard's rows, beside `cap`/`out_cap`)
+                if qid == 3 and not _probe_shrinks(programs, 2):
+                    failures.append(f"{name}: fragment _f2 compacts no "
+                                    "probe side (no shrink_<n>l)")
             statements.append((record, qid, key, rows))
 
         # what the statements left on the device, before anything is freed

@@ -166,25 +166,28 @@ class DistExecutor(Executor):
     def _attempt_infos(self, p, caps, facts: list, checks: dict) -> list:
         """The attempt's checks merged on the host, and on its profile what
         its programs' facts say: `n_shards`, `programs` (module name -> its
-        compactions and exchanges), `compactions` and `segment_sums` (all of
-        them, as on one chip), and for every all_to_all `exchange_fill`, its fullest bucket
+        compactions, each with the `live` rows its check counted, and its
+        exchanges), `compactions` and `segment_sums` (all of them, as on
+        one chip), and for every all_to_all `exchange_fill`, its fullest bucket
         (the overflow check's value, on the host anyway) over the bucket's
         capacity — skew and padding, read off a statement."""
         keyed = [(k, self._host_max(v)) for k, v in checks.items()]
+        fullest = dict(keyed)
         p.set_info("n_shards", self.n)
-        p.set_info("programs", {
-            f["name"]: {"compactions": dict(f["compactions"]),
+        programs = {
+            f["name"]: {"compactions": self._compactions_with_live(
+                            f["compactions"], fullest),
                         "exchanges": list(f["exchanges"])}
-            for f in facts if f})
-        done = {k: c for f in facts
-                for k, c in f.get("compactions", {}).items()}
+            for f in facts if f}
+        p.set_info("programs", programs)
+        done = {k: c for holds in programs.values()
+                for k, c in holds["compactions"].items()}
         if done:
             p.set_info("compactions", done)
         sums = {k: c for f in facts
                 for k, c in f.get("segment_sums", {}).items()}
         if sums:
             p.set_info("segment_sums", sums)
-        fullest = dict(keyed)
         fill = {e["check"]: round(fullest[e["check"]]
                                   / caps.values[e["check"]], 4)
                 for f in facts for e in f.get("exchanges", ())

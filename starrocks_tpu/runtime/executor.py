@@ -1276,10 +1276,13 @@ class Executor:
             facts = self._program_facts(
                 self.cache.program_bucket(("local", plan)), caps,
                 trace_box.pop("facts", None))
-            for fact in ("compactions", "segment_sums"):
-                if facts.get(fact):
-                    p.set_info(fact, dict(facts[fact]))
-            return out, [(k, int(v)) for k, v in checks.items()]
+            keyed = [(k, int(v)) for k, v in checks.items()]
+            if facts.get("compactions"):
+                p.set_info("compactions", self._compactions_with_live(
+                    facts["compactions"], dict(keyed)))
+            if facts.get("segment_sums"):
+                p.set_info("segment_sums", dict(facts["segment_sums"]))
+            return out, keyed
 
         def publish(vals):
             self.cache.bucket_last_set(
@@ -1310,6 +1313,17 @@ class Executor:
             return self.cache.bucket_meta_get(bucket, key) or {}
         self.cache.bucket_meta_set(bucket, key, fresh)
         return fresh
+
+    @staticmethod
+    def _compactions_with_live(done: dict, counts: dict) -> dict:
+        """A program's compactions (capacity key -> `cap`, `out_cap`,
+        `method`, from its trace) with `live`, the rows this attempt's
+        overflow check counted under the key (on a mesh: on the fullest
+        shard): `live / out_cap` is the fill, `out_cap / cap` the shrink.
+        A compaction whose caller knows its bound (a top-N's) has no check
+        and no `live`."""
+        return {k: {**c, "live": counts[k]} if k in counts else dict(c)
+                for k, c in done.items()}
 
     @staticmethod
     def _bind_operators(profile, node_ord):

@@ -60,8 +60,8 @@ from .logical import (
 )
 from .optimizer import and_all
 from .physical import (
-    Caps, PlanError, _equi_pair, _key_bit_width, plan_scopes, scope_name,
-    scope_table, unique_sets,
+    Caps, PlanError, _equi_pair, _key_bit_width, join_side_estimates,
+    plan_scopes, scope_name, scope_table, shrink_capacity, unique_sets,
 )
 
 SHARDED = "sharded"
@@ -328,7 +328,7 @@ def compile_distributed(
                     kcap = pad_capacity(k)
                     if kcap < c.capacity:
                         # live <= k: no overflow
-                        c = compact_to(c, f"limit_{ordinal(p)}", kcap)
+                        c, _ = compact_to(c, f"limit_{ordinal(p)}", kcap)
                 if _is_dist(m):
                     note(p, 0, p.child, "gather", (), REPLICATED, "limit",
                          m, c)
@@ -355,9 +355,24 @@ def compile_distributed(
             raise PlanError(f"cannot compile {type(p).__name__} distributed")
 
         def compact_to(c, key: str, cap: int):
+            """(chunk at `cap` slots, its live rows on this shard): a caller
+            that cannot bound the rows puts the count in `checks[key]`."""
             compactions[key] = {"cap": c.capacity, "out_cap": cap,
                                 "method": INDEX_METHOD}
-            return compact(c, cap)[0]
+            return compact(c, cap)
+
+        def shrink(c, mode, key: str, est: float):
+            """physical.compile_plan's `maybe_compact`, a shard: `est` live
+            rows over all shards are `est / n_shards` here where the chunk
+            is distributed. Dead slots only, so `mode` stands."""
+            if _is_dist(mode):
+                est = est / n_shards
+            cap = shrink_capacity(caps, key, c.capacity, est)
+            if cap is None:
+                return c
+            c, live = compact_to(c, key, cap)
+            checks[key] = live[None]
+            return c
 
         def _emit_ctrs(p, ctrs, dist: bool):
             """'~ctr_' profile counters ride the checks channel, whose host
@@ -432,7 +447,7 @@ def compile_distributed(
                 kcap = pad_capacity(p.limit)
                 if kcap < local.capacity:
                     # live <= limit: no overflow
-                    local = compact_to(local, f"topn_{ordinal(p)}", kcap)
+                    local, _ = compact_to(local, f"topn_{ordinal(p)}", kcap)
                 note(p, 0, p.child, "gather", (), REPLICATED, "topn",
                      m, local)
                 gathered = all_gather(local)
@@ -671,6 +686,7 @@ def compile_distributed(
             )
 
             strategy = rf_strategy_of(_cfg)
+            exact_rf = False  # as in compile_plan: dense, or uncapped bloom
             if p.kind in ("inner", "semi", "cross") and probe_keys and not (
                 len(probe_keys) == 1 and isinstance(probe_keys[0], Lit)
             ) and strategy != "off":
@@ -687,7 +703,7 @@ def compile_distributed(
                                           _cfg.get("rf_bloom_max_bits"))
                 n0 = lc.num_rows()
                 if dr is None and bloom is not None:
-                    bits, _exactish = bloom
+                    bits, exact_rf = bloom
                     lc = lc.and_sel(bloom_filter_mask(
                         lc, rc, tuple(probe_keys), tuple(build_keys),
                         bit_widths, rf_axis, bits=bits))
@@ -695,6 +711,7 @@ def compile_distributed(
                     checks[f"~ctr_rf_bloom_bits@{ordinal(p)}"] = (
                         jnp.asarray(bits, jnp.int64)[None])
                 else:
+                    exact_rf = dr is not None
                     lc = lc.and_sel(runtime_filter_mask(
                         lc, rc, tuple(probe_keys), tuple(build_keys),
                         bit_widths, rf_axis, dense_range=dr))
@@ -705,6 +722,16 @@ def compile_distributed(
                     # host max IS the cross-shard sum)
                     pruned = jax.lax.psum(pruned, axis)
                 checks[f"~ctr_rf_rows_pruned@{ordinal(p)}"] = pruned[None]
+
+            # the sides hold few live rows in many slots now (filters below,
+            # the runtime filter above), and all that follows is priced per
+            # slot: the shuffle's pack, the build's sort, the probe's search,
+            # the payload gathers, the consumer at the join's capacity.
+            # Shrink them here, BEFORE a side is shuffled, by the one-chip
+            # compiler's rule (physical.shrink_capacity)
+            est_l, est_r = join_side_estimates(p, catalog, exact_rf)
+            lc = shrink(lc, lm, f"shrink_{ordinal(p)}l", est_l)
+            rc = shrink(rc, rm, f"shrink_{ordinal(p)}r", est_r)
 
             # --- distribution strategy ---
             def align_pos(mode, keys):

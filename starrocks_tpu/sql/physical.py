@@ -475,6 +475,42 @@ class Caps:
         return self.values.setdefault(key, default)
 
 
+SHRINK_MIN_CAPACITY = 8192
+
+
+def shrink_capacity(caps: Caps, key: str, capacity: int,
+                    est: float) -> int | None:
+    """The capacity to compact a sparse chunk to before work that is priced
+    per SLOT (sort, search, shuffle pack, aggregate), or None to leave it
+    alone. Shared by compile_plan's `maybe_compact` and the distributed
+    compiler's `emit_join` so their plans can never diverge; `est` is the
+    live rows expected in THIS chunk (on a mesh: one shard's). Nothing
+    under 8,192 slots; seeded at 1.5x the estimate + 1,024; skipped when
+    that is no smaller than what is there. A learned capacity like every
+    other: `caps` holds it under `key` only where the shrink can engage,
+    and its overflow check recompiles on an underestimate."""
+    if capacity < SHRINK_MIN_CAPACITY:
+        return None
+    default = pad_capacity(int(est * 1.5) + 1024)
+    if default >= capacity:
+        return None
+    cap = caps.get(key, default)
+    return cap if cap < capacity else None
+
+
+def join_side_estimates(p: LJoin, catalog, exact_rf: bool) -> tuple:
+    """(probe, build) live rows a binary join's sides hold when its search
+    runs, over all shards. A probe side just masked by an exact (dense
+    bitmap) or near-exact (uncapped bloom) runtime filter holds about the
+    JOIN's output, not the plan estimate of its subtree; the min/max
+    fallback may keep every row, and only an inner join's filter drops
+    what the join would."""
+    est_l = estimate_rows(p.left, catalog)
+    if exact_rf and p.kind == "inner":
+        est_l = min(est_l, estimate_rows(p, catalog))
+    return est_l, estimate_rows(p.right, catalog)
+
+
 _SCOPE_KIND = {
     LScan: "scan", LFilter: "filter", LProject: "project", LJoin: "join",
     LAggregate: "agg", LSort: "sort", LLimit: "limit", LWindow: "window",
@@ -629,18 +665,11 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             masked by an exact runtime filter); the overflow check
             recompiles on underestimates (same contract as every other
             capacity)."""
-            if c.capacity < 8192:
-                return c
             if est is None:
                 est = estimate_rows(child_plan, catalog)
-            default = pad_capacity(int(est * 1.5) + 1024)
-            if default >= c.capacity:
-                return c
             key = f"shrink_{tag}"
-            cap = caps.get(key, default)
-            if cap >= c.capacity:
-                return c
-            return compact_to(c, key, cap)
+            cap = shrink_capacity(caps, key, c.capacity, est)
+            return c if cap is None else compact_to(c, key, cap)
 
         def _emit(p: LogicalPlan):
             if isinstance(p, LScan):
@@ -1053,14 +1082,11 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
             # size instead of raw probe capacity (TPC-H Q18: 6M lineitem
             # probe vs a 57-order build). Overflow checks recover if the
             # estimate lied.
-            est_l = None
-            if exact_rf and p.kind == "inner":
-                est_l = min(estimate_rows(p.left, catalog),
-                            estimate_rows(p, catalog))
+            est_l, est_r = join_side_estimates(p, catalog, exact_rf)
             lc = maybe_compact(p.left, lc, f"{ordinal(p)}l", est=est_l)
             # the sorted join paths argsort the BUILD side at full capacity —
             # compact it first when it is sparse (filtered dimension chains)
-            rc = maybe_compact(p.right, rc, f"{ordinal(p)}r")
+            rc = maybe_compact(p.right, rc, f"{ordinal(p)}r", est=est_r)
             bo_idx = build_order_input(p, rc, rc0)
             build_order = (
                 inputs[len(scans) + bo_idx] if bo_idx is not None else None

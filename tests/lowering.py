@@ -11,9 +11,11 @@ from starrocks_tpu.sql.physical import Caps, compile_plan
 SCOPED = re.compile(r'"(jit\([^"]*?sr\.[^"]*)"')
 
 
-def lowered_text(session, result) -> str:
+def lowered_text(session, result, debug_info: bool = True) -> str:
     """Lower the plan `result` ran, at the capacities it ended on, over the
-    session's own device columns."""
+    session's own device columns. Without `debug_info` the text holds no
+    name stacks and no source lines: the same program gives the same bytes
+    on two trees."""
     caps = {}
     for attempt in result.profile.children:
         caps = attempt.infos.get("capacities") or caps
@@ -23,7 +25,7 @@ def lowered_text(session, result) -> str:
                    for t, a, cols in compiled.scans) + tuple(
         session.cache.build_order_for(table(t), a, keys, widths)
         for t, a, keys, widths in compiled.aux)
-    return jax.jit(compiled.fn).lower(inputs).as_text(debug_info=True)
+    return jax.jit(compiled.fn).lower(inputs).as_text(debug_info=debug_info)
 
 
 def scope_paths(text: str, phases) -> set:

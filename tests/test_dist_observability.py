@@ -272,9 +272,17 @@ def test_q3_attempt_names_its_compactions_and_how_full_its_exchanges_ran(sends):
         attempt = result.profile.children[-1].infos
         done = attempt["compactions"]
         # the top-10 of each shard is compacted to 1,024 slots before the
-        # result gather; on a cache hit the info is read from the bucket
-        assert set(done) == {"topn_0"}
+        # result gather, lineitem's 15,360 slots a shard to their live
+        # rows before the search (`orders`' 3,840 are under the rule's
+        # 8,192); on a cache hit the info is read from the bucket
+        # (the first send also shrinks the join's build side, which the
+        # learned shuffle capacity then leaves under 8,192 slots)
+        assert {"topn_0", "shrink_3l"} <= set(done) <= {
+            "topn_0", "shrink_3l", "shrink_3r"}
         assert done["topn_0"]["out_cap"] == 1024 < done["topn_0"]["cap"]
+        assert "live" not in done["topn_0"]  # bounded by its caller
+        probe = done["shrink_3l"]
+        assert 0 < probe["live"] <= probe["out_cap"] < probe["cap"] == 15360
         fill = attempt["exchange_fill"]
         assert {k.split("_")[0] for k in fill} == {"shufL", "shufR"}
         for key, share in fill.items():
@@ -498,9 +506,35 @@ def test_chip_smoke_on_four_virtual_devices(small_tables_shard, capsys):
                 for e in holds["exchanges"] if e["op"] == "all_to_all"]
     assert [c.split("_")[0] for c in shuffles] == ["shufL", "shufR"]
     assert by_name["mysql:q3"]["compactions"]["topn_0"]["method"]
+    f2 = next(h for n, h in programs.items() if n.endswith("_f2"))
+    assert 0 < f2["compactions"]["shrink_3l"]["live"]
+    assert re.search(r"program mysql:q3 name=q_[0-9a-f]{8}_f2 compactions="
+                     r'\{"shrink_3l": \{"cap": 15360, "out_cap": \d+, '
+                     r'"method": "shift", "live": \d+\}', out)
     assert re.search(r"program mysql:q3 name=q_[0-9a-f]{8}_f3 compactions="
                      r'\{"topn_0"', out)
     assert len(by_name["mysql:q1"]["programs"]) == 2
+
+
+def test_chip_smoke_fails_where_q3_searches_at_its_probe_capacity(
+        small_tables_shard, monkeypatch):
+    """`_f2` without a `shrink_<n>l` is the four-chip Q3 of before PR 30
+    (20 s a statement at SF10): right answers, and a failure."""
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+    from starrocks_tpu.sql import physical
+
+    assert chip_smoke._probe_shrinks(
+        {"q_0_f1": {"compactions": {"shrink_6l": {}}},
+         "q_0_f2": {"compactions": {"shrink_3r": {}, "shrink_3l": {},
+                                    "topn_0": {}}}}, 2) == ["shrink_3l"]
+    monkeypatch.setattr(physical, "SHRINK_MIN_CAPACITY", 1 << 30)
+    res = chip_smoke.run(sf=0.01, chips=N, seed=7)
+    assert not res["ok"]
+    assert res["failures"] == [
+        "mysql:q3: fragment _f2 compacts no probe side (no shrink_<n>l)"]
+    by_name = {s["statement"]: s for s in res["statements"]}
+    assert all(s["oracle_match"] for s in by_name.values())
 
 
 def test_collective_check_reports_a_mismatch(monkeypatch, eight_devices):
