@@ -1,0 +1,26 @@
+"""SSB Q2.2 on `lineorder_flat` in pandas: the plain reference for
+`statements/ssb_flat/q2.2.sql`, written from the statement's meaning (revenue
+by year and brand for eight brands, compared as text, and Asian suppliers).
+Integer columns are widened to int64 before any arithmetic, so every sum is
+exact."""
+
+import pandas as pd
+
+COLUMNS = {"lineorder_flat": ("LO_ORDERDATE", "LO_REVENUE", "P_BRAND",
+                              "S_REGION")}
+KEY = None  # ORDER BY names every group column: total
+
+
+def expected(f):
+    t = f["lineorder_flat"]
+    # the range is over the text: decide it once a brand, not once a row
+    brands = [b for b in t.P_BRAND.cat.categories
+              if "MFGR#2221" <= b <= "MFGR#2228"]
+    x = t[t.P_BRAND.isin(brands) & (t.S_REGION == "ASIA")]
+    x = x.assign(year=x.LO_ORDERDATE.dt.year,
+                 revenue64=x.LO_REVENUE.astype("int64"))
+    g = x.groupby(["year", "P_BRAND"], as_index=False,
+                  observed=True).agg(revenue=("revenue64", "sum"))
+    g = g.sort_values(["year", "P_BRAND"])
+    return g[["revenue", "year", "P_BRAND"]].astype(
+        {"P_BRAND": str})

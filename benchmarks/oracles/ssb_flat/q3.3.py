@@ -1,0 +1,27 @@
+"""SSB Q3.3 on `lineorder_flat` in pandas: the plain reference for
+`statements/ssb_flat/q3.3.sql`, written from the statement's meaning (revenue
+by city pair and year between two cities of the United Kingdom, 1992 to 1997).
+Integer columns are widened to int64 before any arithmetic, so every sum is
+exact."""
+
+import pandas as pd
+
+COLUMNS = {"lineorder_flat": ("LO_ORDERDATE", "LO_REVENUE", "C_CITY", "S_CITY")}
+# ORDER BY year, revenue DESC: revenues of different groups do not tie
+KEY = None
+
+
+def expected(f):
+    t = f["lineorder_flat"]
+    x = t[t.C_CITY.isin(["UNITED KI1", "UNITED KI5"])
+          & t.S_CITY.isin(["UNITED KI1", "UNITED KI5"])
+          & (t.LO_ORDERDATE >= pd.Timestamp("1992-01-01"))
+          & (t.LO_ORDERDATE <= pd.Timestamp("1997-12-31"))]
+    x = x.assign(year=x.LO_ORDERDATE.dt.year,
+                 revenue64=x.LO_REVENUE.astype("int64"))
+    g = x.groupby(["C_CITY", "S_CITY", "year"], as_index=False,
+                  observed=True).agg(revenue=("revenue64", "sum"))
+    g = g.sort_values(["year", "revenue"],
+                      ascending=[True, False])
+    return g[["C_CITY", "S_CITY", "year", "revenue"]].astype(
+        {"C_CITY": str, "S_CITY": str})

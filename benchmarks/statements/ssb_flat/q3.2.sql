@@ -1,0 +1,5 @@
+SELECT c_city, s_city, year(lo_orderdate) AS year, sum(lo_revenue) AS revenue
+FROM lineorder_flat
+WHERE c_nation = 'UNITED STATES' AND s_nation = 'UNITED STATES' AND lo_orderdate >= '1992-01-01' AND lo_orderdate <= '1997-12-31'
+GROUP BY c_city, s_city, year
+ORDER BY year ASC, revenue DESC

@@ -14,6 +14,17 @@ one, and the next run compiles once at it — runtime/executor.py `_adaptive`);
 the last send must compile nothing. Every answer is compared with
 tests/tpch_oracle.py after the sends, by tests/test_tpch_sql.py's tolerance.
 
+    python chip_smoke.py --suite ssb_flat [--sf 100]
+
+runs upstream's 13 SSB flat-table statements instead (`benchmarks/statements/
+ssb_flat/`, in upstream's text, over one chip's share of `lineorder_flat` from
+`benchmarks/datagen/ssb_flat.py`, against `benchmarks/oracles/ssb_flat/`), the
+same way through the MySQL door, and prints per statement its program's
+name, `capacities`, `compactions`, `segment_sums`, and from a `jax.profiler`
+trace of one more send the device time of each scope (`sr.agg.2/datepart/year`,
+`sr.agg.2/compact/gather`, ...), so that a slow statement is named by scope
+and phase before a benchmark run is spent on it.
+
 Exits non-zero — with no JSON line — unless `jax.default_backend()` is
 "tpu"; no flag admits a CPU. Also non-zero on any mismatch, exception,
 missing counter, compile in a last send, off-device column or missing
@@ -34,6 +45,7 @@ import faulthandler
 import http.client
 import json
 import math
+import numbers
 import os
 import re
 import sys
@@ -123,6 +135,16 @@ def _last_attempt_info(name: str) -> dict:
     return done[-1] if done else {}
 
 
+def _last_statement_info(name: str):
+    """The info `name` of the newest retained statement itself (`program`,
+    its XLA module's name)."""
+    from starrocks_tpu.runtime.profile import PROFILE_MANAGER
+
+    entries = PROFILE_MANAGER.snapshot()
+    return ((entries[-1]["profile"] or {}).get("infos", {}).get(name)
+            if entries else None)
+
+
 def _probe_shrinks(programs: dict, fid: int) -> list:
     """The keys `shrink_<n>l` among the compactions of fragment program
     `_f<fid>`: the probe sides of its joins that ran at their live rows."""
@@ -195,6 +217,176 @@ def _check_collectives(chips: int, seed: int) -> list:
     return failures
 
 
+def _send_until_warm(name: str, send, failures: list) -> tuple:
+    """Send a statement twice, and a third time only if the second send
+    still compiled; the last send must compile nothing. Returns (the sends'
+    records, the rows of the last)."""
+    from starrocks_tpu.runtime.metrics import PROGRAM_COMPILES, RECOMPILES
+
+    sends, rows = [], None
+    while len(sends) < 2 or (sends[-1]["compiles"] and len(sends) < MAX_SENDS):
+        c0, r0 = PROGRAM_COMPILES.value, RECOMPILES.value
+        t0 = time.monotonic()
+        got = send()
+        sends.append({
+            "seconds": round(time.monotonic() - t0, 3),
+            "compiles": int(PROGRAM_COMPILES.value - c0),
+            "recompiles": int(RECOMPILES.value - r0)})
+        if rows is not None and got != rows:
+            failures.append(f"{name}: send {len(sends)} returned other rows "
+                            "than send 1")
+        rows = got
+        print(f"sent {name} #{len(sends)} rows={len(got)} "
+              f"{json.dumps(sends[-1])}")
+    if sends[-1]["compiles"] or sends[-1]["recompiles"]:
+        failures.append(f"{name}: send {len(sends)} still compiled "
+                        f"({sends[-1]['compiles']} programs)")
+    return sends, rows
+
+
+def _scope_path(tf_op) -> str:
+    """`sr.agg.2/datepart/year` of `jit(q_..)/sr.sort.0/sr.agg.2/datepart/
+    year/floor_divide:`: the name stack from the innermost plan node down,
+    less the primitive."""
+    stack = (tf_op or "").rsplit(":", 1)[0].split("/")
+    inner = max((i for i, c in enumerate(stack) if c.startswith("sr.")),
+                default=None)
+    if inner is None:
+        return "(no sr scope)"
+    return "/".join(c for c in stack[inner:-1] if not c.startswith("jit("))
+
+
+def _traced_scopes(send) -> dict:
+    """{scope path: device self seconds} of one `send()` under
+    `jax.profiler`; {} on a backend whose trace holds no device plane."""
+    import tempfile
+
+    import jax
+
+    from benchmarks.harness import scopes, xplane
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        try:
+            send()
+        finally:
+            jax.profiler.stop_trace()
+        devices = scopes.read_ops(xplane.find_xplane(trace_dir))
+    totals: dict = {}
+    for ops in devices.values():
+        events = [(_scope_path(tf_op), start, end)
+                  for _, tf_op, _, start, end in ops]
+        for scope, self_ps in xplane._self_times(events):
+            totals[scope] = totals.get(scope, 0.0) + self_ps / 1e12
+    return totals
+
+
+def run_ssb_flat(sf: float, seed: int) -> dict:
+    """The 13 SSB flat-table statements through the MySQL door, on whatever
+    backend JAX has (main() lets only a TPU through; tier-1 calls this at a
+    small `sf` on CPU). Returns {"ok", "failures", "device", "statements"}."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    sys.path.insert(0, os.path.join(REPO, "tests"))
+    from test_mysql_protocol import FullClient
+
+    from benchmarks.harness import cells, compare
+    from starrocks_tpu.runtime.mysql_service import MySQLServer
+    from starrocks_tpu.runtime.serving import ServingTier
+    from starrocks_tpu.runtime.session import Session
+    from starrocks_tpu.storage.catalog import Catalog
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
+    print(f"versions {json.dumps(_versions())}")
+    print(f"device {json.dumps(device)} suite=ssb_flat sf={sf:g} seed={seed} "
+          f"compile_cache={jax.config.jax_compilation_cache_dir}")
+    flat = "ssb_flat_sf100_share.cycle13x10"
+    cell = cells.Cell(REPO, flat)
+    t0 = time.monotonic()
+    gen = cells.load_module(REPO, "datagen", cell.config["generator"])
+    tables = gen.generate(sf, seed)
+    print(f"generated lineorder_flat_rows="
+          f"{tables['lineorder_flat'].num_rows} "
+          f"seconds={time.monotonic() - t0:.1f}")
+    catalog = Catalog()
+    for name, table in tables.items():
+        catalog.register(name, table, gen.UNIQUE_KEYS.get(name, ()),
+                         gen.DISTRIBUTION.get(name, ()))
+    session = Session(catalog)
+    my = MySQLServer(session, port=0, tier=ServingTier(session)).start()
+    mysql = FullClient("127.0.0.1", my.port)
+    mysql.sock.settimeout(1100)
+
+    failures: list = []
+    statements = []
+    try:
+        for v in cell.variants:
+            name = v["template"]
+
+            def send(sql=v["sql"]):
+                return mysql.query(sql)[1]
+
+            sends, rows = _send_until_warm(name, send, failures)
+            record = {"statement": name, "sends": sends,
+                      "program": _last_statement_info("program"),
+                      "capacities": _last_attempt_info("capacities"),
+                      "compactions": _last_attempt_info("compactions"),
+                      "segment_sums": _last_attempt_info("segment_sums")}
+            for key in ("program", "capacities", "compactions",
+                        "segment_sums"):
+                print(f"{key} {name} {json.dumps(record[key])}")
+            record["scopes"] = _traced_scopes(send)
+            for scope, sec in sorted(record["scopes"].items(),
+                                     key=lambda kv: -kv[1])[:8]:
+                print(f"scope {name} {scope} self_ms={sec * 1e3:.3f}")
+            print(f"device_ms {name} "
+                  f"{sum(record['scopes'].values()) * 1e3:.3f}")
+            statements.append((record, v, rows))
+        for d in devices[:1]:
+            print(f"memory device={d.id} stats={json.dumps(d.memory_stats())}")
+        resident = sum(a.nbytes for _, a in session.cache.resident_arrays())
+        print(f"resident bytes={resident}")
+    finally:
+        mysql.sock.close()
+        my.shutdown()
+
+    t0 = time.monotonic()
+    frames = compare.frames(tables, compare.union_columns(
+        [t["oracle"].COLUMNS for t in cell.templates]))
+    for record, v, rows in statements:
+        expected = v["oracle"].expected(frames)
+        diff = compare.first_mismatch(rows, expected, v["oracle"].KEY)
+        record["oracle_match"] = diff is None
+        if diff is not None:
+            failures.append(f"{record['statement']}: {diff}")
+        # the configuration promises exact integer sums and `first_mismatch`
+        # lets 1e-6 through: integers are also held to equality here
+        inexact = [(g, e) for got, exp in zip(
+            rows, expected.itertuples(index=False)) for g, e in zip(got, exp)
+            if isinstance(e, numbers.Integral) and int(g) != int(e)]
+        record["integers_equal"] = diff is None and not inexact
+        if diff is None and inexact:
+            failures.append(f"{record['statement']}: integer {inexact[0][0]}, "
+                            f"reference has {inexact[0][1]}")
+        print(f"statement {record['statement']} rows={len(expected)} "
+              f"oracle_match={record['oracle_match']} "
+              f"integers_equal={record['integers_equal']} "
+              f"first_run_s={record['sends'][0]['seconds']} "
+              f"sends={len(record['sends'])} "
+              f"last_run_s={record['sends'][-1]['seconds']}")
+    print(f"oracle seconds={time.monotonic() - t0:.1f}")
+    for f in failures:
+        print(f"FAILED {f}")
+    return {"ok": not failures, "failures": failures, "device": device,
+            "sf": sf, "seed": seed,
+            "statements": [record for record, *_ in statements]}
+
+
 def _versions() -> dict:
     import importlib.metadata as md
 
@@ -221,7 +413,6 @@ def run(sf: float, chips: int, seed: int) -> dict:
     from tpch_queries import QUERIES
 
     from starrocks_tpu.runtime.http_service import SqlHttpServer
-    from starrocks_tpu.runtime.metrics import PROGRAM_COMPILES, RECOMPILES
     from starrocks_tpu.runtime.mysql_service import MySQLServer
     from starrocks_tpu.runtime.serving import ServingTier
     from starrocks_tpu.runtime.session import Session
@@ -271,28 +462,10 @@ def run(sf: float, chips: int, seed: int) -> dict:
     try:
         for door, qid, key in STATEMENTS:
             name = f"{door}:q{qid}"
-            sends, rows = [], None
-            while len(sends) < 2 or (sends[-1]["compiles"]
-                                     and len(sends) < MAX_SENDS):
-                c0, r0 = PROGRAM_COMPILES.value, RECOMPILES.value
-                t0 = time.monotonic()
-                got = send(door, QUERIES[qid])
-                sends.append({
-                    "seconds": round(time.monotonic() - t0, 3),
-                    "compiles": int(PROGRAM_COMPILES.value - c0),
-                    "recompiles": int(RECOMPILES.value - r0)})
-                if rows is not None and got != rows:
-                    failures.append(f"{name}: send {len(sends)} returned "
-                                    "other rows than send 1")
-                rows = got
-                print(f"sent {name} #{len(sends)} rows={len(got)} "
-                      f"{json.dumps(sends[-1])}")
+            sends, rows = _send_until_warm(
+                name, lambda: send(door, QUERIES[qid]), failures)
             if qid not in seen and not sends[0]["compiles"]:
                 failures.append(f"{name}: first send compiled no program")
-            if sends[-1]["compiles"] or sends[-1]["recompiles"]:
-                failures.append(
-                    f"{name}: send {len(sends)} still compiled "
-                    f"({sends[-1]['compiles']} programs)")
             seen.add(qid)
             record = {"statement": name, "sends": sends}
             done = _last_attempt_info("compactions")
@@ -404,8 +577,12 @@ def main() -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 = the same statements through "
                          "Session(dist_shards=4) on a four-chip host")
-    ap.add_argument("--sf", type=float, default=10.0,
-                    help="TPC-H scale factor (the deployment is SF10)")
+    ap.add_argument("--suite", default="tpch", choices=("tpch", "ssb_flat"),
+                    help="ssb_flat = upstream's 13 flat-table statements "
+                         "over one chip's share of lineorder_flat")
+    ap.add_argument("--sf", type=float, default=None,
+                    help="scale factor (the deployments: TPC-H SF10, "
+                         "SSB-flat SF100's share)")
     ap.add_argument("--seed", type=int, default=42)
     args = ap.parse_args()
 
@@ -420,7 +597,11 @@ def main() -> int:
         return 1
     # a hung chip becomes a traceback and a non-zero exit inside the limit
     faulthandler.dump_traceback_later(1170, exit=True)
-    res = run(args.sf, args.chips, args.seed)
+    if args.suite == "ssb_flat":
+        res = run_ssb_flat(100.0 if args.sf is None else args.sf, args.seed)
+    else:
+        res = run(10.0 if args.sf is None else args.sf, args.chips,
+                  args.seed)
     if not res["ok"]:
         return 1
     print(json.dumps({"ok": True, "device": res["device"]}))

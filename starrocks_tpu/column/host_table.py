@@ -237,13 +237,20 @@ class HostTable:
     @classmethod
     def from_chunk(cls, chunk: Chunk) -> "HostTable":
         """Pull a device chunk back to host, dropping dead rows."""
-        sel = np.asarray(chunk.sel_mask())
+        import jax
+
+        # one round of transfers, all started before the first is awaited:
+        # an array at a time, a one-row answer crossed to the host three
+        # times in turn
+        sel, data, valid = jax.device_get(
+            (chunk.sel, list(chunk.data), list(chunk.valid)))
+        # either way a fresh array the table owns
+        live = np.array if sel is None else (lambda a: np.asarray(a)[sel])
         arrays, valids = {}, {}
-        for i, f in enumerate(chunk.schema.fields):
-            a = np.asarray(chunk.data[i])[sel]
-            arrays[f.name] = a
-            if chunk.valid[i] is not None:
-                valids[f.name] = np.asarray(chunk.valid[i])[sel]
+        for f, a, v in zip(chunk.schema.fields, data, valid):
+            arrays[f.name] = live(a)
+            if v is not None:
+                valids[f.name] = live(v)
         return cls(chunk.schema, arrays, valids)
 
     # --- result materialization --------------------------------------------

@@ -30,9 +30,11 @@ from __future__ import annotations
 import dataclasses
 import datetime
 import fnmatch
+import functools
 import re
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -436,12 +438,30 @@ def _common_valued(a: T.LogicalType, b: T.LogicalType) -> T.LogicalType:
 _FUNCTIONS = {}
 
 
-def function(name):
+def function(name, scope=None):
+    """Registers a builtin. With `scope`, its operations carry the name
+    `<scope>/<name>` under their plan node's (`sr.project.2/datepart/year`),
+    so a device trace says what of a filter, projection or aggregate is that
+    builtin's arithmetic; `f` itself stays callable without it."""
+
     def deco(f):
-        _FUNCTIONS[name] = f
+        if scope is None:
+            _FUNCTIONS[name] = f
+            return f
+
+        @functools.wraps(f)
+        def scoped(cc, *args):
+            with jax.named_scope(f"{scope}/{name}"):
+                return f(cc, *args)
+
+        _FUNCTIONS[name] = scoped
         return f
 
     return deco
+
+
+# calendar fields of a date: civil-from-days arithmetic, a row at a time
+DATE_PART = "datepart"
 
 
 def _binary_numeric(cc: ExprCompiler, a: EVal, b: EVal, op, scale_rule):
@@ -840,8 +860,14 @@ def _f_if(cc, c, a, b):
 # civil-from-days (Howard Hinnant's algorithm), vectorized over int32 days.
 
 
-def _civil_from_days(days):
-    z = jnp.asarray(days, jnp.int64) + 719_468
+def _civil_from_days(days, dtype=jnp.int64):
+    """(year, month, day) of days since 1970. `dtype` int32 is exact for
+    every DATE (the days of years 0..9999 shifted to 0000-03-01 stay under
+    2^22 and every product below under 2^25) and is what the date parts of
+    a column pass: in int64 a TPU emulates each of the dozen divisions
+    (weekofyear over 75M rows: 374 ms on a v5e and 581 s of XLA compile,
+    PR 32). The default keeps the other callers' programs as they were."""
+    z = jnp.asarray(days, dtype) + 719_468
     era = jnp.where(z >= 0, z, z - 146_096) // 146_097
     doe = z - era * 146_097
     yoe = (doe - doe // 1460 + doe // 36_524 - doe // 146_096) // 365
@@ -882,27 +908,27 @@ def _date_bounds_days(a: EVal):
     return None
 
 
-@function("year")
+@function("year", scope=DATE_PART)
 def _f_year(cc, a):
     a = _lit_as_date_if_str(a)
-    y, m, d = _civil_from_days(_as_days(a))
+    y, m, d = _civil_from_days(_as_days(a), jnp.int32)
     db = _date_bounds_days(a)
     yb = ((_py_year_of_days(db[0]), _py_year_of_days(db[1]))
           if db is not None else None)
     return EVal(y, a.valid, T.INT, bounds=yb)
 
 
-@function("month")
+@function("month", scope=DATE_PART)
 def _f_month(cc, a):
     a = _lit_as_date_if_str(a)
-    y, m, d = _civil_from_days(_as_days(a))
+    y, m, d = _civil_from_days(_as_days(a), jnp.int32)
     return EVal(m, a.valid, T.INT, bounds=(1, 12))
 
 
-@function("day")
+@function("day", scope=DATE_PART)
 def _f_day(cc, a):
     a = _lit_as_date_if_str(a)
-    y, m, d = _civil_from_days(_as_days(a))
+    y, m, d = _civil_from_days(_as_days(a), jnp.int32)
     return EVal(d, a.valid, T.INT, bounds=(1, 31))
 
 
@@ -916,8 +942,8 @@ def _f_date_add_days(cc, a, n):
     )
 
 
-def _days_from_civil(y, m, d):
-    yy = jnp.asarray(y, jnp.int64) - jnp.asarray(m <= 2, jnp.int64)
+def _days_from_civil(y, m, d, dtype=jnp.int64):
+    yy = jnp.asarray(y, dtype) - jnp.asarray(m <= 2, dtype)
     era = jnp.where(yy >= 0, yy, yy - 399) // 400
     yoe = yy - era * 400
     mp = jnp.where(m > 2, m - 3, m + 9)
@@ -1195,7 +1221,7 @@ def _f_datediff(cc, a, b):
     )
 
 
-@function("dayofweek")
+@function("dayofweek", scope=DATE_PART)
 def _f_dayofweek(cc, a):
     a = _lit_as_date_if_str(a)
     # 1970-01-01 was a Thursday; SQL convention: 1=Sunday .. 7=Saturday
@@ -1203,7 +1229,7 @@ def _f_dayofweek(cc, a):
     return EVal(((days + 4) % 7 + 1).astype(jnp.int32), a.valid, T.INT)
 
 
-@function("quarter")
+@function("quarter", scope=DATE_PART)
 def _f_quarter(cc, a):
     a = _lit_as_date_if_str(a)
     y, m, d = _civil_from_days(_as_days(a))

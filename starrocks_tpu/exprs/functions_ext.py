@@ -28,9 +28,9 @@ import numpy as np
 from .. import types as T
 from ..column.dict_encoding import StringDict
 from .compile import (
-    EVal, _and_valid, _as_days, _civil_from_days, _common, _days_from_civil,
-    _lit_as_date_if_str, _string_bool_fn, _string_map_fn, _to_numeric,
-    function,
+    DATE_PART, EVal, _and_valid, _as_days, _civil_from_days, _common,
+    _days_from_civil, _lit_as_date_if_str, _string_bool_fn, _string_map_fn,
+    _to_numeric, function,
 )
 
 
@@ -218,14 +218,14 @@ def _dt_us(v: EVal):
     raise TypeError(f"expected date/datetime, got {v.type}")
 
 
-@function("dayofmonth")
+@function("dayofmonth", scope=DATE_PART)
 def _f_dayofmonth(cc, a):
     from .compile import _f_day
 
     return _f_day(cc, a)
 
 
-@function("dayofyear")
+@function("dayofyear", scope=DATE_PART)
 def _f_dayofyear(cc, a):
     a = _lit_as_date_if_str(a)
     days = _as_days(a)
@@ -234,21 +234,21 @@ def _f_dayofyear(cc, a):
     return EVal(jnp.asarray(days - jan1 + 1, jnp.int32), a.valid, T.INT)
 
 
-@function("weekofyear")
+@function("weekofyear", scope=DATE_PART)
 def _f_weekofyear(cc, a):
     """ISO 8601 week number (the reference's week(d, 3) mode)."""
     a = _lit_as_date_if_str(a)
-    days = jnp.asarray(_as_days(a), jnp.int64)
+    days = jnp.asarray(_as_days(a), jnp.int32)
     # ISO: week of the Thursday of this week
     dow = (days + 3) % 7  # 0 = Monday
     thursday = days - dow + 3
-    y, m, d = _civil_from_days(thursday)
-    jan1 = _days_from_civil(y, jnp.ones_like(m), jnp.ones_like(d))
+    y, m, d = _civil_from_days(thursday, jnp.int32)
+    jan1 = _days_from_civil(y, jnp.ones_like(m), jnp.ones_like(d), jnp.int32)
     return EVal(jnp.asarray((thursday - jan1) // 7 + 1, jnp.int32), a.valid,
                 T.INT)
 
 
-function("week")(_f_weekofyear)
+function("week", scope=DATE_PART)(_f_weekofyear)
 
 
 @function("hour")

@@ -30,9 +30,9 @@ import numpy as np
 from .. import types as T
 from ..column.dict_encoding import StringDict
 from .compile import (
-    EVal, _and_valid, _as_days, _civil_from_days, _common, _days_from_civil,
-    _lit_as_date_if_str, _string_bool_fn, _string_map_fn, _to_numeric,
-    function,
+    DATE_PART, EVal, _and_valid, _as_days, _civil_from_days, _common,
+    _days_from_civil, _lit_as_date_if_str, _string_bool_fn, _string_map_fn,
+    _to_numeric, function,
 )
 from .functions_ext import _lit_str, _string_int_fn, _unary_double
 
@@ -252,7 +252,7 @@ def _f_week_of_year(cc, a):
     return cc.call("weekofyear", a)
 
 
-@function("yearweek")
+@function("yearweek", scope=DATE_PART)
 def _f_yearweek(cc, a):
     """ISO pair: the year of the week's Thursday x 100 + ISO week (keeps
     year boundaries consistent with weekofyear — late-December dates in ISO

@@ -14,8 +14,8 @@ import numpy as np
 from .. import types as T
 from ..column.dict_encoding import StringDict
 from .compile import (
-    EVal, _and_valid, _as_days, _days_from_civil, _string_bool_fn,
-    _string_map_fn, function,
+    DATE_PART, EVal, _and_valid, _as_days, _days_from_civil,
+    _string_bool_fn, _string_map_fn, function,
 )
 from .functions_ext import _lit_str, _string_int_fn
 from .functions_wave3 import _const_str, _json_get, _rand_impl
@@ -395,7 +395,7 @@ def _f_hour_from_unixtime(cc, a):
     return EVal((secs // 3600) % 24, a.valid, T.BIGINT)
 
 
-@function("week_iso")
+@function("week_iso", scope=DATE_PART)
 def _f_week_iso(cc, a):
     """ISO-8601 week number via the Thursday rule (the week containing the
     year's first Thursday is week 1)."""

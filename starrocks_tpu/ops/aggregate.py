@@ -160,6 +160,16 @@ def bounded_domain(chunk: Chunk, group_by) -> Optional[int]:
     return total
 
 
+# chunks a compaction wrote, or larger. Not a width rule: below it an int32
+# key wins too, but packing every key that fits moves the lowered text of
+# the mesh's Q1 (digest `mesh_q1_f1`, a fragment program of the cell
+# `tpch_sf10_x4.join`) and of one-chip Q3 at the tests' scale (`one_chip_q3`;
+# tests/data/lowered_before_mesh_compaction.json), and the old cells'
+# programs were not PR 32's to move. Whoever may move them deletes this
+# constant and its test.
+NARROW_SORT_KEY_ROWS = 1 << 13
+
+
 def _mixed_radix_pack(keys, live, total_limit: int, out_dtype):
     """THE single mixed-radix key packer (null -> extra code past the
     domain, dead rows -> `total`, which sorts/indexes past every live
@@ -191,13 +201,23 @@ def _mixed_radix_pack(keys, live, total_limit: int, out_dtype):
 
 
 def _packed_sort_codes(keys, live):
-    """One int64 mixed-radix code per row packing ALL bounded group keys
-    (dead rows -> a sentinel that sorts last), or None when a key is
-    unbounded or the domain product overflows 2^62. The sort-path agg then
-    argsorts ONE int64 instead of lexsorting k arrays + validity masks —
-    the multi-key comparator is the lexsort path's dominant cost (TPC-H
-    Q16's 4-key distinct level, Q13's 2-key histogram)."""
-    out = _mixed_radix_pack(keys, live, 1 << 62, jnp.int64)
+    """One mixed-radix code per row packing ALL bounded group keys (dead
+    rows -> a sentinel that sorts last), or None when a key is unbounded or
+    the domain product overflows 2^62. The sort-path agg then argsorts ONE
+    key instead of lexsorting k arrays + validity masks — the multi-key
+    comparator is the lexsort path's dominant cost (TPC-H Q16's 4-key
+    distinct level, Q13's 2-key histogram). The code is an int32 where the
+    domain fits one (SSB's year x brand: 7,000) and the chunk is large
+    enough for the key's width to matter: a TPU sorts an int64 key as two
+    u32 operands, and XLA took 81 s to compile that argsort at 901,120 rows
+    against 35 s for the int32 one, and at 17,408 rows still ~45 s against
+    ~12 (PR 32, v5e described). Under NARROW_SORT_KEY_ROWS, the floor of a
+    compaction's output (`sql/physical.shrink_capacity`), a chunk's program
+    stays as it was."""
+    out = None
+    if live.shape[0] >= NARROW_SORT_KEY_ROWS:
+        out = _mixed_radix_pack(keys, live, (1 << 31) - 1, jnp.int32)
+    out = out or _mixed_radix_pack(keys, live, 1 << 62, jnp.int64)
     return None if out is None else out[0]
 
 
@@ -738,7 +758,7 @@ def hash_aggregate(
             pk_s = packed[order]
             live_s = live[order]
             prev = jnp.concatenate(
-                [jnp.full((1,), -1, jnp.int64), pk_s[:-1]])
+                [jnp.full((1,), -1, pk_s.dtype), pk_s[:-1]])
             is_new = live_s & (pk_s != prev)
         else:
             with phase("lexsort"):

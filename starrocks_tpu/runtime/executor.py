@@ -37,7 +37,7 @@ from . import lifecycle
 from .config import config
 from .failpoint import fail_point
 from .metrics import (PROGRAM_COMPILES, QUERIES_TOTAL, QUERY_ERRORS,
-                      RECOMPILES, ROWS_RETURNED, metrics)
+                      RECOMPILES, ROWS_RETURNED, count_compactions, metrics)
 from .profile import RuntimeProfile
 
 COMPILE_MS = metrics.histogram(
@@ -48,6 +48,11 @@ COMPILE_MS = metrics.histogram(
 
 class ExecError(RuntimeError):
     pass
+
+
+# a result of at most this many bytes starts for the host as soon as its
+# program has run; a larger one crosses when `fetch_results` asks for it
+PREFETCH_RESULT_BYTES = 1 << 20
 
 
 # Compile apart from first run, without changing how programs run: JAX
@@ -1141,6 +1146,7 @@ class Executor:
             prev_counts.update(keyed_checks)
             if not overflow:
                 profile.add_counter("recompiles", attempt)
+                count_compactions(p.infos.get("compactions") or {})
                 for k, v in ctrs:  # only the surviving attempt's counters
                     base, _, o = k[len("~ctr_"):].partition("@")
                     profile.add_counter(base, int(v))
@@ -1276,7 +1282,16 @@ class Executor:
             facts = self._program_facts(
                 self.cache.program_bucket(("local", plan)), caps,
                 trace_box.pop("facts", None))
-            keyed = [(k, int(v)) for k, v in checks.items()]
+            # the checks cross to the host in one round (an `int()` each
+            # waited for a transfer each: four checks, four rounds), and an
+            # answer a client reads at once starts its own crossing beside
+            # them, so that `fetch_results` finds it there
+            leaves = jax.tree_util.tree_leaves((out.sel, out.data, out.valid))
+            if sum(a.nbytes for a in leaves) <= PREFETCH_RESULT_BYTES:
+                for a in leaves:
+                    if isinstance(a, jax.Array):
+                        a.copy_to_host_async()
+            keyed = [(k, int(v)) for k, v in jax.device_get(checks).items()]
             if facts.get("compactions"):
                 p.set_info("compactions", self._compactions_with_live(
                     facts["compactions"], dict(keyed)))

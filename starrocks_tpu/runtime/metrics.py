@@ -219,6 +219,35 @@ EXCHANGE_BYTES = metrics.counter(
     "bytes one shard put on the interconnect for them: data and validity "
     "columns and the live mask, to the n-1 other shards")
 
+# Compactions (`ops/common.compact`: a chunk shrunk to its live rows before a
+# join, an aggregate or a sort), counted on the host once per statement from
+# the program that ran — the static shapes its trace logged and the row
+# count the surviving attempt's overflow check brought back
+# (runtime/executor.py `_adaptive`; the attempt info `compactions`) — cached
+# programs too. On a mesh the shapes are a shard's and the rows the fullest
+# shard's. live / slots_out is the fill, slots_out / rows_in the shrink.
+COMPACTIONS = metrics.counter(
+    "sr_tpu_compactions_total",
+    "compactions in the programs that ran")
+COMPACT_ROWS_IN = metrics.counter(
+    "sr_tpu_compact_rows_in_total",
+    "slots of the chunks they read")
+COMPACT_SLOTS_OUT = metrics.counter(
+    "sr_tpu_compact_slots_out_total",
+    "slots of the chunks they wrote")
+COMPACT_ROWS_LIVE = metrics.counter(
+    "sr_tpu_compact_rows_live_total",
+    "live rows they kept, where an overflow check counted them")
+
+
+def count_compactions(done: dict):
+    """`done`: an attempt's `compactions` info, capacity key -> `cap`,
+    `out_cap`, and `live` where the compaction has a check."""
+    COMPACTIONS.inc(len(done))
+    COMPACT_ROWS_IN.inc(sum(c["cap"] for c in done.values()))
+    COMPACT_SLOTS_OUT.inc(sum(c["out_cap"] for c in done.values()))
+    COMPACT_ROWS_LIVE.inc(sum(c.get("live", 0) for c in done.values()))
+
 
 class MetricsHistory:
     """Fixed-capacity time-series ring over the registry: each sample

@@ -110,12 +110,27 @@ def lenenc_str(s: bytes) -> bytes:
 class _Conn:
     """One client connection: packet framing + protocol state."""
 
+    # a response leaves in writes of at most this many bytes
+    FLUSH_BYTES = 1 << 20
+
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.seq = 0
+        sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        # the packets of the response in the making. A resultset is column
+        # definitions, rows and EOFs, a packet each: written one by one, a
+        # one-row answer was five writes and five wake-ups of the client
+        self._out = bytearray()
+
+    def flush(self):
+        if self._out:
+            out, self._out = self._out, bytearray()
+            self.sock.sendall(out)
 
     # packet = 3-byte little-endian length, 1-byte sequence id, payload
     def read_packet(self) -> bytes:
+        # the server turns to listen: what it has said goes out first
+        self.flush()
         head = self._read_n(4)
         if head is None:
             return None
@@ -136,10 +151,12 @@ class _Conn:
         # 16MB+ payloads would need continuation packets; result rows are
         # emitted one packet per row so only a single enormous cell hits this
         assert len(payload) < 0xFFFFFF, "oversized packet"
-        self.sock.sendall(
-            struct.pack("<I", len(payload))[:3] + bytes([self.seq]) + payload
-        )
+        self._out += struct.pack("<I", len(payload))[:3]
+        self._out.append(self.seq)
+        self._out += payload
         self.seq = (self.seq + 1) & 0xFF
+        if len(self._out) >= self.FLUSH_BYTES:
+            self.flush()
 
     # --- composite packets ---
     def send_handshake(self, thread_id: int, salt: bytes):
@@ -299,9 +316,18 @@ class MySQLServer:
         return user
 
     def _serve(self, sock: socket.socket):
+        conn = _Conn(sock)
+        try:
+            self._converse(conn)
+        finally:
+            try:
+                conn.flush()  # the last word (an ERR before hanging up)
+            except OSError:
+                pass  # the client hung up first
+
+    def _converse(self, conn: _Conn):
         from .auth import AuthManager
 
-        conn = _Conn(sock)
         salt = AuthManager.new_salt()
         conn.send_handshake(next(self._thread_ids), salt)
         user = self._authenticate(conn, salt)

@@ -69,12 +69,13 @@ class Scope:
 
     def resolve(self, table: Optional[str], name: str):
         """Returns (qualified_name, depth) — depth>0 means outer (correlated)."""
-        hits = []
-        for alias, cols in self.entries:
-            if table is not None and alias != table:
-                continue
-            if name in cols:
-                hits.append(f"{alias}.{name}")
+        hits = self._hits(table, name, lambda a, b: a == b)
+        if not hits:
+            # names resolve without regard to case, as MySQL's and
+            # StarRocks' do (a DDL in capitals, statements in lower case);
+            # the spelling as written wins where both exist
+            hits = self._hits(table, name,
+                              lambda a, b: a.lower() == b.lower())
         if len(hits) > 1:
             raise AnalyzerError(f"ambiguous column {name!r}: {hits}")
         if hits:
@@ -85,6 +86,11 @@ class Scope:
         raise AnalyzerError(
             f"unknown column {(table + '.') if table else ''}{name}"
         )
+
+    def _hits(self, table: Optional[str], name: str, same) -> list:
+        return [f"{alias}.{c}" for alias, cols in self.entries
+                if table is None or same(alias, table)
+                for c in cols if same(c, name)]
 
     def resolve_or_none(self, table: Optional[str], name: str):
         try:

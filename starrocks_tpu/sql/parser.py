@@ -878,12 +878,9 @@ class Parser:
             if t.value in ("true", "false"):
                 self.next()
                 return Lit(t.value == "true")
-            if t.value == "date":
+            if t.value == "date" and self.peek(1).kind == "string":
                 self.next()
-                s = self.next()
-                if s.kind != "string":
-                    raise ParseError("DATE literal expects a string")
-                return Lit(s.value, T.DATE)
+                return Lit(self.next().value, T.DATE)
             if t.value == "interval":
                 self.next()
                 v = self.next()
@@ -932,13 +929,10 @@ class Parser:
             self.expect_op(")")
             return e
         if t.kind == "ident" or (
-            t.kind == "kw"
-            and t.value in ("key", "primary", "update", "set", "delete",
-                            "truncate", "tables", "show", "first", "last",
-                            "view", "materialized", "refresh", "row", "rows",
-                            "range", "following", "unbounded", "preceding",
-                            "current")
-        ):
+                t.kind == "kw" and t.value in self.SOFT_KEYWORDS):
+            # a non-reserved word is a name wherever no syntax claims it:
+            # `year(d) AS year ... GROUP BY year` (the call form of the
+            # function-style ones was taken above, by the `(` after it)
             # func call / qualified col / bare col
             if self.peek(1).kind == "op" and self.peek(1).value == "(":
                 e = self.parse_func_call(self.next().value)

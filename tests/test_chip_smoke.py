@@ -55,3 +55,48 @@ def test_run_passes_on_cpu_at_small_scale():
     # scale its capacity is within the contraction's limit
     assert by_name["mysql:q3"]["segment_sums"]["sr.agg.2"][
         "formulation"] == "contract"
+
+
+def test_ssb_flat_suite_passes_on_cpu_at_small_scale():
+    """`--suite ssb_flat`: upstream's 13 flat-table statements, the
+    benchmark's own text against the benchmark's own references, through the
+    MySQL door; per statement the program's name, capacities, compactions
+    and segment sums (the scopes' device times need a device trace: a CPU
+    has none)."""
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    res = chip_smoke.run_ssb_flat(sf=0.05, seed=42)
+    assert res["ok"], res["failures"]
+    by_name = {s["statement"]: s for s in res["statements"]}
+    assert list(by_name) == [f"ssb_flat.q{a}.{b}" for a, n in
+                             ((1, 3), (2, 3), (3, 4), (4, 3))
+                             for b in range(1, n + 1)]
+    for s in by_name.values():
+        assert s["oracle_match"] and s["sends"][-1]["compiles"] == 0
+        # sums over integers: equal, not within the comparison's 1e-6
+        assert s["integers_equal"]
+        assert s["program"].startswith("q_") and s["scopes"] == {}
+    # Q1.x are one global sum and compact nothing; Q2.1 compacts the rows
+    # its filter keeps before year() and the GROUP BY see them
+    q1, q2 = by_name["ssb_flat.q1.1"], by_name["ssb_flat.q2.1"]
+    assert q1["compactions"] == {} and q1["segment_sums"]["sr.agg.1"][
+        "formulation"] == "global"
+    (shrink,) = q2["compactions"].values()
+    assert shrink["live"] <= shrink["out_cap"] < shrink["cap"]
+    assert q2["capacities"]["shrink_0"] == shrink["out_cap"]
+    assert q2["segment_sums"]["sr.agg.2"]["formulation"] == "contract"
+
+
+def test_scope_path_keeps_what_lies_under_the_plan_node():
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    assert chip_smoke._scope_path(
+        "jit(q_1a2b3c4d)/sr.sort.0/sr.agg.2/datepart/year/jit(floor_divide)"
+        "/div:") == "sr.agg.2/datepart/year"
+    assert chip_smoke._scope_path(
+        "jit(q_1a2b3c4d)/sr.agg.2/compact/gather/gather:"
+    ) == "sr.agg.2/compact/gather"
+    assert chip_smoke._scope_path("inputs[0][0][2]:") == "(no sr scope)"
+    assert chip_smoke._scope_path(None) == "(no sr scope)"

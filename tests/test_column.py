@@ -132,6 +132,37 @@ def test_host_table_roundtrip():
     assert df.shape == (5, 3)
 
 
+@pytest.mark.parametrize("selected", [True, False], ids=["sel", "no_sel"])
+def test_from_chunk_crosses_to_the_host_once_and_owns_its_arrays(
+        selected, monkeypatch):
+    """The live mask, the columns and their validity masks come back in one
+    `jax.device_get` (an array at a time, a one-row answer waited for three
+    transfers in turn: `fetch_ms` 2.6 on a v5e, PR 32), dead rows dropped,
+    the arrays the table's own whether the chunk had a mask or none."""
+    c = chunk_from_arrays(
+        Schema((Field("k", T.BIGINT), Field("v", T.DOUBLE, nullable=True))),
+        {"k": np.arange(4, dtype=np.int64),
+         "v": np.array([1.5, 0.0, 2.5, 3.5])},
+        {"v": np.array([True, False, True, True])})
+    cap = c.capacity
+    if selected:
+        c = c.with_sel(jnp.arange(cap) % 2 == 0)
+    else:
+        c = Chunk(c.schema, c.data, c.valid, None)
+    calls = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: calls.append(1) or real(x))
+    back = HostTable.from_chunk(c)
+    assert len(calls) == 1
+    assert back.num_rows == (cap // 2 if selected else cap)
+    rows = back.to_pylist()
+    assert rows[0] == (0, 1.5)
+    assert rows[1] == ((2, 2.5) if selected else (1, None))
+    for a in (*back.arrays.values(), *back.valids.values()):
+        assert a.flags.writeable and a.flags.owndata
+
+
 def test_host_table_decimal():
     ht = HostTable.from_pydict(
         {"price": [1.23, 4.56]}, types={"price": T.DECIMAL(15, 2)}

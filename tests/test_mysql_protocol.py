@@ -466,3 +466,73 @@ def test_prepared_execute_without_rebound_types(auth_server):
         if p[0] == 0xFE and len(p) < 9:
             break
     c.quit()
+
+
+# --- a response is one write (PR 32) -------------------------------------------
+
+class _RecordingSocket:
+    """What `_Conn` needs of a socket: it records every write and answers
+    reads from a script."""
+
+    def __init__(self, incoming: bytes = b""):
+        self.writes, self.incoming, self.options = [], incoming, []
+
+    def setsockopt(self, *option):
+        self.options.append(option)
+
+    def sendall(self, data):
+        self.writes.append(bytes(data))
+
+    def recv(self, n):
+        out, self.incoming = self.incoming[:n], self.incoming[n:]
+        return out
+
+
+def test_a_resultset_leaves_in_one_write_when_the_server_turns_to_read():
+    """Column count, definitions, EOF, rows, EOF: one packet each as the
+    protocol has them, one write in all (a write a packet woke the client
+    once a packet, and a small write behind an unacknowledged one waited for
+    the client's delayed ACK: 40 ms on an answer of a few hundred rows)."""
+    from starrocks_tpu import types as T
+    from starrocks_tpu.runtime.mysql_service import _Conn, lenenc_int
+
+    ping = b"\x01\x00\x00\x00\x0e"
+    sock = _RecordingSocket(ping)
+    conn = _Conn(sock)
+    assert (socket.IPPROTO_TCP, socket.TCP_NODELAY, 1) in sock.options
+    conn.seq = 1
+    conn.send_packet(lenenc_int(1))
+    conn.send_column_def("n", T.BIGINT)
+    conn.send_eof()
+    for n in range(300):
+        conn.send_packet(lenenc_int(len(str(n))) + str(n).encode())
+    conn.send_eof()
+    assert sock.writes == []
+    assert conn.read_packet() == b"\x0e"
+    (wire,) = sock.writes
+    # the packets are framed as before: lengths and sequence ids in order
+    pos, seqs, payloads = 0, [], []
+    while pos < len(wire):
+        (ln,) = struct.unpack("<I", wire[pos:pos + 3] + b"\x00")
+        seqs.append(wire[pos + 3])
+        payloads.append(wire[pos + 4:pos + 4 + ln])
+        pos += 4 + ln
+    assert len(payloads) == 304 and payloads[3] == b"\x010"
+    assert seqs == [(1 + i) & 0xFF for i in range(304)]
+    # nothing said since: turning to read again writes nothing
+    assert conn.read_packet() is None and len(sock.writes) == 1
+
+
+def test_a_long_resultset_leaves_in_bounded_writes():
+    from starrocks_tpu.runtime.mysql_service import _Conn
+
+    sock = _RecordingSocket()
+    conn = _Conn(sock)
+    row = b"x" * 1000
+    for _ in range(3000):
+        conn.send_packet(row)
+    conn.flush()
+    assert 2 <= len(sock.writes) <= 4
+    assert all(len(w) < _Conn.FLUSH_BYTES + 1100 for w in sock.writes)
+    assert sum(len(w) for w in sock.writes) == 3000 * 1004
+
