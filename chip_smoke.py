@@ -20,10 +20,10 @@ runs upstream's 13 SSB flat-table statements instead (`benchmarks/statements/
 ssb_flat/`, in upstream's text, over one chip's share of `lineorder_flat` from
 `benchmarks/datagen/ssb_flat.py`, against `benchmarks/oracles/ssb_flat/`), the
 same way through the MySQL door, and prints per statement its program's
-name, `capacities`, `compactions`, `segment_sums`, and from a `jax.profiler`
-trace of one more send the device time of each scope (`sr.agg.2/datepart/year`,
-`sr.agg.2/compact/gather`, ...), so that a slow statement is named by scope
-and phase before a benchmark run is spent on it.
+name, `capacities`, `compactions`, `segment_sums`, `dict_predicates`, and
+from a `jax.profiler` trace of one more send the device time of each scope
+(`sr.agg.2/datepart/year`, `sr.agg.2/compact/gather`, ...), so that a slow
+statement is named by scope and phase before a benchmark run is spent on it.
 
 Exits non-zero — with no JSON line — unless `jax.default_backend()` is
 "tpu"; no flag admits a CPU. Also non-zero on any mismatch, exception,
@@ -123,7 +123,9 @@ def _oracle_frames(catalog) -> dict:
 def _last_attempt_info(name: str) -> dict:
     """The info `name` of the newest retained statement's last attempt that
     has it: `compactions` ({} where its program compacts nothing),
-    `segment_sums` (an aggregate scope -> its batch of integer sums), and on
+    `segment_sums` (an aggregate scope -> its batch of integer sums),
+    `dict_predicates` (a plan-node scope -> its boolean predicates over
+    dictionary columns, `ranges` or `lut`), and on
     a mesh `programs`, module name -> that fragment program's compactions and
     exchanges."""
     from starrocks_tpu.runtime.profile import PROFILE_MANAGER
@@ -336,9 +338,11 @@ def run_ssb_flat(sf: float, seed: int) -> dict:
                       "program": _last_statement_info("program"),
                       "capacities": _last_attempt_info("capacities"),
                       "compactions": _last_attempt_info("compactions"),
-                      "segment_sums": _last_attempt_info("segment_sums")}
+                      "segment_sums": _last_attempt_info("segment_sums"),
+                      "dict_predicates": _last_attempt_info(
+                          "dict_predicates")}
             for key in ("program", "capacities", "compactions",
-                        "segment_sums"):
+                        "segment_sums", "dict_predicates"):
                 print(f"{key} {name} {json.dumps(record[key])}")
             record["scopes"] = _traced_scopes(send)
             for scope, sec in sorted(record["scopes"].items(),
@@ -474,13 +478,16 @@ def run(sf: float, chips: int, seed: int) -> dict:
                 # the program the last send ran
                 record["compactions"] = done
                 print(f"compactions {name} {json.dumps(done)}")
-            sums = _last_attempt_info("segment_sums")
-            if sums:
-                # per aggregate scope: rows, groups, integer columns handed
-                # in and summed, limbs made and the formulation of its one
-                # batch of segment sums
-                record["segment_sums"] = sums
-                print(f"segment_sums {name} {json.dumps(sums)}")
+            # per aggregate scope: rows, groups, integer columns handed in
+            # and summed, limbs made and the formulation of its one batch of
+            # segment sums; per plan-node scope: column, dictionary length,
+            # TRUE codes, runs and formulation of each predicate over a
+            # dictionary column
+            for info in ("segment_sums", "dict_predicates"):
+                found = _last_attempt_info(info)
+                if found:
+                    record[info] = found
+                    print(f"{info} {name} {json.dumps(found)}")
             programs = _last_attempt_info("programs")
             if programs:
                 # on a mesh: each fragment program the last send ran

@@ -18,8 +18,10 @@ must never be observed except through `valid`.
 String strategy (TPU-first): dictionaries are trace-time constants, so
 - comparisons against literals become integer code comparisons
   (sorted dicts make range predicates order-correct);
-- arbitrary string->bool functions (LIKE, regexp) become constant boolean
-  LUTs gathered per-row: lut[codes];
+- IN lists and arbitrary string->bool functions (LIKE, regexp) become a
+  constant boolean table over the dictionary, evaluated on the codes
+  (`dict_code_mask`): compares against its runs of consecutive TRUE codes,
+  or, for a set of many scattered codes, the table gathered per-row;
 - string->string functions become constant remap tables into a new dict.
 This is the reference's global low-cardinality dict rewrite
 (be/src/compute_env/global_dict/parser.h) promoted to the only string path.
@@ -27,6 +29,8 @@ This is the reference's global low-cardinality dict rewrite
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import datetime
 import fnmatch
@@ -54,6 +58,9 @@ class EVal:
     # static (lo, hi) value bounds known at trace time (from catalog stats),
     # propagated through a few closed-form functions; None = unbounded
     bounds: Optional[tuple] = None
+    # the chunk column this value IS (a bare `Col`), for the `dict_predicates`
+    # info; None for anything computed
+    col: Optional[str] = None
 
 
 def _and_valid(*valids):
@@ -259,6 +266,74 @@ def _common(a: EVal, b: EVal) -> T.LogicalType:
     return T.common_numeric_type(a.type, b.type)
 
 
+# --- predicates over a dictionary column -------------------------------------
+
+# A set of TRUE codes becomes compares on the codes while it has at most this
+# many runs of consecutive codes, and a boolean table gathered a row above.
+# `tools/dict_predicate_probe.py` on a v5e, 74,989,568 int32 codes (PERF.md
+# section 6, PR 33): the table gathered a row is 700 ms (9.3 ns a row) at every
+# dictionary length from 65 up; to 64 entries XLA's TPU compiler expands it into
+# a chain of selects itself (1.2 ms at 5 entries, 2.4 at 64), no faster than
+# the compares over the same dictionary. The compares read the column once:
+# 1.2 ms to 8 runs, 3.8 at 64, 7.0 at 128, 17.2 at 256, 29.7 at 512, 54.6 at
+# 1,024, so by time they win through the whole grid; what grows is the
+# compile, 0.3 s to 64 runs, 0.7 at 128, 1.4 at 256, then 5.5 at 512 and
+# 11.8 at 1,024. 256 is the last reading before that knee. The dictionary's
+# length decides nothing: a set cannot have more runs than half of it.
+RANGES_MAX_RUNS = 256
+
+# where `dict_code_mask` records what it did: (sink, plan-node scope), set by
+# the plan compilers around each node they emit (`dict_predicate_log`)
+_DICT_PREDICATE_LOG: contextvars.ContextVar = contextvars.ContextVar(
+    "dict_predicate_log", default=None)
+
+
+@contextlib.contextmanager
+def dict_predicate_log(sink: dict, scope: str):
+    """While open, every `dict_code_mask` traced on this thread appends what
+    it did to `sink[scope]`: a program's `dict_predicates` info."""
+    token = _DICT_PREDICATE_LOG.set((sink, scope))
+    try:
+        yield
+    finally:
+        _DICT_PREDICATE_LOG.reset(token)
+
+
+def dict_code_mask(codes, true: np.ndarray, column: str | None = None):
+    """Row mask of a boolean predicate over a dictionary column: `true[code]`
+    on every row whose code is in the dictionary (a NULL row carries any code
+    and gets any bit; its validity is the caller's).
+
+    `true` is the predicate over the dictionary, a trace-time constant. Split
+    into its maximal runs of consecutive TRUE codes `[lo, hi]` it is `ranges`:
+    the OR over the runs of `code == lo` or `(code >= lo) & (code <= hi)`,
+    int32 compares XLA fuses into the one pass over the column (a sorted
+    dictionary makes an IN list or a prefix LIKE a run or a few). A set of
+    more than RANGES_MAX_RUNS runs stays `lut`: the table gathered a row."""
+    codes = jnp.asarray(codes)
+    true = np.asarray(true, np.bool_)
+    edges = np.flatnonzero(np.diff(np.concatenate(
+        [[False], true, [False]]).astype(np.int8)))
+    runs = [(int(lo), int(hi) - 1) for lo, hi in zip(edges[::2], edges[1::2])]
+    if len(runs) <= RANGES_MAX_RUNS:
+        formulation = "ranges"
+        parts = [(codes == lo) if lo == hi else ((codes >= lo) & (codes <= hi))
+                 for lo, hi in runs]
+        m = (functools.reduce(jnp.logical_or, parts) if parts
+             else jnp.zeros(codes.shape, jnp.bool_))
+    else:
+        formulation = "lut"
+        m = jnp.asarray(true)[jnp.clip(codes, 0, len(true) - 1)]
+    log = _DICT_PREDICATE_LOG.get()
+    if log is not None:
+        sink, scope = log
+        sink.setdefault(scope, []).append({
+            "column": column, "dict": len(true),
+            "true_codes": int(true.sum()), "runs": len(runs),
+            "formulation": formulation})
+    return m
+
+
 # --- the compiler -----------------------------------------------------------
 
 
@@ -272,7 +347,8 @@ class ExprCompiler:
         if isinstance(e, Col):
             data, valid = self.chunk.col(e.name)
             f = self.chunk.field(e.name)
-            return EVal(data, valid, f.type, f.dict, bounds=f.bounds)
+            return EVal(data, valid, f.type, f.dict, bounds=f.bounds,
+                        col=e.name)
         if isinstance(e, Lit):
             hv, lt = _infer_lit(e.value, e.type)
             if lt.kind is T.TypeKind.NULL:
@@ -400,13 +476,9 @@ class ExprCompiler:
         elif v.type.is_string:
             codes = {v.dict.encode_one(str(x)) for x in values}
             codes.discard(-1)
-            if not codes:
-                m = jnp.zeros((cap,), jnp.bool_)
-            else:
-                lut = np.zeros((max(len(v.dict), 1),), dtype=np.bool_)
-                for c in sorted(codes):
-                    lut[c] = True
-                m = jnp.asarray(lut)[jnp.clip(v.data, 0, len(lut) - 1)]
+            true = np.zeros((len(v.dict),), dtype=np.bool_)
+            true[list(codes)] = True
+            m = dict_code_mask(v.data, true, v.col)
         else:
             m = jnp.zeros((cap,), jnp.bool_)
             for x in values:
@@ -976,12 +1048,8 @@ def _string_bool_fn(cc, a: EVal, pred) -> EVal:
     if a.dict is None and isinstance(a.data, str):
         return EVal(jnp.asarray(bool(pred(a.data))), a.valid, T.BOOLEAN)
     assert a.dict is not None, "string function needs a dict column"
-    lut = jnp.asarray(a.dict.lut(pred))
-    n = max(len(a.dict), 1)
-    m = lut[jnp.clip(a.data, 0, n - 1)] if len(a.dict) else jnp.zeros_like(
-        jnp.asarray(a.data), dtype=jnp.bool_
-    )
-    return EVal(m, a.valid, T.BOOLEAN)
+    return EVal(dict_code_mask(a.data, a.dict.lut(pred), a.col), a.valid,
+                T.BOOLEAN)
 
 
 def like_to_regex(pattern: str) -> str:

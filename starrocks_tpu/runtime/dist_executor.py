@@ -149,7 +149,8 @@ class DistExecutor(Executor):
             self.cache.program_bucket(key), "names", (name, compiled.scopes))
         return {"name": name, "compactions": compiled.compactions,
                 "exchanges": compiled.exchanges,
-                "segment_sums": compiled.segment_sums}
+                "segment_sums": compiled.segment_sums,
+                "dict_predicates": compiled.dict_predicates}
 
     def _ran(self, key, caps, fresh: dict | None) -> dict:
         """After a mesh program ran, compiled just now (`fresh`) or cached:
@@ -167,10 +168,11 @@ class DistExecutor(Executor):
         """The attempt's checks merged on the host, and on its profile what
         its programs' facts say: `n_shards`, `programs` (module name -> its
         compactions, each with the `live` rows its check counted, and its
-        exchanges), `compactions` and `segment_sums` (all of them, as on
-        one chip), and for every all_to_all `exchange_fill`, its fullest bucket
-        (the overflow check's value, on the host anyway) over the bucket's
-        capacity — skew and padding, read off a statement."""
+        exchanges), `compactions`, `segment_sums` and `dict_predicates` (all
+        of them, as on one chip), and for every all_to_all `exchange_fill`,
+        its fullest bucket (the overflow check's value, on the host anyway)
+        over the bucket's capacity — skew and padding, read off a
+        statement."""
         keyed = [(k, self._host_max(v)) for k, v in checks.items()]
         fullest = dict(keyed)
         p.set_info("n_shards", self.n)
@@ -184,10 +186,10 @@ class DistExecutor(Executor):
                 for k, c in holds["compactions"].items()}
         if done:
             p.set_info("compactions", done)
-        sums = {k: c for f in facts
-                for k, c in f.get("segment_sums", {}).items()}
-        if sums:
-            p.set_info("segment_sums", sums)
+        for info in ("segment_sums", "dict_predicates"):
+            found = {k: c for f in facts for k, c in f.get(info, {}).items()}
+            if found:
+                p.set_info(info, found)
         fill = {e["check"]: round(fullest[e["check"]]
                                   / caps.values[e["check"]], 4)
                 for f in facts for e in f.get("exchanges", ())

@@ -1242,7 +1242,8 @@ class Executor:
                 trace_box["node_ord"] = compiled.node_ord
                 trace_box["facts"] = {
                     "compactions": compiled.compactions,
-                    "segment_sums": compiled.segment_sums}
+                    "segment_sums": compiled.segment_sums,
+                    "dict_predicates": compiled.dict_predicates}
                 # the XLA module is named after the statement, not `run`
                 name = program_name(plan, fb_fp)
                 compiled.fn.__name__ = compiled.fn.__qualname__ = name
@@ -1278,7 +1279,8 @@ class Executor:
                 ("local", plan), caps, p, compile_cb, place_cb
             )
             # what this program's compactions were (rows in, slots out,
-            # index method) and its aggregates' batches of segment sums
+            # index method), its aggregates' batches of segment sums and
+            # its predicates over dictionary columns
             facts = self._program_facts(
                 self.cache.program_bucket(("local", plan)), caps,
                 trace_box.pop("facts", None))
@@ -1295,8 +1297,9 @@ class Executor:
             if facts.get("compactions"):
                 p.set_info("compactions", self._compactions_with_live(
                     facts["compactions"], dict(keyed)))
-            if facts.get("segment_sums"):
-                p.set_info("segment_sums", dict(facts["segment_sums"]))
+            for info in ("segment_sums", "dict_predicates"):
+                if facts.get(info):
+                    p.set_info(info, dict(facts[info]))
             return out, keyed
 
         def publish(vals):
@@ -1320,9 +1323,10 @@ class Executor:
 
     def _program_facts(self, bucket, caps, fresh: dict | None) -> dict:
         """What a program's trace found out about it ({"compactions": ...,
-        "segment_sums": ...}, for a mesh program also {"exchanges": ...}): `fresh` from the
-        attempt that compiled it, kept with the bucket under the capacities
-        that key the program, and read back there on a cache hit."""
+        "segment_sums": ..., "dict_predicates": ...}, for a mesh program
+        also {"exchanges": ...}): `fresh` from the attempt that compiled it,
+        kept with the bucket under the capacities that key the program, and
+        read back there on a cache hit."""
         key = ("facts", tuple(sorted(caps.values.items())))
         if fresh is None:
             return self.cache.bucket_meta_get(bucket, key) or {}

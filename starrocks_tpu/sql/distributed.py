@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..column.column import Field, pad_capacity
+from ..exprs.compile import dict_predicate_log
 from ..exprs.ir import Col, Lit
 from ..ops import (
     INNER, LEFT_ANTI, LEFT_OUTER, LEFT_SEMI,
@@ -149,7 +150,7 @@ def _single_sort_rank(chunk, sort_keys):
 class DistCompiled:
     def __init__(self, fn, scans, scan_modes, checks_meta, out_names, n_shards,
                  scopes=None, compactions=None, exchanges=None,
-                 segment_sums=None):
+                 segment_sums=None, dict_predicates=None):
         self.fn = fn
         self.scans = scans  # list[(table, alias, columns)]
         self.scan_modes = scan_modes  # list[SHARDED|REPLICATED]
@@ -163,10 +164,13 @@ class DistCompiled:
         # key (`limit_<n>`, `topn_<n>`: rows in, slots out, index method),
         # `exchanges` in program order (parallel/exchange.py `_shape`),
         # `segment_sums` by aggregate scope, `/partial` and `/final` apart
-        # (physical.Compiled.segment_sums)
+        # (physical.Compiled.segment_sums), `dict_predicates` by plan-node
+        # scope (physical.Compiled.dict_predicates)
         self.compactions = {} if compactions is None else compactions
         self.exchanges = [] if exchanges is None else exchanges
         self.segment_sums = {} if segment_sums is None else segment_sums
+        self.dict_predicates = ({} if dict_predicates is None
+                                else dict_predicates)
 
 
 def plan_scan_modes(plan: LogicalPlan, catalog) -> dict:
@@ -233,6 +237,7 @@ def compile_distributed(
     compactions: dict = {}
     exchanges: list = []
     segment_sums: dict = {}
+    dict_predicates: dict = {}
     all_gather = functools.partial(all_gather_chunk, axis=axis, log=exchanges)
 
     if recorder is not None:
@@ -271,13 +276,16 @@ def compile_distributed(
         emit_memo: dict = {}
         checks: dict = {}
         exchanges.clear()  # a retrace (the auditor's, a new input layout)
+        dict_predicates.clear()
 
         def emit(p):
             if p in emit_memo:
                 return emit_memo[p]
-            # the operator's name on its device operations, as in
-            # physical.compile_plan
-            with jax.named_scope(scope_name(scopes, p)):
+            # the operator's name on its device operations, and on the
+            # dictionary predicates it evaluates, as in physical.compile_plan
+            name = scope_name(scopes, p)
+            with jax.named_scope(name), dict_predicate_log(dict_predicates,
+                                                           name):
                 out = _emit(p)
             emit_memo[p] = out
             return out
@@ -868,4 +876,5 @@ def compile_distributed(
         step, scans, scan_mode_list, None, root_node.output_names(), n_shards,
         scopes=scope_table(scopes), compactions=compactions,
         exchanges=exchanges, segment_sums=segment_sums,
+        dict_predicates=dict_predicates,
     )

@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 
+from ..exprs.compile import dict_predicate_log
 from ..exprs.ir import AggExpr, Call, Col, Expr, Lit
 from ..ops import (
     INNER, LEFT_ANTI, LEFT_OUTER, LEFT_SEMI,
@@ -546,7 +547,7 @@ def scope_table(scopes: dict) -> dict:
 class Compiled:
     def __init__(self, fn, scans, checks_meta, out_names, aux=(),
                  node_ord=None, scopes=None, compactions=None,
-                 segment_sums=None):
+                 segment_sums=None, dict_predicates=None):
         self.fn = fn  # (inputs tuple) -> (chunk, checks tuple)
         self.scans = scans  # list[(table, alias, columns)]
         self.checks_meta = checks_meta  # list[(cap_key,)] parallel to checks
@@ -572,6 +573,14 @@ class Compiled:
         # "distinct" summed, "limbs" made, "formulation"}. Filled while fn
         # traces; the attempt's `segment_sums` info.
         self.segment_sums = {} if segment_sums is None else segment_sums
+        # plan-node scope (`sr.filter.<n>`) -> the boolean predicates over a
+        # dictionary column evaluated under it (`exprs/compile.
+        # dict_code_mask`), in trace order: [{"column", "dict" its length,
+        # "true_codes", "runs" of consecutive codes, "formulation" `ranges` |
+        # `lut`}]. Filled while fn traces; the attempt's `dict_predicates`
+        # info.
+        self.dict_predicates = ({} if dict_predicates is None
+                                else dict_predicates)
 
 
 def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
@@ -582,6 +591,7 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
     node_ord: dict = {}  # plan node (by value) -> deterministic ordinal
     compactions: dict = {}  # capacity key -> what `compact` did under it
     segment_sums: dict = {}  # aggregate scope -> what `seg_sums` did under it
+    dict_predicates: dict = {}  # node scope -> what `dict_code_mask` did
 
     def ordinal(p) -> int:
         return node_ord.setdefault(p, len(node_ord))
@@ -620,13 +630,16 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
         tracers. Overflow checks return as a dict with static keys."""
         emit_memo: dict = {}  # keyed by node VALUE so equal-but-copied
         checks: dict = {}     # subtrees (ROLLUP levels) emit once
+        dict_predicates.clear()  # a retrace appends the same entries again
 
         def emit(p: LogicalPlan):
             if p in emit_memo:
                 return emit_memo[p]
             # HLO metadata only: device operations carry the operator's
             # name (nested under its parent's) into a profiler trace
-            with jax.named_scope(scope_name(scopes, p)):
+            name = scope_name(scopes, p)
+            with jax.named_scope(name), dict_predicate_log(dict_predicates,
+                                                           name):
                 out = _emit(p)
             emit_memo[p] = out
             return out
@@ -1163,7 +1176,8 @@ def compile_plan(plan: LogicalPlan, catalog, caps: Caps,
 
     return Compiled(run, scans, None, plan.output_names(), tuple(aux),
                     node_ord=node_ord, scopes=scope_table(scopes),
-                    compactions=compactions, segment_sums=segment_sums)
+                    compactions=compactions, segment_sums=segment_sums,
+                    dict_predicates=dict_predicates)
 
 
 def _equi_pair(conj: Expr, lcols: frozenset, rcols: frozenset):

@@ -248,7 +248,9 @@ def lowered_now(small_tables_shard):
 
 
 with open(LOWERED) as _f:
-    LOWERED_BEFORE = json.load(_f)  # taken at 03cdf7a, the parent of PR 30
+    # taken at 03cdf7a, the parent of PR 30; Q7 rewritten by PR 32 (a year in
+    # int32), Q12 by PR 33 (`l_shipmode in (...)` as compares on the codes)
+    LOWERED_BEFORE = json.load(_f)
 
 
 @pytest.mark.parametrize("program", sorted(LOWERED_BEFORE))

@@ -191,6 +191,26 @@ def test_date_parts_carry_a_scope_under_their_plan_node(share, template, scope):
     assert "datepart" not in lowered_text(s, s.sql(sql), debug_info=False)
 
 
+@pytest.mark.parametrize("template", ["ssb_flat.q3.3", "ssb_flat.q3.4"])
+def test_a_city_in_list_is_compares_on_the_codes(share, template):
+    """`c_city in ('UNITED KI1', 'UNITED KI5') and s_city in (...)`: two runs
+    of one code in each 250-entry dictionary, so four equalities beside the
+    date compares and no table gathered a row (1.4 s a statement over 75.0M
+    rows on a v5e, PERF.md section 6, PR 33)."""
+    _, s = share
+    sql = next(v["sql"] for v in CELL.variants if v["template"] == template)
+    result = s.sql(sql)
+    preds = result.profile.children[-1].infos["dict_predicates"]
+    assert list(preds) == ["sr.filter.3"]
+    assert preds["sr.filter.3"] == [
+        {"column": f"lineorder_flat.{c}_CITY", "dict": 250, "true_codes": 2,
+         "runs": 2, "formulation": "ranges"} for c in "CS"]
+    under_filter = {p.rsplit("/", 1)[1]
+                    for p in SCOPED.findall(lowered_text(s, result))
+                    if "/sr.filter.3/" in p}
+    assert under_filter == {"eq", "or", "ge", "le", "and"}
+
+
 def test_date_parts_in_int32_equal_the_calendar_over_every_date():
     """year() / month() / day() / weekofyear() of a column run their
     civil-from-days arithmetic in int32 (in int64 a TPU emulates each
