@@ -32,8 +32,11 @@ memory statistic. With `--chips 4` it first runs the two collectives the
 engine works around (uint8 OR as an int32 psum, int64 min/max as an
 all_gather) on the real mesh against numpy, prints every fragment
 program's module name with its compactions (`cap`, `out_cap`, `live`) and
-exchanges, and fails if Q3's `_f2` holds no `shrink_<n>l`. Seconds are
-printed as facts of this run, not as metrics. Last stdout line on success:
+exchanges, and fails if Q3's `_f2` holds no `shrink_<n>l`. Each send's line
+counts the scans it placed by hash (`hash_placements`) and the shard layouts
+the host derived for them (`hash_layouts`); a last send that derives one
+fails. Seconds are printed as facts of this run, not as metrics. Last stdout
+line on success:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 """
@@ -221,19 +224,26 @@ def _check_collectives(chips: int, seed: int) -> list:
 
 def _send_until_warm(name: str, send, failures: list) -> tuple:
     """Send a statement twice, and a third time only if the second send
-    still compiled; the last send must compile nothing. Returns (the sends'
-    records, the rows of the last)."""
-    from starrocks_tpu.runtime.metrics import PROGRAM_COMPILES, RECOMPILES
+    still compiled; the last send must compile nothing. A send's record
+    also counts its scans placed on a mesh by hash (`hash_placements`) and
+    the shard layouts they derived on the host (`hash_layouts`), which the
+    last send must not. Returns (the sends' records, the rows of the
+    last)."""
+    from starrocks_tpu.runtime.metrics import (HASH_LAYOUTS, HASH_PLACEMENTS,
+                                               PROGRAM_COMPILES, RECOMPILES)
 
     sends, rows = [], None
     while len(sends) < 2 or (sends[-1]["compiles"] and len(sends) < MAX_SENDS):
         c0, r0 = PROGRAM_COMPILES.value, RECOMPILES.value
+        p0, l0 = HASH_PLACEMENTS.value, HASH_LAYOUTS.value
         t0 = time.monotonic()
         got = send()
         sends.append({
             "seconds": round(time.monotonic() - t0, 3),
             "compiles": int(PROGRAM_COMPILES.value - c0),
-            "recompiles": int(RECOMPILES.value - r0)})
+            "recompiles": int(RECOMPILES.value - r0),
+            "hash_placements": int(HASH_PLACEMENTS.value - p0),
+            "hash_layouts": int(HASH_LAYOUTS.value - l0)})
         if rows is not None and got != rows:
             failures.append(f"{name}: send {len(sends)} returned other rows "
                             "than send 1")
@@ -243,6 +253,9 @@ def _send_until_warm(name: str, send, failures: list) -> tuple:
     if sends[-1]["compiles"] or sends[-1]["recompiles"]:
         failures.append(f"{name}: send {len(sends)} still compiled "
                         f"({sends[-1]['compiles']} programs)")
+    if sends[-1]["hash_layouts"]:
+        failures.append(f"{name}: send {len(sends)} derived "
+                        f"{sends[-1]['hash_layouts']} shard layouts")
     return sends, rows
 
 

@@ -101,7 +101,7 @@ class DistExecutor(Executor):
                 box["facts"] = self._name_program(
                     compiled, key, program_name(plan, self._fb_fp()))
                 scans_meta = tuple(zip(compiled.scans, compiled.scan_modes))
-                inputs0 = self._place(scans_meta)
+                inputs0 = self._place(scans_meta, p)
                 in_specs = tuple(
                     jax.tree_util.tree_map(
                         lambda _, mm=m: P() if mm == REPLICATED else P(self.axis),
@@ -121,7 +121,7 @@ class DistExecutor(Executor):
                 return jax.jit(raw), scans_meta, raw
 
             out, checks = self._cached_attempt(
-                key, caps, p, compile_cb, self._place)
+                key, caps, p, compile_cb, lambda s: self._place(s, p))
             facts = self._ran(key, caps, box.pop("facts", None))
             return out, self._attempt_infos(p, caps, [facts], checks)
 
@@ -251,11 +251,11 @@ class DistExecutor(Executor):
             return int(np.asarray(merged).max())
         return int(np.asarray(v).max())
 
-    def _place(self, scans_meta):
+    def _place(self, scans_meta, profile=None):
         return tuple(
             self.cache.chunk_for(
                 self.catalog.get_table(t), a, cols,
-                placement=(self.mesh, self.axis, m),
+                placement=(self.mesh, self.axis, m), profile=profile,
             )
             for (t, a, cols), m in scans_meta
         )
@@ -301,7 +301,7 @@ class DistExecutor(Executor):
             recorder=rec,
         )
         scans_meta = tuple(zip(compiled.scans, compiled.scan_modes))
-        inputs0 = self._place(scans_meta)
+        inputs0 = self._place(scans_meta, profile)
         raw = shard_map(
             compiled.fn, mesh=self.mesh,
             in_specs=(self._scan_in_specs(inputs0, scans_meta),),
@@ -363,7 +363,7 @@ class DistExecutor(Executor):
 
         def attempt(caps, p):
             with p.timer("scan_to_device"):
-                inputs = self._place(scans_meta)
+                inputs = self._place(scans_meta, p)
             outputs: dict = {}
             merged: dict = {}
             facts: list = []

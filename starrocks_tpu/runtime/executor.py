@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import threading
 import time
@@ -36,8 +37,9 @@ from ..sql.physical import Caps, compile_plan
 from . import lifecycle
 from .config import config
 from .failpoint import fail_point
-from .metrics import (PROGRAM_COMPILES, QUERIES_TOTAL, QUERY_ERRORS,
-                      RECOMPILES, ROWS_RETURNED, count_compactions, metrics)
+from .metrics import (HASH_LAYOUTS, HASH_PLACEMENTS, PROGRAM_COMPILES,
+                      QUERIES_TOTAL, QUERY_ERRORS, RECOMPILES, ROWS_RETURNED,
+                      count_compactions, metrics)
 from .profile import RuntimeProfile
 
 COMPILE_MS = metrics.histogram(
@@ -91,6 +93,21 @@ def program_name(plan, fingerprint: str | None = None) -> str:
     there is one, else by a digest of the plan."""
     fp = fingerprint or hashlib.sha256(repr(plan).encode()).hexdigest()
     return "q_" + fp[:8]
+
+
+def hash_shard_layout(keys, n_shards: int):
+    """(rows a shard, stable row permutation grouping rows by shard) of a
+    hash placement: shard i holds the rows whose splitmix64 bucket (the
+    device shuffle's) is i, in table order. The bucket ids are sorted as
+    uint8 (uint16 above 256 shards): numpy's stable argsort of those is a
+    radix sort, linear in the rows, and gives the int32 sort's permutation."""
+    from ..native import hash_partition_i64
+
+    bucket = hash_partition_i64(np.asarray(keys, dtype=np.int64), n_shards)
+    counts = np.bincount(bucket, minlength=n_shards)
+    if n_shards <= 1 << 16:
+        bucket = bucket.astype(np.uint8 if n_shards <= 1 << 8 else np.uint16)
+    return counts, np.argsort(bucket, kind="stable")
 
 
 class DeviceCache:
@@ -162,9 +179,16 @@ class DeviceCache:
         with self._lock:
             self._cols.pop(key, None)
 
-    def _cap_for(self, key, default: int) -> int:
+    def _cap_for(self, key, seed) -> int:
+        """The capacity cached under `key`; on a miss `seed()`, called
+        outside the lock, becomes it (first writer wins)."""
         with self._lock:
-            return self._caps.setdefault(key, default)
+            cap = self._caps.get(key)
+        if cap is None:
+            cap = seed()
+            with self._lock:
+                cap = self._caps.setdefault(key, cap)
+        return cap
 
     def resident_arrays(self) -> list:
         """Snapshot [(cache key, device array)] of every cached device
@@ -347,10 +371,19 @@ class DeviceCache:
         return e[0]
 
     def chunk_for(self, handle, alias: str, columns, placement=None,
-                  cache_tag=None) -> Chunk:
+                  cache_tag=None, profile=None) -> Chunk:
         """Device chunk of the requested columns, renamed to alias-qualified.
         `cache_tag` overrides the column-cache namespace (RF-pruned scans
-        must not collide with the full-table entries)."""
+        must not collide with the full-table entries).
+
+        A hash placement's shard layout (rows a shard, and the stable row
+        permutation that groups rows by shard) is derived from the key
+        column only when something it feeds is missing: the capacity, a
+        column or validity array, or the selection mask. Then it is derived
+        once for the call and fills every miss; when all of them hit, the
+        key column is not read. No layout is kept: DML's `invalidate(table)`
+        drops those entries, so the next placement derives the layout from
+        the new rows. `profile` gets the derivation's `hash_layout` span."""
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -360,8 +393,7 @@ class DeviceCache:
         lifecycle.checkpoint("scan::chunk_to_device")
 
         ht = handle.table
-        reorder = None  # host row permutation + per-shard layout (hash modes)
-        per_shard_rows = None
+        hash_key = None  # hash mode: the base-name column rows bucket by
         if placement is None:
             tag, put, n_shards = cache_tag or "local", jnp.asarray, 1
         else:
@@ -371,16 +403,9 @@ class DeviceCache:
             if isinstance(mode, tuple) and mode[0] == "hash":
                 # colocate placement: shard i holds rows whose bucket
                 # (same splitmix64 as the device shuffle) equals i
-                keycol = mode[1].split(".", 1)[-1]  # qualified -> base name
-                tag = f"hash:{keycol}"
-                from ..native import hash_partition_i64
-
-                bucket = hash_partition_i64(
-                    np.asarray(ht.arrays[keycol], dtype=np.int64), n_shards
-                )
-                counts = np.bincount(bucket, minlength=n_shards)
-                per_shard_rows = counts
-                reorder = np.argsort(bucket, kind="stable")
+                hash_key = mode[1].split(".", 1)[-1]  # qualified -> base name
+                tag = f"hash:{hash_key}"
+                HASH_PLACEMENTS.inc()
             else:
                 tag = mode
             spec = P() if replicated else P(axis)
@@ -393,17 +418,26 @@ class DeviceCache:
 
                 return put_global(x, sharding)
 
+        @functools.cache  # once a call, and only when a miss asks for it
+        def shard_layout():
+            HASH_LAYOUTS.inc()
+            with (profile.timer("hash_layout") if profile is not None
+                  else contextlib.nullcontext()):
+                return hash_shard_layout(ht.arrays[hash_key], n_shards)
+
         n = ht.num_rows
         cap_key = (handle.name, tag)
-        if reorder is not None:
-            shard_cap = pad_capacity(int(per_shard_rows.max()) if n else 1)
-            default_cap = shard_cap * n_shards
-        elif n_shards > 1:
-            default_cap = pad_capacity((n + n_shards - 1) // n_shards) * n_shards
-        else:
-            default_cap = pad_capacity(n)
+
+        def default_cap():
+            if hash_key is not None:
+                counts, _ = shard_layout()
+                return pad_capacity(int(counts.max()) if n else 1) * n_shards
+            if n_shards > 1:
+                return pad_capacity((n + n_shards - 1) // n_shards) * n_shards
+            return pad_capacity(n)
+
         if handle.name.startswith("information_schema."):
-            cap = default_cap  # virtual tables grow between reads
+            cap = default_cap()  # virtual tables grow between reads
         else:
             cap = self._cap_for(cap_key, default_cap)
 
@@ -411,13 +445,14 @@ class DeviceCache:
             """Host layout: pad (range mode) or bucket-slotted (hash mode).
             Handles rank-2 wide columns (ARRAY/DECIMAL128) row-wise."""
             tail = a.shape[1:]
-            if reorder is None:
+            if hash_key is None:
                 if len(a) < cap:
                     a = np.concatenate(
                         [a, np.full((cap - len(a),) + tail, fill,
                                     dtype=a.dtype)]
                     )
                 return a
+            per_shard_rows, reorder = shard_layout()
             shard_cap = cap // n_shards
             out = np.full((cap,) + tail, fill, dtype=a.dtype)
             srt = a[reorder]
@@ -469,9 +504,10 @@ class DeviceCache:
                 self._cpop(sel_key)
             sentry = self._cget(sel_key)
             if sentry is None:
-                if reorder is None:
+                if hash_key is None:
                     selv = np.arange(cap) < n
                 else:
+                    per_shard_rows, _ = shard_layout()
                     shard_cap = cap // n_shards
                     selv = np.zeros(cap, dtype=bool)
                     for b in range(n_shards):

@@ -218,6 +218,18 @@ EXCHANGE_BYTES = metrics.counter(
     "sr_tpu_exchange_bytes_total",
     "bytes one shard put on the interconnect for them: data and validity "
     "columns and the live mask, to the n-1 other shards")
+# Hash placements (`DeviceCache.chunk_for` with a ("hash", key) mode: shard i
+# holds the rows whose splitmix64 bucket is i), and the calls among them that
+# derived the table's shard layout on the host because the capacity, a
+# column or the selection mask was not cached yet. In a warm window layouts
+# stay put while placements move.
+HASH_PLACEMENTS = metrics.counter(
+    "sr_tpu_hash_placements_total",
+    "scans placed on a mesh by hash of their distribution column")
+HASH_LAYOUTS = metrics.counter(
+    "sr_tpu_hash_layouts_total",
+    "of them, those that hashed, counted and sorted the key column on the "
+    "host to derive the shard layout")
 
 # Compactions (`ops/common.compact`: a chunk shrunk to its live rows before a
 # join, an aggregate or a sort), counted on the host once per statement from

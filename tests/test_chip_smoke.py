@@ -34,6 +34,9 @@ def test_run_passes_on_cpu_at_small_scale():
     assert set(by_name) == {"mysql:q1", "mysql:q6", "mysql:q3", "http:q1"}
     for s in by_name.values():
         assert s["oracle_match"] and s["sends"][-1]["compiles"] == 0
+        # one chip places nothing by hash
+        assert all(send["hash_placements"] == send["hash_layouts"] == 0
+                   for send in s["sends"])
     # a statement new to the tier compiles; the same statement through the
     # other door reuses the tier's program
     assert by_name["mysql:q1"]["sends"][0]["compiles"] >= 1
